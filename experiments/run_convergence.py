@@ -217,8 +217,8 @@ def exp_wrn_tpu() -> list[dict]:
     """The WRN recipe ON THE REAL TPU with the production hot path active
     (round-4 verdict item 1): bf16 compute, fused 4-step dispatch,
     augmentation, 10-crop val, and a checkpointed mid-run resume — the
-    exact code path the throughput claims (ZOO_BENCH/BENCH_rNN) measure,
-    carried to an accuracy number instead of a perf sample. A same-seed
+    production code path carried to an accuracy number instead of a
+    perf sample. A same-seed
     single-device CPU run in f32 per-step dispatch is the trusted-math
     reference curve; results/wrn_tpu_vs_cpu.json quantifies divergence
     (bf16 + platform + fusion, jointly — each alone is below the run-to-

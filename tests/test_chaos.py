@@ -43,7 +43,6 @@ def test_smoke_campaign_zero_violations_under_budget(tmp_path):
     under the 120 s budget, wall time reported in timings_s."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["TMPI_FORCE_PLATFORM"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (

@@ -38,3 +38,49 @@ def test_peak_flops_table():
     assert peak_flops(Unknown()) is None
     assert mfu(1e12, Unknown()) is None
     assert abs(mfu(98.5e12, FakeDev()) - 0.5) < 1e-9
+
+
+def test_unknown_tpu_kind_raises_instead_of_calibrating():
+    """Off the TPU an unknown device reports None and consumers
+    calibrate; a TPU the spec tables do not know must not quietly take
+    that path — every table lookup raises."""
+    import pytest
+
+    from theanompi_tpu.obs.attribution import link_bytes_per_sec
+    from theanompi_tpu.utils.flops import (
+        hbm_capacity_bytes,
+        peak_hbm_bytes_per_sec,
+    )
+
+    class NewChip:
+        platform = "tpu"
+        device_kind = "TPU v9 mega"
+
+    for lookup in (peak_flops, peak_hbm_bytes_per_sec, hbm_capacity_bytes,
+                   link_bytes_per_sec):
+        with pytest.raises(ValueError, match="TPU v9 mega"):
+            lookup(NewChip())
+
+    class Cpu:
+        platform = "cpu"
+        device_kind = "cpu"
+
+    assert link_bytes_per_sec(Cpu()) is None
+
+
+def test_compiled_cost_swallows_a_failed_lowering_only_off_the_tpu():
+    import pytest
+
+    from theanompi_tpu.utils.flops import compiled_cost
+
+    class Unlowerable:
+        def lower(self, *a, **k):
+            raise RuntimeError("does not lower")
+
+    class Tpu:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    assert compiled_cost(Unlowerable(), device=jax.devices()[0]) is None
+    with pytest.raises(RuntimeError, match="does not lower"):
+        compiled_cost(Unlowerable(), device=Tpu())

@@ -134,3 +134,39 @@ def test_profile_trace_capture(tmp_path):
     )
     produced = list(prof.rglob("*.xplane.pb")) + list(prof.rglob("*.trace.json.gz"))
     assert produced, f"no trace files under {prof}: {list(prof.rglob('*'))}"
+
+
+@pytest.mark.parametrize("rule,devices,kw", [
+    ("bsp", 1, {}),
+    ("bsp", 4, {}),
+    ("bsp", 4, {"zero": 1}),
+    ("easgd", 4, {"avg_freq": 2}),
+    ("gosgd", 4, {}),
+])
+def test_train_step_compiles_once(rule, devices, kw, caplog):
+    """The state a run starts from carries the shardings (and types) of
+    the state every step returns, so the second step reuses the first
+    one's program. An uncommitted or weak-typed initial state made every
+    engine lower and compile its step twice — on a chip, the most
+    expensive half-minute of a run, paid double."""
+    import logging
+
+    import jax
+
+    # an odd width: nothing else in this process has lowered this step
+    overrides = dict(_TINY["recipe_overrides"], input_shape=(12, 12, 3))
+    data = dict(_TINY["dataset_kwargs"], n_train=32 * 4 * 3, n_val=32 * 4,
+                image_shape=(12, 12, 3))
+    with jax.log_compiles(True), caplog.at_level(logging.WARNING, logger="jax"):
+        summary = run_training(
+            rule=rule, model_cls=TinyCNN, devices=devices, n_epochs=1,
+            max_steps=3, **{**_TINY, "recipe_overrides": overrides,
+                            "dataset_kwargs": data}, **kw,
+        )
+    assert summary["steps"] == summary["device_steps"] == 3
+    lowered = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith(("Compiling jit(sharded_step)",
+                                             "Compiling jit(single_step)",
+                                             "Compiling sharded_step",
+                                             "Compiling single_step"))]
+    assert len(lowered) == 1, lowered

@@ -211,3 +211,27 @@ def test_device_normalize_pipeline_agrees_with_host(tmp_path):
     np.testing.assert_allclose(
         (xd.astype(np.float32) - t["mean"]) * t["scale"], xh, rtol=1e-5, atol=1e-5
     )
+
+
+def test_artifact_is_keyed_on_source_content_not_on_the_host(tmp_path, monkeypatch):
+    """A library is loaded only under the name this checkout's source,
+    flags and CPU hash to: a copied tree or another machine's build can
+    never be picked up by host name or mtime."""
+    import os
+    import shutil
+
+    here = native._artifact()
+    assert here is not None and os.path.basename(here).startswith("_tmpi_native-")
+    # another CPU's features -> another artifact
+    with monkeypatch.context() as m:
+        m.setattr(native, "_cpu_flags", lambda: "another cpu")
+        assert native._artifact() != here
+    edited = tmp_path / "loader.cpp"
+    shutil.copy(native._SRC, edited)
+    with open(edited, "a") as f:
+        f.write("\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", str(edited))
+    assert native._artifact() != here
+    # no source: nothing on disk can be trusted
+    monkeypatch.setattr(native, "_SRC", str(tmp_path / "missing.cpp"))
+    assert native._artifact() is None and native._build() is None

@@ -18,7 +18,6 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(cmd, timeout=600):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["TMPI_FORCE_PLATFORM"] = "cpu"
     p = subprocess.run(
         cmd, cwd=REPO_ROOT, env=env, capture_output=True, text=True,
         timeout=timeout,

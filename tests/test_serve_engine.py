@@ -71,10 +71,14 @@ def test_mixed_stream_compiles_at_most_len_buckets(served_engine):
     assert engine._batches < len(results)
 
 
-def test_padding_is_bit_identical_to_unbatched_forward(served_engine):
-    """A request served from a padded micro-batch returns EXACTLY the
-    logits of an unbatched (bucket-1) forward: eval-mode forwards are
-    row-independent, so the zero-padded rows cannot perturb real ones."""
+def test_padding_rows_cannot_perturb_real_rows(served_engine):
+    """Eval-mode forwards are row-independent, so what shares a padded
+    micro-batch with a request cannot change its logits: the served row
+    is BIT-identical to the same bucket-8 program run with every other
+    row replaced by noise. Against an unbatched (bucket-1) forward — a
+    different compiled program, whose matmuls may accumulate in another
+    order — it agrees to float32 rounding (7e-7 observed on XLA:CPU),
+    not to the bit."""
     engine = served_engine
     model = engine.model
     state = init_train_state(tiny_model(), jax.random.PRNGKey(0))
@@ -87,11 +91,15 @@ def test_padding_is_bit_identical_to_unbatched_forward(served_engine):
     got = [f.result(20.0).logits for f in futs]
     assert engine._batches == 1
     ref_fwd = jax.jit(infer_fn(model))
-    for x, out in zip(xs, got):
-        ref = np.asarray(
-            ref_fwd(state.params, state.model_state, x[None])
-        )[0]
-        np.testing.assert_array_equal(out, ref)
+    for i, (x, out) in enumerate(zip(xs, got)):
+        crowd = r.randn(8, 8, 8, 3).astype(np.float32)
+        crowd[i] = x
+        same_program = np.asarray(
+            ref_fwd(state.params, state.model_state, crowd))[i]
+        np.testing.assert_array_equal(out, same_program)
+        unbatched = np.asarray(
+            ref_fwd(state.params, state.model_state, x[None]))[0]
+        np.testing.assert_allclose(out, unbatched, rtol=1e-5, atol=1e-5)
 
 
 def test_expired_deadline_rejected_not_served():

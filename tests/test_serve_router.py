@@ -419,3 +419,50 @@ def test_router_health_transitions_under_stress(tmp_path):
     assert res.ok, res.violations
     # the stress evidence rides the telemetry stream
     assert check_file(str(tmp_path / "stress.jsonl")) == []
+
+
+@pytest.mark.parametrize("kind", ["eval", "decode"])
+def test_fleet_members_take_distinct_devices(kind):
+    """`tmpi serve --replicas N` built every member identically, so a
+    fleet on a multi-chip host sat on chip 0. Member ``rid`` now takes
+    local device ``rid % n_local``: params (and the decode member's KV
+    pool) live there, and the member answers from there."""
+    from theanompi_tpu.serve.cli import replica_sharding
+
+    n_local = len(jax.local_devices())
+    assert n_local >= 4
+
+    def member(rid):
+        if kind == "eval":
+            eng = ServeEngine(_MODEL, buckets=(1,),
+                              sharding=replica_sharding(rid))
+            eng.set_params(_STATE.params, _STATE.model_state, 1)
+            x = np.zeros((8, 8, 3), np.float32)
+        else:
+            from test_decode_engine import make_engine, set_tiny_params
+
+            eng = make_engine(sharding=replica_sharding(rid))
+            set_tiny_params(eng)
+            x = np.asarray([1, 2, 3], np.int32)
+        eng.warmup()
+        eng.start()
+        try:
+            eng.infer(x, timeout=60)
+        finally:
+            eng.drain(timeout=60)
+        return eng
+
+    members = [member(rid) for rid in range(4)]
+    ids = [m.params_device()["device_ids"] for m in members]
+    assert ids == [[0], [1], [2], [3]]
+    if kind == "decode":
+        for rid, m in enumerate(members):
+            assert {d.id for d in m._cache.k_pool.devices()} == {rid}
+            # and no request moved the pool (nothing retraced)
+            assert m.compile_count == len(m.buckets) + 1
+    # more members than devices wrap around; a single engine and a
+    # tensor-sharded recipe are left alone
+    wrapped = replica_sharding(n_local + 1)
+    assert [d.id for d in wrapped.mesh.devices.flat] == [1]
+    assert replica_sharding(None) is None
+    assert replica_sharding(2, base=wrapped) is wrapped

@@ -321,6 +321,28 @@ def test_async_start_collectives_priced_by_payload_not_tuple():
     assert sync[0].result_bytes == 512.0
 
 
+def test_untyped_operands_are_sized_from_the_result():
+    """The installed XLA prints operands by name only
+    (`reduce-scatter(%param.1)`): a reduce-scatter priced by its
+    operand then read as 0 B and every clean ZeRO engine got a
+    SHARD002. The operand is recovered from the result and the group."""
+    n = 2
+    hlo = "\n".join([
+        "ROOT %rs = f32[1627]{0} reduce-scatter(%param.1), channel_id=1, "
+        "replica_groups={{0,1}}, use_global_device_ids=true, "
+        "dimensions={0}, to_apply=%region_0.0",
+        "%ag = f32[3254]{0} all-gather(%shard), channel_id=2, "
+        "replica_groups={{0,1}}, dimensions={0}",
+        "%ar = (f32[1024]{0}, f32[1024]{0}) all-reduce-start(%p), "
+        "channel_id=3, replica_groups={{0,1}}",
+    ])
+    kinds = hlo_kind_bytes(hlo_collectives(hlo, default_group=n))
+    # (n-1)/n of the FULL pre-scatter buffer: 2 x 1627 x 4 B
+    assert kinds["reduce-scatter"] == (n - 1) / n * 2 * 1627 * 4
+    assert kinds["all-gather"] == (n - 1) / n * 3254 * 4
+    assert kinds["all-reduce"] == 2.0 * (n - 1) / n * 4096
+
+
 def test_bare_spmd_exempt_rejected_for_shard_rules(tmp_path):
     """SHARD findings honor the written-reason suppression contract: a
     bare `spmd_exempt:` does not count."""

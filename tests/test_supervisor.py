@@ -258,7 +258,6 @@ def _tmpi_subprocess(args, allow_kill=False):
     platform (warm compile cache inherited from the session)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["TMPI_FORCE_PLATFORM"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (

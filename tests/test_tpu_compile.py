@@ -1,0 +1,176 @@
+"""The main path's Pallas kernels and the one-chip LM train step, compiled
+at real widths for a DESCRIBED TPU v5e (no chip attached): what Mosaic or
+XLA:TPU would refuse on the chip, it refuses here, at no chip time.
+
+Interpret-mode tests cannot see any of this (tile alignment, VMEM budget,
+HBM fit). Nothing runs: a pass is not a chip run and says nothing about
+results or times.
+
+The topology is described inside a module-scoped fixture of THIS file only
+(never at import, never in conftest.py): the process that describes it
+loads libtpu and keeps it until it exits, so under pytest-xdist exactly one
+worker may do so. The kernels ask ``interpret_mode()`` which backend is
+live and would take their CPU branch here, so each test steers the name
+its kernel module imported."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """conftest.py turns the persistent cache on, and a described-chip
+    executable written to it cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _mosaic(monkeypatch, module):
+    monkeypatch.setattr(module, "_interpret", lambda: False)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_flash_attention_forward_and_backward_at_the_136m_shape(
+        one_chip, no_persistent_cache, monkeypatch):
+    """B=8 H=12 T=1024 D=64 bf16 causal, default 512x512 blocks: D=64 is
+    half a lane tile, and the backward keeps the opposite sequence
+    VMEM-resident."""
+    from theanompi_tpu.ops import pallas_attention as pa
+
+    _mosaic(monkeypatch, pa)
+    q = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa.flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    fwd = jax.jit(attend).lower(q, q, q).compile()
+    assert _kernels(fwd) == 1
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile()
+    assert _kernels(bwd) == 3  # forward + dq + dk/dv
+
+
+def test_fused_update_grid_branch_on_a_ragged_leaf(
+        one_chip, no_persistent_cache, monkeypatch):
+    """A leaf whose size is no multiple of 512*128: the on-TPU row grid,
+    which interpret mode never takes (ops/pallas_update._block_rows)."""
+    from theanompi_tpu.ops import pallas_update as pu
+
+    _mosaic(monkeypatch, pu)
+    n = 9216 * 4096 + 12345  # AlexNet fc6 and a ragged tail
+    assert n % (512 * 128)
+    p = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+
+    def update(p, v, g):
+        return pu.fused_update_leaf(p, v, g, 0.01, 1.0, momentum=0.9,
+                                    weight_decay=5e-4, nesterov=False)
+
+    assert _kernels(jax.jit(update).lower(p, p, p).compile()) == 1
+
+
+@pytest.mark.parametrize("rows", [
+    8 * 2 ** 20 // 512,   # an 8 MB gradient bucket
+    9216 * 4096 // 128 + 8,  # a whole AlexNet fc6 leaf, ragged last block
+])
+def test_int8_block_quant_round_trip(one_chip, no_persistent_cache,
+                                     monkeypatch, rows):
+    """One block per leaf was refused from 32 MB up (scoped VMEM); the
+    row grid must take any leaf the codec hands it."""
+    from theanompi_tpu.ops import pallas_quant as pq
+
+    _mosaic(monkeypatch, pq)
+    x = jax.ShapeDtypeStruct((rows, 128), jnp.float32, sharding=one_chip)
+
+    def round_trip(x):
+        return pq.dequantize_int8_block(*pq.quantize_int8_block(x))
+
+    assert _kernels(jax.jit(round_trip).lower(x).compile()) == 2
+
+
+def test_maxpool3x3_forward_and_backward(one_chip, no_persistent_cache,
+                                         monkeypatch):
+    """The opt-in (TMPI_PALLAS_POOL=1) GoogLeNet inception pool, off the
+    main path, at an inception-3 shape."""
+    from theanompi_tpu.ops import pallas_pool as pp
+
+    _mosaic(monkeypatch, pp)
+    x = jax.ShapeDtypeStruct((64, 28, 28, 192), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(x):
+        return pp.maxpool3x3_s1(x).astype(jnp.float32).sum()
+
+    assert _kernels(jax.jit(jax.grad(loss)).lower(x).compile()) == 2
+
+
+def test_lm_136m_train_step_fits_one_v5e(topo, one_chip, no_persistent_cache,
+                                         monkeypatch):
+    """The whole jitted BSP-1 step of TransformerLM_136M (the program
+    chip_smoke.py's train-lm phase runs), from eval_shape shapes: it
+    compiles with its 36 attention kernels and fits 16 GB of HBM."""
+    from theanompi_tpu.models.lm import TransformerLM_136M
+    from theanompi_tpu.ops import pallas_attention as pa
+    from theanompi_tpu.parallel.bsp import make_bsp_train_step
+    from theanompi_tpu.train import init_train_state
+
+    _mosaic(monkeypatch, pa)
+    model = TransformerLM_136M()
+    r = model.recipe
+
+    def described(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(described, jax.eval_shape(
+        lambda: init_train_state(model, jax.random.PRNGKey(0))))
+    key = described(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((r.batch_size, *r.input_shape), jnp.int32,
+                                  sharding=one_chip)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    compiled = make_bsp_train_step(model, mesh).lower(
+        state, tokens, tokens, key).compile()
+
+    assert _kernels(compiled) == 3 * r.n_layers
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    n_params = sum(math.prod(a.shape)
+                   for a in jax.tree_util.tree_leaves(state.params))
+    assert 130e6 < n_params < 140e6
+    assert live < V5E_HBM_BYTES, f"{live / 1e9:.2f} GB does not fit one v5e"

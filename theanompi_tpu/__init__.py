@@ -56,7 +56,6 @@ if _impl is None and "JAX_DEFAULT_PRNG_IMPL" not in _os.environ:
 if _impl:
     _jax.config.update("jax_default_prng_impl", _impl)
 
-from theanompi_tpu import _jax_compat  # noqa: F401,E402  (jax API bridge)
 from theanompi_tpu.launch.session import BSP, EASGD, GOSGD, SyncRule  # noqa: F401,E402
 from theanompi_tpu.launch.supervisor import supervise_training  # noqa: F401,E402
 

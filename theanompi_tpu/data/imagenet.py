@@ -96,6 +96,12 @@ class ImageNet_data(Dataset):
         self._val = self._index(base, "val")
         if not self._train:
             raise FileNotFoundError(f"no train_images_*.npy shards under {base}")
+        # say which implementation feeds the trainer: the numpy fallback
+        # is bit-identical but slower, and otherwise only a failed build
+        # ever prints
+        print(f"[data] imagenet shards under {base}: {self.n_train} train / "
+              f"{self.n_val} val rows; host loader: {native.describe()}",
+              flush=True)
         mean_path = os.path.join(base, "mean.npy")
         # reference: per-pixel img_mean subtracted in the loader
         self.mean = (

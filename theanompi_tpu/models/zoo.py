@@ -3,18 +3,15 @@ batch). Shared by the root ``bench.py`` harness and
 ``tools/op_profile.py`` so the batch policy lives in one place.
 
 Batch policy: AlexNet runs the reference workload's GLOBAL batch
-(BASELINE config #2: 8 workers x 128 = 1024 — same SGD trajectory, and
-a v5e only reaches full MXU utilization ~batch 1024); GoogLeNet uses
-512 — the round-5 batch sweep (experiments/results/
-googlenet_layout.json: 5547/5630/5118 img/s at 256/512/1024, OOM at
-2048) puts the single-chip knee at 512; its step is ~35% max-pool
-sweeps that scale with batch, so past the knee extra batch only grows
-the bandwidth-bound work. (Config #3's global 1024 is a 32-WORKER
-batch — at pod scale each chip sees 32 rows; the single-chip row's
-batch is a free throughput parameter, and the earlier 1024 reading
-5134.9 img/s is retained in the committed sweep for comparison.)
-ResNet-50 uses config #4's batch 256; VGG16/WRN use the largest
-power-of-two that fits one chip's HBM comfortably."""
+(BASELINE config #2: 8 workers x 128 = 1024 — same SGD trajectory);
+GoogLeNet uses 512 (an earlier batch sweep,
+experiments/results/googlenet_layout.json, put its single-chip knee
+there and ran out of memory at 2048; config #3's global 1024 is a
+32-WORKER batch — at pod scale each chip sees 32 rows, so the
+single-chip batch is a free parameter). ResNet-50 uses config #4's
+batch 256; VGG16/WRN use the largest power-of-two that fits one chip's
+HBM comfortably. None of these has been re-measured on the current
+machine."""
 
 from __future__ import annotations
 

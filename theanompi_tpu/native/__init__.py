@@ -5,10 +5,14 @@ The reference hid preprocessing cost in a spawned loader process
 random crop, mirror in numpy; SURVEY.md §3.4), with hwloc pinning the
 loader near its GPU (``lib/hwloc_utils.py``). The TPU rebuild keeps the
 prefetch thread but makes the hot loop itself native: ``loader.cpp`` is
-compiled ON DEMAND with the system g++ into ``_tmpi_native.so`` (cached
-beside the source, rebuilt when the source is newer) and called through
-ctypes — no build-system dependency, and any failure degrades to the
-numpy path (``available()`` returns False).
+compiled ON DEMAND with the system g++ into ``_tmpi_native-<key>.so``
+beside the source and called through ctypes — no build-system
+dependency, and any failure degrades to the numpy path (``available()``
+returns False). ``<key>`` hashes the source bytes, the compiler flags
+and this CPU's feature flags, so a library is only ever loaded by a
+checkout whose source produced it, on a CPU it was built for; a copied
+tree or a shared filesystem rebuilds (~1 s) instead of running another
+machine's ``-march=native`` code (SIGILL).
 
 Set ``TMPI_NATIVE=0`` to force the numpy fallback;
 ``TMPI_LOADER_THREADS`` overrides the preprocessing thread count
@@ -27,13 +31,7 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "loader.cpp")
-# -march=native makes the artifact host-specific; key the cache by
-# hostname so a shared-filesystem install (NFS venv across pod hosts)
-# never runs another host's AVX build (SIGILL), and each host builds its
-# own (~1s, once)
-import platform as _platform
-
-_SO = os.path.join(_DIR, f"_tmpi_native-{_platform.node() or 'local'}.so")
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-std=c++17")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -54,35 +52,61 @@ def default_threads() -> int:
     return max(1, min(8, n - 1))
 
 
-def _build() -> bool:
-    if not os.path.exists(_SRC):
-        # source missing (e.g. wheel without package data): a cached .so
-        # for this host is still trustworthy; otherwise degrade
-        return os.path.exists(_SO)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return True
+def _cpu_flags() -> str:
+    """This CPU's feature flags (what -march=native compiles against)."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    import platform
+
+    return platform.machine() + platform.processor()
+
+
+def _artifact() -> Optional[str]:
+    """Path of the library THIS source builds on THIS CPU; None when the
+    source is missing (then nothing on disk can be trusted)."""
+    import hashlib
+
+    try:
+        with open(_SRC, "rb") as f:
+            src = f.read()
+    except OSError:
+        return None
+    key = hashlib.sha256(
+        src + " ".join(_FLAGS).encode() + _cpu_flags().encode()
+    ).hexdigest()[:16]
+    return os.path.join(_DIR, f"_tmpi_native-{key}.so")
+
+
+def _build() -> Optional[str]:
+    """Path of a loadable library built from this checkout's source, or
+    None."""
+    so = _artifact()
+    if so is None or os.path.exists(so):
+        return so
     # pid-unique tmp: N controller processes on one host may race to
     # build on first use; each compiles privately, os.replace is atomic,
     # last writer wins with a valid artifact either way
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        "-o", tmp, _SRC, "-lpthread",
-    ]
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, "-o", tmp, _SRC, "-lpthread"]
     try:
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         if r.returncode != 0:
-            return False
-        os.replace(tmp, _SO)
+            return None
+        os.replace(tmp, so)
     except (OSError, subprocess.TimeoutExpired):
-        return False
+        return None
     finally:
         if os.path.exists(tmp):
             try:
                 os.unlink(tmp)
             except OSError:
                 pass
-    return True
+    return so
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -95,7 +119,8 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("TMPI_NATIVE", "1") == "0":
             return None
-        if not _build():
+        so = _build()
+        if so is None:
             print(
                 "theanompi_tpu.native: C++ loader kernels unavailable "
                 "(g++/source missing?) — using the slower numpy path",
@@ -103,13 +128,11 @@ def _load() -> Optional[ctypes.CDLL]:
             )
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             _bind(lib)
         except (OSError, AttributeError):
-            # load failure OR a stale cached .so missing a newer symbol
-            # (source absent so no rebuild possible): degrade, don't crash
             print(
-                f"theanompi_tpu.native: failed to load/bind {_SO} — using "
+                f"theanompi_tpu.native: failed to load/bind {so} — using "
                 "the slower numpy path",
                 flush=True,
             )
@@ -152,6 +175,14 @@ def _bind(lib: ctypes.CDLL) -> None:
 
 def available() -> bool:
     return _load() is not None
+
+
+def describe() -> str:
+    """Which implementation feeds callers in this process, for logs."""
+    lib = _load()
+    if lib is None:
+        return "numpy fallback"
+    return f"native C++ ({os.path.basename(lib._name)})"
 
 
 def crop_mirror_normalize(

@@ -1,7 +1,7 @@
 """Step-time attribution: where does the training step go?
 
-The perf trajectory plateaued at MFU ~0.38 (BENCH_r03–r05) and the
-evidence was scattered across four tools that did not compose: XLA
+The evidence for where a step's time goes was scattered across four
+tools that did not compose: XLA
 cost-analysis math lived only inside ``bench.py --compute``,
 ``tools/op_profile.py`` needed a manually captured trace, spans measure
 host wall only, and ``traffic_model()`` comm bytes were never
@@ -88,16 +88,10 @@ _DCN_BYTES_PER_SEC_DEFAULT = 25e9
 
 def link_bytes_per_sec(device=None) -> Optional[float]:
     """Per-chip ICI bytes/s for ``device`` (default: first visible);
-    None when unknown (CPU test meshes)."""
-    import jax
+    None off the TPU (CPU test meshes), an unknown TPU raises."""
+    from theanompi_tpu.utils.flops import match_device_table
 
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, bw in _LINK_BYTES_PER_SEC:
-        if key in kind:
-            return bw
-    return None
+    return match_device_table(_LINK_BYTES_PER_SEC, device)
 
 
 def dcn_bytes_per_sec() -> float:

@@ -133,25 +133,49 @@ def _quantize_block_jnp(x2d):
     return vals, scale
 
 
+# rows per grid step of the block kernels: 1024 x 128 lanes of f32 in,
+# int8 + one lane-padded scale column out ~= 1.2 MB of VMEM — a whole
+# leaf in one block is refused by Mosaic from 32 MB up (16 MB scoped
+# VMEM: an AlexNet fc leaf is 151 MB). Rows are independent (one scale
+# each), so a ragged last block is harmless: what it reads past the end
+# only reaches rows whose writes are dropped.
+_BLOCK_ROWS = 1024
+
+
+def _row_blocks(rows: int):
+    """``(block_rows, lanes_spec, scale_column_spec)`` of the row grid.
+    One block in interpreter mode (it pays per grid step, and has no
+    VMEM to respect) — the same policy as ops/pallas_update.py."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    block = rows if _interpret() else min(_BLOCK_ROWS, rows)
+    return (
+        block,
+        pl.BlockSpec((block, _LANES), lambda i: (i, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((block, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
+    )
+
+
 def quantize_int8_block(x2d: jax.Array):
     """``(rows, 128) f32 -> ((rows, 128) int8, (rows, 1) f32 scales)``
     with one absmax scale PER ROW (128-element block)."""
     if not _use_pallas():
         return _quantize_block_jnp(x2d)
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
+    rows = x2d.shape[0]
+    block, lanes, column = _row_blocks(rows)
     return pl.pallas_call(
         _quant_block_kernel,
         out_shape=(
             jax.ShapeDtypeStruct(x2d.shape, jnp.int8),
-            jax.ShapeDtypeStruct((x2d.shape[0], 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ),
+        grid=(pl.cdiv(rows, block),),
+        in_specs=[lanes],
+        out_specs=(lanes, column),
         interpret=_interpret(),
     )(x2d)
 
@@ -161,16 +185,15 @@ def dequantize_int8_block(vals: jax.Array, scales: jax.Array) -> jax.Array:
     if not _use_pallas():
         return vals.astype(jnp.float32) * scales
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
+    rows = vals.shape[0]
+    block, lanes, column = _row_blocks(rows)
     return pl.pallas_call(
         _dequant_block_kernel,
         out_shape=jax.ShapeDtypeStruct(vals.shape, jnp.float32),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        grid=(pl.cdiv(rows, block),),
+        in_specs=[lanes, column],
+        out_specs=lanes,
         interpret=_interpret(),
     )(vals, scales)
 

@@ -374,7 +374,9 @@ class GOSGDEngine:
             ef = jnp.zeros((self.n, flat_size), jnp.float32)
         return GOSGDState(
             workers=stack_replicas(ts, self.n),
-            alpha=jnp.full((self.n,), 1.0 / self.n),
+            # strongly typed, like the alpha every step returns: a
+            # weak-typed initial share retraces the step on its 2nd call
+            alpha=jnp.full((self.n,), 1.0 / self.n, jnp.float32),
             ef=ef,
         )
 

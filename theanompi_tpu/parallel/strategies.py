@@ -583,7 +583,11 @@ class BucketedOverlapSync:
         leaves, treedef = jax.tree_util.tree_flatten(params)
         out = list(leaves)
         tag = self._make_tag()
-        for idx in assign_buckets(leaves, self.bucket_bytes):
+        # tagged LAST bucket first: jax's backward runs the tags' rules
+        # in the order the tags were applied, and the collectives must
+        # be posted in gradient-PRODUCTION order (output-layer leaves
+        # first) — the bsp_bucketed golden pins that schedule
+        for idx in reversed(assign_buckets(leaves, self.bucket_bytes)):
             tagged = tag(*[leaves[i] for i in idx])
             for j, i in enumerate(idx):
                 out[i] = tagged[j]

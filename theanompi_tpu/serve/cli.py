@@ -172,11 +172,29 @@ def _resolve_serve_model(spec: str, recipe_args: list):
     return cls(recipe)
 
 
+def replica_sharding(rid, base=None):
+    """The serving recipe of fleet member ``rid``: its own local device,
+    ``rid % n_local`` — an N-replica fleet on a four-chip host must not
+    sit on chip 0. A single engine (``rid`` None) and tensor-sharded
+    serving (``base`` spans every local device already) keep ``base``."""
+    if rid is None or base is not None:
+        return base
+    import jax
+
+    from theanompi_tpu.parallel.recipe import ShardingRecipe
+
+    local = jax.local_devices()
+    return ShardingRecipe.serve(device=local[rid % len(local)])
+
+
 def serve_main(argv=None) -> int:
     args = build_serve_parser().parse_args(argv)
 
     from theanompi_tpu.serve.engine import ServeEngine
     from theanompi_tpu.serve.reload import CheckpointReloader
+    from theanompi_tpu.utils.compile_cache import CompileClock
+
+    compile_clock = CompileClock()
 
     model = _resolve_serve_model(args.model, args.recipe_arg)
     buckets = tuple(int(b) for b in args.buckets.split(","))
@@ -209,7 +227,7 @@ def serve_main(argv=None) -> int:
                 replica_id=rid,
                 sink_name=("decode.jsonl" if rid is None
                            else f"decode_r{rid}.jsonl"),
-                sharding=sharding,
+                sharding=replica_sharding(rid, sharding),
             )
 
         engine_kind, program_note = "decode", (
@@ -225,6 +243,7 @@ def serve_main(argv=None) -> int:
                 replica_id=rid,
                 sink_name=("serve.jsonl" if rid is None
                            else f"serve_r{rid}.jsonl"),
+                sharding=replica_sharding(rid),
             )
 
         engine_kind, program_note = "serve", f"buckets {buckets}"
@@ -236,6 +255,11 @@ def serve_main(argv=None) -> int:
         print(f"[serve] {engine_kind} engine: {model.name} step {step}; "
               f"{compiled} programs AOT-warmed ({program_note})",
               flush=True)
+        # where the served params actually live, read off the placed
+        # arrays (chip_smoke.py checks it) + what the warm-up compiled
+        print("[serve] placement " + json.dumps(
+            {**engine.params_device(), **compile_clock.report()}),
+            flush=True)
         engine.start()
         final_record = (engine.decode_record if args.decode
                         else engine.serve_record)
@@ -285,18 +309,28 @@ def serve_main(argv=None) -> int:
 
             rng = np.random.RandomState(0)
             if args.decode:
-                # decode selftest: mixed-length int32 prompts exercise
-                # every prefill bucket plus the shared decode program
+                # decode selftest: a 1-token prompt (decode program
+                # only), then the longest prompt each prefill bucket
+                # admits (its last token rides the decode step), in
+                # rotation — len(buckets) + 1 requests run every program
                 vocab = int(model.recipe.num_classes)
-                top = max(int(b) for b in args.prefill_buckets.split(",")) + 1
+                sizes = [1] + [b + 1 for b in prefill_buckets]
                 for i in range(args.selftest):
-                    n = 1 + (i * 3) % top
-                    engine.infer(rng.randint(0, vocab, size=n, dtype=np.int32))
+                    n = sizes[i % len(sizes)]
+                    res = engine.infer(
+                        rng.randint(0, vocab, size=n, dtype=np.int32))
+                    print(f"[serve.selftest] request {i}: prompt {n} -> "
+                          f"{len(res.tokens)} tokens at step {res.step}",
+                          flush=True)
             else:
                 shape = tuple(model.recipe.input_shape)
                 for _ in range(args.selftest):
                     engine.infer(rng.randn(*shape))
             _shutdown()
+            if replicas == 1:
+                # still the warm-up's count: no request retraced anything
+                print(f"[serve.selftest] {engine.compile_count} programs "
+                      "traced in all", flush=True)
             # LAST stdout line = one schema-valid stats record
             # (kind=serve/decode, or kind=router for a replica fleet)
             print(json.dumps(final_record()))

@@ -237,6 +237,12 @@ class DecodeEngine:
         # ``tmpi serve --decode --shard tensor`` passes the tensor-serve
         # recipe here instead of the replicated default
         self.sharding = sharding if sharding is not None else ShardingRecipe.serve()
+        # the KV pool lives where the params live, committed like them:
+        # a pool left on the process-default device would change
+        # placement (and retrace every program) the first time a step
+        # returns it from the params' device
+        self._cache.k_pool, self._cache.v_pool = self.sharding.place_replicated(
+            (self._cache.k_pool, self._cache.v_pool))
 
         self._served: Optional[ServedParams] = None
         self._swap_lock = threading.Lock()
@@ -316,6 +322,10 @@ class DecodeEngine:
         """Checkpoint step currently served (-1 before load_initial)."""
         served = self._served
         return served.step if served is not None else -1
+
+    def params_device(self) -> dict:
+        """Platform, kind and device ids holding the served params."""
+        return self._served.device()
 
     def load_initial(self, ckpt_dir: str) -> int:
         """Load the newest VERIFIED checkpoint from a training run's

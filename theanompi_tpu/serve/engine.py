@@ -115,6 +115,19 @@ class ServedParams(NamedTuple):
     model_state: object
     step: int
 
+    def device(self) -> dict:
+        """Where the params live, read off the placed arrays (not off
+        what placement was asked for)."""
+        import jax
+
+        devs = sorted(
+            {d for leaf in jax.tree_util.tree_leaves(self.params)
+             for d in leaf.devices()},
+            key=lambda d: d.id,
+        )
+        return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                "device_ids": [d.id for d in devs]}
+
 
 class ServeResult(NamedTuple):
     """Per-request result: the logits row and the checkpoint step of
@@ -191,7 +204,9 @@ class ServeEngine:
     record so a fleet's obs streams attribute to the member.
     ``sink_name``: the JSONL file under ``obs_dir`` (replica members
     write ``serve_r<id>.jsonl`` so N members never interleave one
-    file).
+    file). ``sharding``: the serving :class:`~theanompi_tpu.parallel.
+    recipe.ShardingRecipe` (default: replicated on the first device; a
+    fleet member passes the recipe of its own chip).
     """
 
     def __init__(
@@ -206,6 +221,7 @@ class ServeEngine:
         record_every: int = 50,
         replica_id: Optional[int] = None,
         sink_name: str = "serve.jsonl",
+        sharding=None,
     ):
         from theanompi_tpu.models.zoo import infer_fn
         from theanompi_tpu.obs.metrics import MetricsRegistry
@@ -253,7 +269,7 @@ class ServeEngine:
         # ``__topology__`` specs, and the placement set_params uses
         from theanompi_tpu.parallel.recipe import ShardingRecipe
 
-        self.sharding = ShardingRecipe.serve()
+        self.sharding = sharding if sharding is not None else ShardingRecipe.serve()
 
         self._served: Optional[ServedParams] = None
         self._swap_lock = threading.Lock()
@@ -306,6 +322,10 @@ class ServeEngine:
         served = self._served
         return served.step if served is not None else -1
 
+    def params_device(self) -> dict:
+        """Platform, kind and device ids holding the served params."""
+        return self._served.device()
+
     def load_initial(self, ckpt_dir: str) -> int:
         """Load the newest VERIFIED checkpoint from a training run's
         keep-chain (the same discovery resume uses) and serve it."""
@@ -317,7 +337,9 @@ class ServeEngine:
             raise FileNotFoundError(
                 f"no verified checkpoint under {ckpt_dir!r} to serve"
             )
-        params, model_state, step = load_for_serving(path, self.model)
+        params, model_state, step = load_for_serving(
+            path, self.model, target_mesh=self.sharding.mesh
+        )
         self.set_params(params, model_state, step)
         return step
 
@@ -334,9 +356,7 @@ class ServeEngine:
         current = self._served
         if current is not None and step <= current.step:
             return False
-        # placement per the serving recipe (replicated; plain
-        # device_put on the single-device mesh — see
-        # ShardingRecipe.place_replicated)
+        # placement per the serving recipe (replicated on ITS mesh)
         params = self.sharding.place_replicated(params)
         model_state = self.sharding.place_replicated(model_state)
         with self._swap_lock:

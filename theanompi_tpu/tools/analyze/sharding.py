@@ -167,6 +167,22 @@ def hlo_collectives(hlo_text: str, default_group: int = 2) -> list:
             _type_bytes(f"{dt}[{dims}]")
             for dt, dims in _SHAPE_RE.findall(result_t)
         ]
+        gm = _GROUPS_RE.search(line)
+        if gm:
+            group = len([x for x in gm.group(1).split(",") if x.strip()])
+        else:
+            gi = _GROUPS_IOTA_RE.search(line)
+            group = int(gi.group(2)) if gi else default_group
+        if not operand_b and member_bytes:
+            # this XLA prints operands by name only (`(%param.1)`), no
+            # types: recover the operand size from the result — equal
+            # for all-reduce/permute, result x group for the full
+            # pre-scatter buffer, result / group for a gather's shard
+            # (a start's tuple result holds the destination as its
+            # largest member)
+            dest = max(member_bytes) if is_start else sum(member_bytes)
+            operand_b = {"reduce-scatter": dest * group,
+                         "all-gather": dest / group}.get(kind, dest)
         if is_start:
             if kind in ("all-gather", "all-to-all"):
                 result_b = max(member_bytes) if member_bytes else operand_b
@@ -174,12 +190,6 @@ def hlo_collectives(hlo_text: str, default_group: int = 2) -> list:
                 result_b = operand_b
         else:
             result_b = sum(member_bytes)
-        gm = _GROUPS_RE.search(line)
-        if gm:
-            group = len([x for x in gm.group(1).split(",") if x.strip()])
-        else:
-            gi = _GROUPS_IOTA_RE.search(line)
-            group = int(gi.group(2)) if gi else default_group
         out.append(HloCollective(
             kind=kind, result_bytes=result_b,
             operand_bytes=operand_b, group_size=group,

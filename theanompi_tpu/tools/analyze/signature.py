@@ -17,7 +17,7 @@ will post, with enough detail to verify them:
 
 Alongside the signature the walk runs a replicated-vs-varying dataflow
 analysis — the classic SPMD uniformity question. Seeds: ``shard_map``
-invars with non-empty ``in_names`` are varying (each device holds a
+invars whose ``in_specs`` entry names a mesh axis are varying (each device holds a
 different shard), ``axis_index``/``ppermute``/``reduce_scatter``/
 ``all_to_all`` outputs are varying; ``psum``/``all_gather``/``pmin``/
 ``pmax`` outputs are uniform (every rank computes the same value).
@@ -169,6 +169,11 @@ def _eqn_is_quant_marker(eqn) -> bool:
     return False
 
 
+def _spec_shards(spec) -> bool:
+    """Does a shard_map PartitionSpec shard ANY dim over a mesh axis?"""
+    return any(entry is not None for entry in spec)
+
+
 class _Walker:
     """Recursive jaxpr walk threading three per-var facts: ``varying``
     (may differ across ranks) and ``quant`` (low-bit evidence
@@ -203,7 +208,7 @@ class _Walker:
             if name == "shard_map":
                 self._walk_shard_map(eqn, varying, quant, mult)
                 continue
-            if name == "pjit":
+            if name == "jit":
                 self._walk_mapped(eqn.params["jaxpr"].jaxpr, eqn, varying,
                                   quant, mult)
                 continue
@@ -300,20 +305,20 @@ class _Walker:
                 self.axis_sizes.update(dict(mesh.shape))
             except Exception:  # noqa: BLE001
                 pass
-        in_names = eqn.params.get("in_names", ())
+        in_specs = eqn.params.get("in_specs", ())
         sv, sq = {}, {}
         for i, si in enumerate(body.invars):
-            names = in_names[i] if i < len(in_names) else {}
-            sharded = bool(names)  # any named axis -> per-device shard
+            # any named axis in the PartitionSpec -> per-device shard
+            sharded = i < len(in_specs) and _spec_shards(in_specs[i])
             oi = eqn.invars[i] if i < len(eqn.invars) else None
             self._set(sv, si, sharded or (oi is not None
                                           and self._get(varying, oi)))
             self._set(sq, si, oi is not None and self._get(quant, oi))
         self.walk(body, sv, sq, mult)
-        out_names = eqn.params.get("out_names", ())
+        out_specs = eqn.params.get("out_specs", ())
         for i, ov in enumerate(eqn.outvars):
-            names = out_names[i] if i < len(out_names) else {}
-            self._set(varying, ov, bool(names))
+            self._set(varying, ov,
+                      i < len(out_specs) and _spec_shards(out_specs[i]))
             self._set(quant, ov, False)
 
     def _extract_branch(self, branch, eqn, varying, quant, mult):
@@ -556,13 +561,13 @@ def has_quantized_collective(sig: Signature) -> bool:
 
 
 def donated_flags(closed_jaxpr, n_leading: Optional[int] = None) -> tuple:
-    """The ``donated_invars`` tuple of the outermost pjit equation (the
+    """The ``donated_invars`` tuple of the outermost jit equation (the
     jitted step), optionally truncated to the first ``n_leading``
     entries (= the flattened state argument's leaves)."""
     jaxpr = closed_jaxpr.jaxpr if hasattr(closed_jaxpr, "jaxpr") else \
         closed_jaxpr
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             d = tuple(eqn.params.get("donated_invars", ()))
             return d[:n_leading] if n_leading is not None else d
     return ()

@@ -405,7 +405,6 @@ def _repo_root() -> str:
 def _subprocess_env(mutate: Optional[str]) -> dict:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["TMPI_FORCE_PLATFORM"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (

@@ -320,8 +320,7 @@ def _ensure_virtual_devices() -> None:
     setting it here works as long as nothing touched devices yet, and
     is a harmless no-op under pytest's conftest (backend already up
     with 8 virtual devices and the same flag)."""
-    os.environ.setdefault(
-        "JAX_PLATFORMS", os.environ.get("TMPI_FORCE_PLATFORM") or "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

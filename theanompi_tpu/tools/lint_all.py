@@ -33,7 +33,10 @@ from typing import Optional
 # never telemetry; test fixtures under tests/ may hold deliberately
 # invalid lines for the schema checker's own tests
 _SKIP_DIRS = {".git", "__pycache__", ".jax_cache", "node_modules",
-              ".pytest_cache", "tests"}
+              ".pytest_cache", "tests", "chiprun_out", ".archive_check"}
+# JSONL at the repo root that is not this program's telemetry: the
+# driver's per-PR ledger has a schema of its own
+_SKIP_FILES = {"PERF_LEDGER.jsonl"}
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -51,6 +54,8 @@ def telemetry_files(paths: Optional[list] = None) -> list[str]:
         for dirpath, dirnames, filenames in os.walk(root):
             dirnames[:] = [d for d in dirnames if d not in _SKIP_DIRS]
             for name in sorted(filenames):
+                if name in _SKIP_FILES:
+                    continue
                 if name.endswith(".jsonl") or fnmatch.fnmatch(
                     name, "heartbeat_rank*.json"
                 ) or fnmatch.fnmatch(name, "stall_rank*.json"):

@@ -75,34 +75,46 @@ _HBM_CAPACITY = (
 )
 
 
-def _match_table(table, device) -> Optional[float]:
+def match_device_table(table, device=None) -> Optional[float]:
+    """First entry of a ``(kind_substring, value)`` table matching
+    ``device.device_kind`` (default: first visible device). None for a
+    device that is no TPU (CPU test meshes: consumers then calibrate or
+    skip the ratio); a TPU the table does not know RAISES — on the chip
+    a missing peak must not quietly turn MFU and the drift watchdog
+    into self-calibrated numbers."""
     import jax
 
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, peak in table:
-        if key in kind:
-            return peak
+    kind = getattr(device, "device_kind", "")
+    for key, value in table:
+        if key in kind.lower():
+            return value
+    if getattr(device, "platform", "") == "tpu":
+        raise ValueError(
+            f"TPU device_kind {kind!r} matches no entry of the spec table "
+            f"{[k for k, _ in table]} (theanompi_tpu/utils/flops.py, "
+            "obs/attribution.py): add its published peak"
+        )
     return None
 
 
 def peak_flops(device=None) -> Optional[float]:
     """Per-chip peak bf16 FLOP/s for ``device`` (default: first visible
     device); None when unknown (e.g. CPU)."""
-    return _match_table(_PEAK_BF16, device)
+    return match_device_table(_PEAK_BF16, device)
 
 
 def peak_hbm_bytes_per_sec(device=None) -> Optional[float]:
     """Per-chip peak HBM bytes/s (spec sheet); None when unknown."""
-    return _match_table(_PEAK_HBM, device)
+    return match_device_table(_PEAK_HBM, device)
 
 
 def hbm_capacity_bytes(device=None) -> Optional[float]:
     """Per-chip HBM capacity in bytes (spec sheet); None when unknown
     (e.g. CPU test meshes — the memory pre-flight then requires an
     explicit ``--budget-gb``)."""
-    return _match_table(_HBM_CAPACITY, device)
+    return match_device_table(_HBM_CAPACITY, device)
 
 
 @dataclass
@@ -172,28 +184,31 @@ def compiled_cost(jitted, *args, device=None, **kwargs) -> Optional[CostModel]:
     """:class:`CostModel` of one invocation of an already-jitted
     function, from XLA's cost analysis of the lowered+compiled program
     (abstract ``ShapeDtypeStruct`` args work — nothing executes). None
-    when the backend provides no cost model or the lowering fails."""
+    when the backend provides no cost model, or off the TPU when the
+    lowering fails; on a TPU a failed lowering raises."""
     import jax
 
+    if device is None:
+        device = jax.devices()[0]
     try:
         compiled = jitted.lower(*args, **kwargs).compile()
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = float(ca.get("flops", 0.0))
-        if flops <= 0:
-            return None
-        if device is None:
-            device = jax.devices()[0]
-        return CostModel(
-            flops=flops,
-            hbm_bytes=float(ca.get("bytes accessed", 0.0)),
-            device_kind=getattr(device, "device_kind", ""),
-            peak_flops_per_sec=peak_flops(device),
-            peak_hbm_bytes_per_sec=peak_hbm_bytes_per_sec(device),
-        )
     except Exception:
+        if device.platform == "tpu":
+            raise  # on the chip a step that does not lower is a fault
         return None
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0] if ca else {}
+    flops = float(ca.get("flops", 0.0))
+    if flops <= 0:
+        return None
+    return CostModel(
+        flops=flops,
+        hbm_bytes=float(ca.get("bytes accessed", 0.0)),
+        device_kind=getattr(device, "device_kind", ""),
+        peak_flops_per_sec=peak_flops(device),
+        peak_hbm_bytes_per_sec=peak_hbm_bytes_per_sec(device),
+    )
 
 
 def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:

@@ -3,8 +3,8 @@
 Round-3 verdict item 3: the CPU-mesh fixed-work audit (SCALING.json)
 bounds the framework's partition overhead, but says nothing about real
 ICI/DCN time at pod scale. This model predicts it from first principles
-so the 256-chip claim is FALSIFIABLE: every input is either a measured
-repo number (ZOO_BENCH.json single-chip step times), a public spec
+so the 256-chip claim is FALSIFIABLE: every input is either a
+single-chip throughput (STALE: see MODELS below), a public spec
 (bandwidths), or a stated assumption — change any input and the table
 recomputes (`python tools/scaling_model.py` writes SCALING_MODEL.json;
 prose + derivation in SCALING_MODEL.md).
@@ -60,8 +60,11 @@ BW_ICI = 90 * GB      # usable one-direction ICI B/s per chip (A4)
 BW_DCN = 3.1 * GB     # usable DCN B/s per chip (A4)
 OVERLAPS = (0.0, 0.7)  # A3
 
-# measured single-chip throughput (ZOO_BENCH round-4 refresh; img/s)
-# and the per-chip batch each config trains (reference configs)
+# single-chip throughput (img/s) and the per-chip batch each config
+# trains (reference configs). STALE INPUTS: these four img_s values were
+# taken on a development backend that no longer exists and their record
+# is deleted; on the current machine they are not measured. Replace them
+# from the first BENCHMARK.json cells before quoting a prediction.
 MODELS = {
     # name: (img_s_1chip at its bench batch, params, per-chip batch)
     "alexnet": dict(img_s=18605.0, params=61e6, b=128),     # config #2
