@@ -22,6 +22,7 @@ import pytest
 from tinymodel import TinyCNN
 from theanompi_tpu.launch.worker import run_training
 from theanompi_tpu.utils.dispatch import MetricsDispatcher
+from theanompi_tpu.utils.recorder import Recorder
 
 _TINY = dict(
     recipe_overrides={
@@ -37,12 +38,16 @@ _TINY = dict(
 
 # -- MetricsDispatcher unit tests (no jax needed: host arrays) --------------
 
-class FakeRecorder:
+class FakeRecorder(Recorder):
+    """The real brackets (the dispatcher's ``drain`` and ``emit`` spans
+    need them), with the step timings and rows kept as lists."""
+
     def __init__(self):
+        super().__init__(print_freq=0)
         self.times = []
         self.rows = []
 
-    def note_time(self, category, dt):
+    def note_time(self, category, dt, step=None):
         self.times.append((category, dt))
         return dt
 

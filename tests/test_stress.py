@@ -35,18 +35,21 @@ from theanompi_tpu.tools.analyze.stress import (
 )
 from theanompi_tpu.train import init_train_state
 from theanompi_tpu.utils.dispatch import MetricsDispatcher
+from theanompi_tpu.utils.recorder import Recorder
 
 WALL_BUDGET_S = 45.0  # per scenario; the whole module stays tier-1
 
 
-class _Rows:
-    """Minimal recorder stub: collects (step, metrics) rows."""
+class _Rows(Recorder):
+    """Minimal recorder: the real brackets (the dispatcher's spans),
+    collecting (step, metrics) rows."""
 
     def __init__(self):
+        super().__init__(print_freq=0)
         self.rows = []
         self.times = []
 
-    def note_time(self, name, dt):
+    def note_time(self, name, dt, step=None):
         self.times.append((name, dt))
 
     def train_metrics(self, step, metrics, n_images=0):

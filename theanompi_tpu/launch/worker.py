@@ -1042,6 +1042,26 @@ def run_training(
             f"{disp.depth} keeps extra params+opt copies live in HBM",
             flush=True,
         )
+
+    def _split_and_dispatch(step_fn, state, xs, ys, rng, n, stacked, numerics):
+        """The ``key_split`` and ``dispatch`` spans of one dispatch, for
+        both train loops: ``n`` sequential key splits (stacked for a
+        fused group), then the call of the step program, each a recorder
+        bracket under the dispatched group's last step number.
+        -> (state, metrics, rng)."""
+        last = step_count + n
+        rec.start("key_split")
+        subs = []
+        for _ in range(n):
+            rng, sub = jax.random.split(rng)
+            subs.append(sub)
+        keys = jnp.stack(subs) if stacked else subs[0]
+        rec.end("key_split", step=last)
+        rec.start("dispatch")
+        state, metrics = step_fn(state, xs, ys, keys, numerics=numerics)
+        rec.end("dispatch", step=last)
+        return state, metrics, rng
+
     train_loop_s = 0.0  # wall time inside the train loops (the
     # denominator of summary['host_blocked_frac'])
     # -- fault-tolerance state (fault-tolerant run supervisor PR) -------
@@ -1170,7 +1190,11 @@ def run_training(
                     for xs, ys in loader:
                         if _preempt["flag"]:
                             raise Preempted(step_count)
-                        disp.note_wait(rec.end("wait"))
+                        # the group's last step, after the trim below
+                        last = step_count + int(xs.shape[0])
+                        disp.note_wait(rec.end(
+                            "wait",
+                            step=min(last, max_steps) if max_steps else last))
                         if max_steps and step_count + xs.shape[0] > max_steps:
                             # trim the final group to land exactly on max_steps
                             keep = max_steps - step_count
@@ -1187,12 +1211,6 @@ def run_training(
                             xs = faults.poison_batch(
                                 xs, step_count + 1, step_count + g
                             )
-                        # the SAME sequential splits the per-step path draws,
-                        # shipped stacked — fused training is bit-identical
-                        subs = []
-                        for _ in range(g):
-                            rng, s = jax.random.split(rng)
-                            subs.append(s)
                         # numerics under fusion: the dispatch unit is
                         # the GROUP, so the cadence gates at group
                         # granularity — the numerics variant runs only
@@ -1205,9 +1223,11 @@ def run_training(
                         nm_group = bool(nfreq) and (
                             (step_count + g) // nfreq > step_count // nfreq
                         )
-                        state, metrics = engine.fused_train_step(
-                            state, xs, ys, jnp.stack(subs),
-                            numerics=nm_group,
+                        # the SAME sequential splits the per-step path draws,
+                        # shipped stacked — fused training is bit-identical
+                        state, metrics, rng = _split_and_dispatch(
+                            engine.fused_train_step, state, xs, ys, rng,
+                            g, True, nm_group,
                         )
                         step_count += g
                         epoch_steps += g
@@ -1229,7 +1249,7 @@ def run_training(
                     # would attribute it to the in-flight steps AND the
                     # wait bracket — double counting that breaks the
                     # span-fraction invariant
-                    disp.note_wait(rec.end("wait"))
+                    disp.note_wait(rec.end("wait", step=step_count + 1))
                 disp.flush()
                 rec.end_epoch(epoch, n_images=epoch_steps * batch)
             else:
@@ -1260,20 +1280,18 @@ def run_training(
                             continue
                         if _preempt["flag"]:
                             raise Preempted(step_count)
-                        disp.note_wait(rec.end("wait"))
+                        disp.note_wait(rec.end("wait", step=step_count + 1))
                         if faults is not None:
                             faults.check_step(step_count + 1)
                             xg = faults.poison_batch(xg, step_count + 1)
                         rec.profile_tick(step_count)
-                        rng, sub = jax.random.split(rng)
                         # sentinel cadence: every nfreq-th step runs the
                         # numerics variant of the SAME compiled step
                         # (extra scalar outputs; obs/numerics.py) — the
                         # scalars drain with the loss, no host sync here
-                        state, metrics = engine.train_step(
-                            state, xg, yg, sub,
-                            numerics=bool(nfreq)
-                            and (step_count + 1) % nfreq == 0,
+                        state, metrics, rng = _split_and_dispatch(
+                            engine.train_step, state, xg, yg, rng, 1, False,
+                            bool(nfreq) and (step_count + 1) % nfreq == 0,
                         )
                         step_count += 1
                         epoch_steps += 1
@@ -1300,7 +1318,8 @@ def run_training(
                             # collective's real cost bleeds into the next
                             # wait/step brackets
                             cdt = rec.end(
-                                "comm", sync=jax.tree_util.tree_leaves(state)[0]
+                                "comm", sync=jax.tree_util.tree_leaves(state)[0],
+                                step=step_count,
                             )
                             # the comm gauge's denominator includes the
                             # exchange's wall time on the steps that pay
@@ -1313,7 +1332,7 @@ def run_training(
                         if max_steps and step_count >= max_steps:
                             break
                     # credit the epoch-tail wait (see the fused path)
-                    disp.note_wait(rec.end("wait"))
+                    disp.note_wait(rec.end("wait", step=step_count + 1))
                 disp.flush()
                 rec.end_epoch(epoch, n_images=epoch_steps * batch)
 
@@ -1344,6 +1363,7 @@ def run_training(
                 "eval",
                 sync=None if val_accum is None
                 else jax.tree_util.tree_leaves(val_accum)[0],
+                step=step_count,
             )
             if n_val:
                 val_metrics = {k: float(v) / n_val for k, v in val_accum.items()}
@@ -1367,7 +1387,7 @@ def run_training(
                 else:
                     sync_save(ckpt_dir, state, step_count, rng=rng,
                               extra_meta=_save_meta(), topology=topo_meta)
-                rec.end("checkpoint")
+                rec.end("checkpoint", step=step_count)
                 last_ckpt_step = step_count
                 if faults is not None:
                     # post-save storage mutations (ckpt_truncate /

@@ -12,7 +12,17 @@ Span kinds used by the training stack (callers may add their own):
 ``checkpoint`` — plus the nested ``checkpoint_gather`` /
 ``checkpoint_write`` sub-spans utils/checkpoint.py opens inside a save
 (named apart so a synchronous save does not count the same wall time
-twice under one kind). Schema: tools/check_obs_schema.py.
+twice under one kind), and the driver's phases inside a step's
+amortized window, ``key_split``, ``dispatch``, ``drain`` and ``emit``
+(depth 1: children of ``step``, so they stay out of the fractions).
+Schema: tools/check_obs_schema.py.
+
+Clock: ``t0`` is seconds of ``time.time_ns()``, the clock a profiler
+trace is written in, and ``dur`` the difference of two reads of it. The
+Recorder's brackets (utils/recorder.py) hand their own two stamps to
+``begin``/``finish``, so a bracket reads the clock twice whether this
+sink is attached or not; a span of the training loop carries the
+``step`` it belongs to.
 
 Fraction semantics: the summary's ``fractions`` divide per-kind
 EXCLUSIVE top-level time by the recorder's open→close wall clock, and
@@ -38,7 +48,8 @@ import time
 from contextlib import contextmanager
 from typing import Optional
 
-SPAN_KINDS = ("data_wait", "h2d", "step", "grad_sync", "eval", "checkpoint")
+SPAN_KINDS = ("data_wait", "h2d", "step", "grad_sync", "eval", "checkpoint",
+              "key_split", "dispatch", "drain", "emit")
 
 
 class SpanRecorder:
@@ -50,8 +61,7 @@ class SpanRecorder:
         self._wlock = threading.Lock()
         self._stacks = threading.local()  # per-thread open-span stack
         self._owner = threading.get_ident()
-        self._t_open = time.perf_counter()
-        self._t_open_wall = time.time()
+        self._t_open_ns = time.time_ns()
         # totals over ALL spans / owner-thread depth-0 spans respectively
         self._totals: dict[str, float] = {}
         self._counts: dict[str, int] = {}
@@ -64,19 +74,24 @@ class SpanRecorder:
         return self._stacks.s
 
     # -- explicit begin/finish (the Recorder bracket bridge) ----------------
-    def begin(self, name: str) -> dict:
+    def begin(self, name: str, t0_ns: Optional[int] = None,
+              under: int = 0) -> dict:
+        """Open a span. ``t0_ns``: the caller's own ``time.time_ns()``
+        stamp (the Recorder's bracket), else the clock is read here.
+        ``under``: levels of parents that are not on the stack (the
+        amortized ``step`` a driver phase belongs to)."""
         stack = self._stack()
         token = {
             "name": str(name),
-            "t0": time.perf_counter(),
-            "t0_wall": time.time(),
-            "depth": len(stack),
+            "t0_ns": time.time_ns() if t0_ns is None else t0_ns,
+            "depth": len(stack) + under,
             "thread": threading.get_ident(),
         }
         stack.append(token)
         return token
 
-    def finish(self, token: dict) -> float:
+    def finish(self, token: dict, t1_ns: Optional[int] = None,
+               step: Optional[int] = None) -> float:
         stack = self._stack()
         if any(t is token for t in stack):
             # tolerate out-of-order finishes (a bracket leaked across an
@@ -86,16 +101,19 @@ class SpanRecorder:
             stack.pop()
         # a token not on the stack (double finish / cross-thread) still
         # records its span but must not disturb other threads' nesting
-        dur = time.perf_counter() - token["t0"]
+        t1_ns = time.time_ns() if t1_ns is None else t1_ns
+        dur = max(0, t1_ns - token["t0_ns"]) * 1e-9
         name = token["name"]
         rec = {
             "kind": "span",
             "name": name,
             "rank": self.rank,
-            "t0": token["t0_wall"],
+            "t0": token["t0_ns"] * 1e-9,
             "dur": dur,
             "depth": token["depth"],
         }
+        if step is not None:
+            rec["step"] = int(step)
         with self._wlock:
             if not self._closed:
                 self._f.write(json.dumps(rec) + "\n")
@@ -105,7 +123,8 @@ class SpanRecorder:
                 self._owner_top[name] = self._owner_top.get(name, 0.0) + dur
         return dur
 
-    def note(self, name: str, dur: float, t0_wall: Optional[float] = None) -> None:
+    def note(self, name: str, dur: float, t0_wall: Optional[float] = None,
+             step: Optional[int] = None) -> None:
         """Record a span measured EXTERNALLY (no begin/finish pair) —
         the dispatch pipeline's amortized step windows
         (utils/dispatch.py). Attributed to the calling thread at depth
@@ -121,11 +140,13 @@ class SpanRecorder:
             "kind": "span",
             "name": name,
             "rank": self.rank,
-            "t0": (time.time() - dur) if t0_wall is None else t0_wall,
+            "t0": (time.time_ns() * 1e-9 - dur) if t0_wall is None else t0_wall,
             "dur": dur,
             "depth": 0,
             "amortized": True,
         }
+        if step is not None:
+            rec["step"] = int(step)
         with self._wlock:
             if not self._closed:
                 self._f.write(json.dumps(rec) + "\n")
@@ -144,7 +165,7 @@ class SpanRecorder:
 
     # -- run-end summary ----------------------------------------------------
     def summary(self) -> dict:
-        wall = max(time.perf_counter() - self._t_open, 1e-9)
+        wall = max((time.time_ns() - self._t_open_ns) * 1e-9, 1e-9)
         with self._wlock:
             fractions = {
                 k: min(v / wall, 1.0) for k, v in sorted(self._owner_top.items())
@@ -152,7 +173,7 @@ class SpanRecorder:
             rec = {
                 "kind": "span_summary",
                 "rank": self.rank,
-                "t0": self._t_open_wall,
+                "t0": self._t_open_ns * 1e-9,
                 "wall_s": wall,
                 "fractions": fractions,
                 "totals_s": dict(sorted(self._totals.items())),

@@ -119,6 +119,9 @@ def _q_block_start(cfg: _Cfg, j, q_off, k_off):
     return jnp.maximum(0, (k_off + j * cfg.BK - q_off) // cfg.BQ)
 
 
+FWD_NAME = "flash_fwd"  # the kernel's name in a device trace
+
+
 def _fwd_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
     i = pl.program_id(1)
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
@@ -162,6 +165,9 @@ def _fwd_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
     lse_ref[0] = m + jnp.log(l_safe)  # [BQ, 1]
 
 
+DQ_NAME = "flash_bwd_dq"  # the kernel's name in a device trace
+
+
 def _dq_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                lse_ref, dsum_ref, dq_ref):
     i = pl.program_id(1)
@@ -193,6 +199,9 @@ def _dq_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         jnp.zeros(q.shape, jnp.float32),
     )
     dq_ref[0] = dq  # f32: ring hops accumulate partials losslessly
+
+
+DKV_NAME = "flash_bwd_dkv"  # the kernel's name in a device trace
 
 
 def _dkv_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref, dsum_ref,
@@ -252,6 +261,9 @@ def _dkv_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref, dsum_ref,
 _BWD_2D_MIN_T = 8192
 
 
+DQ_2D_NAME = "flash_bwd_dq_2d"  # the kernel's name in a device trace
+
+
 def _dq_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                   lse_ref, dsum_ref, dq_ref):
     """dq with BOTH sides blocked: grid (BH, q blocks, k blocks), the
@@ -288,6 +300,9 @@ def _dq_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         dq_ref[0] += lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
+
+
+DKV_2D_NAME = "flash_bwd_dkv_2d"  # the kernel's name in a device trace
 
 
 def _dkv_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref,
@@ -406,6 +421,7 @@ def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
             jax.ShapeDtypeStruct((BH, Tqp, D), q3.dtype),
             jax.ShapeDtypeStruct((BH, Tqp, 1), jnp.float32),
         ),
+        name=FWD_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, k3, v3)
     return o, lse
@@ -429,6 +445,7 @@ def _dq_call(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
         ],
         out_specs=_q_major((1, cfg.BQ, D)),
         out_shape=jax.ShapeDtypeStruct((BH, Tqp, D), jnp.float32),
+        name=DQ_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, k3, v3, g, lse, dsum)
 
@@ -454,6 +471,7 @@ def _dkv_call(cfg: _Cfg, q3, g, lse, dsum, k3, v3, q_off, k_off):
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
         ),
+        name=DKV_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, g, lse, dsum, k3, v3)
 
@@ -483,6 +501,7 @@ def _dq_call_2d(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
         ],
         out_specs=_by("x", (1, cfg.BQ, D)),   # revisited over the k dim
         out_shape=jax.ShapeDtypeStruct((BH, Tqp, D), jnp.float32),
+        name=DQ_2D_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, k3, v3, g, lse, dsum)
 
@@ -507,6 +526,7 @@ def _dkv_call_2d(cfg: _Cfg, q3, g, lse, dsum, k3, v3, q_off, k_off):
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
         ),
+        name=DKV_2D_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, g, lse, dsum, k3, v3)
 

@@ -93,10 +93,16 @@ def _shift_max(xp, H, W):
     return y
 
 
+FWD_NAME = "pool3x3_fwd"  # the kernel's name in a device trace
+
+
 def _fwd_kernel(x_ref, y_ref, *, H, W):
     x = x_ref[:]
     xp = _frame(x, _ninf(x.dtype))
     y_ref[:] = _shift_max(xp, H, W)
+
+
+BWD_NAME = "pool3x3_bwd"  # the kernel's name in a device trace
 
 
 def _bwd_kernel(x_ref, y_ref, g_ref, dx_ref, *, H, W):
@@ -139,6 +145,7 @@ def _pallas_fwd(x):
         in_specs=[spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name=FWD_NAME,
         interpret=_interpret(),
     )(x)
 
@@ -155,6 +162,7 @@ def _pallas_bwd(x, y, g):
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name=BWD_NAME,
         interpret=_interpret(),
     )(x, y, g)
 

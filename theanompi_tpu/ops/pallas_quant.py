@@ -43,6 +43,9 @@ _LANES = 128
 _SCALES_PER_ROW = _LANES // 4
 
 
+QUANT_NAME = "quant_int8"  # the kernel's name in a device trace
+
+
 def _quant_kernel(x_ref, vals_ref, scale_ref):
     amax = jnp.max(jnp.abs(x_ref[:]))
     scale = jnp.maximum(amax, 1e-30) / 127.0
@@ -50,6 +53,9 @@ def _quant_kernel(x_ref, vals_ref, scale_ref):
     scaled = x_ref[:] / scale
     # round-to-nearest-even, clamp to int8 range
     vals_ref[:] = jnp.clip(jnp.round(scaled), -127, 127).astype(jnp.int8)
+
+
+DEQUANT_NAME = "dequant_int8"  # the kernel's name in a device trace
 
 
 def _dequant_kernel(vals_ref, scale_ref, out_ref):
@@ -82,6 +88,7 @@ def quantize_int8(x2d: jax.Array):
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
+        name=QUANT_NAME,
         interpret=_interpret(),
     )(x2d)
 
@@ -101,6 +108,7 @@ def dequantize_int8(vals: jax.Array, scale: jax.Array) -> jax.Array:
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        name=DEQUANT_NAME,
         interpret=_interpret(),
     )(vals, scale)
 
@@ -109,6 +117,9 @@ def dequantize_int8(vals: jax.Array, scale: jax.Array) -> jax.Array:
 # block-scaled variants: one absmax scale per (1, 128) lane row — the
 # per-leaf block quantizer the codec layer builds on
 # --------------------------------------------------------------------------
+
+
+QUANT_BLOCK_NAME = "quant_int8_block"  # the kernel's name in a device trace
 
 
 def _quant_block_kernel(x_ref, vals_ref, scale_ref):
@@ -120,6 +131,9 @@ def _quant_block_kernel(x_ref, vals_ref, scale_ref):
     vals_ref[:] = jnp.clip(jnp.round(x_ref[:] / scale), -127, 127).astype(
         jnp.int8
     )
+
+
+DEQUANT_BLOCK_NAME = "dequant_int8_block"  # the kernel's name in a device trace
 
 
 def _dequant_block_kernel(vals_ref, scale_ref, out_ref):
@@ -176,6 +190,7 @@ def quantize_int8_block(x2d: jax.Array):
         grid=(pl.cdiv(rows, block),),
         in_specs=[lanes],
         out_specs=(lanes, column),
+        name=QUANT_BLOCK_NAME,
         interpret=_interpret(),
     )(x2d)
 
@@ -194,6 +209,7 @@ def dequantize_int8_block(vals: jax.Array, scales: jax.Array) -> jax.Array:
         grid=(pl.cdiv(rows, block),),
         in_specs=[lanes, column],
         out_specs=lanes,
+        name=DEQUANT_BLOCK_NAME,
         interpret=_interpret(),
     )(vals, scales)
 

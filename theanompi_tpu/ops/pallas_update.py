@@ -75,6 +75,9 @@ def _block_rows(rows: int) -> int:
 # --------------------------------------------------------------------------
 
 
+MOMENTUM_NAME = "update_momentum"  # the kernel's name in a device trace
+
+
 def _momentum_kernel(p_ref, v_ref, g_ref, sc_ref, p_out, v_out, *,
                      momentum, weight_decay, nesterov):
     lr = sc_ref[0, 0]
@@ -85,6 +88,9 @@ def _momentum_kernel(p_ref, v_ref, g_ref, sc_ref, p_out, v_out, *,
     v_out[:] = v
     step = momentum * v - lr * g if nesterov else v
     p_out[:] = (p + step).astype(p_out.dtype)
+
+
+SGD_NAME = "update_sgd"  # the kernel's name in a device trace
 
 
 def _sgd_kernel(p_ref, g_ref, sc_ref, p_out, *, weight_decay):
@@ -150,6 +156,7 @@ def fused_update_leaf(p, v, g, lr, clip_coef, *, momentum: float,
         # in-place: the param and velocity buffers are rewritten, not
         # copied — the donation that makes this ONE HBM round-trip
         input_output_aliases={0: 0, 1: 1},
+        name=MOMENTUM_NAME,
         interpret=_interpret(),
     )(p2, v2, g2, _scalars(lr, clip_coef))
     L = math.prod(shape) if shape else 1
@@ -182,6 +189,7 @@ def fused_sgd_leaf(p, g, lr, clip_coef, *, weight_decay: float):
         in_specs=[vspec, vspec, sspec],
         out_specs=vspec,
         input_output_aliases={0: 0},
+        name=SGD_NAME,
         interpret=_interpret(),
     )(p2, g2, _scalars(lr, clip_coef))
     L = math.prod(shape) if shape else 1

@@ -8,7 +8,8 @@ files into ONE trace viewable in ``chrome://tracing`` / Perfetto /
 - one trace **process** per rank (``pid = rank``), named ``rank {r}``;
 - bracketed spans on thread 0 (``spans``) as complete ``"ph": "X"``
   events — nesting renders from the timestamps, ``depth`` rides in
-  ``args``;
+  ``args``, and so does the ``step`` number of a train-loop span
+  (``data_wait``, ``key_split``, ``dispatch``, ``drain``, ``emit``);
 - ``amortized`` spans (the dispatch pipeline's attributed step windows,
   utils/dispatch.py) on their OWN lane (thread 1, ``amortized``),
   flagged in ``args`` — attributed time is not a measured bracket and
@@ -23,8 +24,10 @@ Usage::
     python -m theanompi_tpu.tools.spans_to_trace spans_rank0.jsonl ...
 
 Directories are searched for ``spans_rank*.jsonl``. Timestamps are the
-span log's wall-clock ``t0`` (seconds) converted to microseconds, so
-multi-rank traces align on real time.
+span log's wall-clock ``t0`` (seconds of ``time.time_ns()``, the clock a
+``jax.profiler`` trace counts in from its ``profile_start_time``)
+converted to microseconds, so multi-rank traces align on real time and a
+span can be laid beside a device trace of the same run.
 
 Multi-rank merges additionally get **clock alignment** (on by default,
 ``--no-align`` to keep raw wall clocks): per-host clocks skew, so raw
@@ -143,7 +146,9 @@ def convert(paths: list[str], align: bool = True) -> dict:
                         "pid": rank,
                         "tid": 1 if amortized else 0,
                         "args": {"depth": row.get("depth", 0),
-                                 "amortized": amortized},
+                                 "amortized": amortized,
+                                 **({"step": row["step"]}
+                                    if "step" in row else {})},
                     })
                     seen_ranks.add(rank)
                 elif kind == "span_summary":

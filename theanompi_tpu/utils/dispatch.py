@@ -35,12 +35,18 @@ streams bit-identical modulo the wall-clock ``images_per_sec`` field.
 ``host_blocked_s`` accumulates the time the host actually spent blocked
 inside drains — ``host_blocked_frac`` in the run summary / bench output
 is this over the train-loop wall time, the direct measurement of the
-per-step host tax this module exists to remove.
+per-step host tax this module exists to remove. It is the sum of the
+recorder's ``drain`` brackets, not a timer of its own.
+
+Spans (utils/recorder.py ``SpanRing``): the blocking D2H is the
+``drain`` bracket and the row's emission (recorder row, ``on_row``, the
+print) the ``emit`` bracket, each under the number of the step it
+drains — at depth 2 the step before the one just dispatched. The
+amortized windows close on the drain bracket's own end stamp.
 """
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Callable, Optional
 
@@ -80,7 +86,7 @@ class MetricsDispatcher:
         self.rec = recorder
         self.depth = max(1, int(depth))
         self._buf: deque = deque()
-        self._t_mark: Optional[float] = None
+        self._t_mark: Optional[int] = None  # ns on the recorder's clock
         self._wait_s = 0.0
         self._on_step_seconds = on_step_seconds
         # per-emitted-row hook ``(step, metrics, numerics)`` — the obs
@@ -121,7 +127,7 @@ class MetricsDispatcher:
             # window opens at the first in-flight push; waits before it
             # (epoch-boundary eval/checkpoint, first batch load) are not
             # part of any step's attribution
-            self._t_mark = time.perf_counter()
+            self._t_mark = self.rec.clock_ns()
             self._wait_s = 0.0
         self._buf.append((int(step), metrics, int(n_images), max(1, int(substeps))))
         while len(self._buf) >= self.depth:
@@ -144,7 +150,7 @@ class MetricsDispatcher:
             return
         entries = list(self._buf)
         self._buf.clear()
-        t0 = time.perf_counter()
+        self.rec.start("drain")
         err: Optional[Exception] = None
         try:
             _block_on(entries[-1][1])
@@ -154,10 +160,11 @@ class MetricsDispatcher:
             # have completed fine; persist their rows (exactly what
             # depth=1 would already have written) before re-raising
             err = e
-        now = time.perf_counter()
-        self.host_blocked_s += now - t0
+        # one block for all the entries: under the newest step's number
+        self.host_blocked_s += self.rec.end("drain", step=entries[-1][0])
         self.n_syncs += 1
-        total = max(0.0, (now - self._t_mark) - self._wait_s)
+        total = max(
+            0.0, (self.rec.t_end_ns - self._t_mark) * 1e-9 - self._wait_s)
         per_entry = total / len(entries)
         self._t_mark = None
         self._wait_s = 0.0
@@ -170,7 +177,7 @@ class MetricsDispatcher:
                 except Exception:  # noqa: BLE001
                     raise err
             self.last_step_seconds = per_entry / substeps
-            self.rec.note_time("step", per_entry)
+            self.rec.note_time("step", per_entry, step=step)
             self._emit_rows(step, metrics, n_images, substeps)
         if err is not None:
             raise err
@@ -190,22 +197,32 @@ class MetricsDispatcher:
     # -- internals -----------------------------------------------------------
     def _drain_one(self) -> None:
         step, metrics, n_images, substeps = self._buf.popleft()
-        t0 = time.perf_counter()
+        self.rec.start("drain")
         host = {k: np.asarray(v) for k, v in metrics.items()}  # D2H sync
-        now = time.perf_counter()
-        self.host_blocked_s += now - t0
+        self.host_blocked_s += self.rec.end("drain", step=step)
+        now = self.rec.t_end_ns
         self.n_syncs += 1
-        dt = max(0.0, (now - self._t_mark) - self._wait_s)
+        dt = max(0.0, (now - self._t_mark) * 1e-9 - self._wait_s)
         self._t_mark = now
         self._wait_s = 0.0
         self.last_step_seconds = dt / substeps
-        self.rec.note_time("step", dt)
+        self.rec.note_time("step", dt, step=step)
         self._emit_rows(step, host, n_images, substeps)
         if self._on_step_seconds is not None:
             self._on_step_seconds(self.last_step_seconds)
 
     def _emit_rows(self, step: int, metrics: dict, n_images: int,
                    substeps: int) -> None:
+        """The drained entry's rows, under one ``emit`` bracket (closed
+        also when ``on_row`` raises an anomaly halt)."""
+        self.rec.start("emit")
+        try:
+            self._rows(step, metrics, n_images, substeps)
+        finally:
+            self.rec.end("emit", step=step)
+
+    def _rows(self, step: int, metrics: dict, n_images: int,
+              substeps: int) -> None:
         from theanompi_tpu.obs.numerics import split_numerics
 
         if substeps == 1:

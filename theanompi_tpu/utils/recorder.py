@@ -16,7 +16,18 @@ is kept because it was good; additions over the reference:
   gauges; pass ``spans`` (obs/spans.py) and every ``start``/``end``
   bracket ALSO opens/closes a trace span (wait -> ``data_wait``,
   comm -> ``grad_sync``, others by name) — the Recorder stays the
-  single emission point, the obs files the machine-readable sinks.
+  single emission point, the obs files the machine-readable sinks;
+- ONE span store (ISSUE 26): a bracket closed with a step number keeps
+  its start and duration in :class:`SpanRing`, in memory, always on.
+  Stamps are integer nanoseconds of ``time.time_ns()``, the clock the
+  profiler writes a ``*.xplane.pb`` in (an event's ``start_ns`` there
+  counts from the ``profile_start_time`` of the ``Task Environment``
+  plane), so a reader lays the spans over a device trace that holds no
+  host events. A bracket reads the clock twice: the span sink takes
+  the same two stamps, and the dispatcher's ``host_blocked_s`` is the
+  sum of the ``drain`` brackets. Each bracket also opens a
+  ``jax.profiler.TraceAnnotation`` of its name: an operator's trace
+  with the host tracer on shows the phases above the device's row.
 
 Note on calc/comm split: in the reference these were separate host
 phases (Theano call, then MPI). Here the collective is fused INSIDE the
@@ -36,12 +47,56 @@ from collections import defaultdict
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+SPAN_RING_STEPS = 65536
+
+
+class SpanRing:
+    """One bracket category's spans of the last ``capacity`` steps:
+    start and duration in integer nanoseconds, addressed by step number
+    (slot ``step % capacity``; an older step's slot is overwritten, so a
+    long run holds constant memory)."""
+
+    __slots__ = ("capacity", "steps", "t0_ns", "dur_ns")
+
+    def __init__(self, capacity: int = SPAN_RING_STEPS):
+        self.capacity = int(capacity)
+        self.steps = np.full(self.capacity, -1, np.int64)
+        self.t0_ns = np.zeros(self.capacity, np.int64)
+        self.dur_ns = np.zeros(self.capacity, np.int64)
+
+    def put(self, step: int, t0_ns: int, dur_ns: int) -> None:
+        i = step % self.capacity
+        self.steps[i] = step
+        self.t0_ns[i] = t0_ns
+        self.dur_ns[i] = dur_ns
+
+    def get(self, step: int) -> Optional[tuple[int, int]]:
+        """``(t0_ns, dur_ns)`` of ``step``'s span, or None where the ring
+        never held it or has since overwritten it."""
+        i = step % self.capacity
+        if self.steps[i] != step:
+            return None
+        return int(self.t0_ns[i]), int(self.dur_ns[i])
+
+    def held(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(steps, t0_ns, dur_ns)`` of every span held, by step."""
+        idx = np.flatnonzero(self.steps >= 0)
+        idx = idx[np.argsort(self.steps[idx], kind="stable")]
+        return self.steps[idx], self.t0_ns[idx], self.dur_ns[idx]
 
 
 class Recorder:
     # bracket category -> obs span kind (obs/spans.py SPAN_KINDS); the
     # reference's 'comm' bracket is the gradient exchange, hence grad_sync
     SPAN_NAMES = {"wait": "data_wait", "comm": "grad_sync"}
+    # the driver's phases inside a step's amortized window: in the span
+    # sink they are children (depth 1) of the ``step`` span, which keeps
+    # span_summary's top-level fractions disjoint (``wait`` stays beside
+    # it at depth 0: the amortized step already excludes the waits)
+    STEP_CHILDREN = frozenset({"key_split", "dispatch", "drain", "emit"})
+    clock_ns = staticmethod(time.time_ns)  # the profiler's clock
 
     def __init__(
         self,
@@ -59,8 +114,10 @@ class Recorder:
         self.run_name = run_name
         self.registry = registry  # obs.MetricsRegistry or None
         self.spans = spans  # obs.SpanRecorder or None
-        self._span_tokens: dict[str, object] = {}
-        self._t0: dict[str, float] = {}
+        # open brackets: category -> (t0_ns, trace annotation, sink token)
+        self._open: dict[str, tuple] = {}
+        self.span_rings: dict[str, SpanRing] = {}
+        self.t_end_ns = 0  # the stamp that closed the newest bracket
         self.timings: dict[str, list[float]] = defaultdict(list)
         self.history: dict[str, list] = defaultdict(list)
         self.epoch_start: Optional[float] = None
@@ -144,16 +201,26 @@ class Recorder:
 
     # -- timing brackets (reference API) ------------------------------------
     def start(self, category: str = "calc") -> None:
+        # with no profiler session, entering one costs an atomic read
+        ann = TraceAnnotation(category)
+        ann.__enter__()
+        t0 = self.clock_ns()
+        token = None
         if self.spans is not None:
-            self._span_tokens[category] = self.spans.begin(
-                self.SPAN_NAMES.get(category, category)
+            token = self.spans.begin(
+                self.SPAN_NAMES.get(category, category), t0_ns=t0,
+                under=1 if category in self.STEP_CHILDREN else 0,
             )
-        self._t0[category] = time.perf_counter()
+        self._open[category] = (t0, ann, token)
 
-    def end(self, category: str = "calc", sync=None) -> float:
+    def end(self, category: str = "calc", sync=None,
+            step: Optional[int] = None) -> float:
         """Close a bracket. Pass a ``jax.Array`` (e.g. the loss) as
         ``sync`` to block until the device work really finished —
-        without it the bracket only measures dispatch.
+        without it the bracket only measures dispatch. Pass the number
+        of the step the bracket belongs to as ``step`` and the span is
+        kept in the category's :class:`SpanRing` (``span(category,
+        step)`` reads it back).
 
         An ``end`` without a matching ``start`` warns (naming the
         category) and returns 0.0 instead of raising — an accounting
@@ -163,8 +230,9 @@ class Recorder:
                 sync.block_until_ready()
             except AttributeError:
                 pass
-        t0 = self._t0.pop(category, None)
-        if t0 is None:
+        t1 = self.clock_ns()
+        opened = self._open.pop(category, None)
+        if opened is None:
             import warnings
 
             warnings.warn(
@@ -172,13 +240,20 @@ class Recorder:
                 f"start({category!r}); returning 0.0",
                 RuntimeWarning, stacklevel=2,
             )
-            self._span_tokens.pop(category, None)
             return 0.0
-        dt = time.perf_counter() - t0
+        t0, ann, token = opened
+        ann.__exit__(None, None, None)
+        self.t_end_ns = t1
+        dur_ns = max(0, t1 - t0)  # the wall clock may be set back
+        dt = dur_ns * 1e-9
         self.timings[category].append(dt)
-        token = self._span_tokens.pop(category, None)
+        if step is not None:
+            ring = self.span_rings.get(category)
+            if ring is None:
+                ring = self.span_rings[category] = SpanRing()
+            ring.put(step, t0, dur_ns)
         if token is not None and self.spans is not None:
-            self.spans.finish(token)
+            self.spans.finish(token, t1_ns=t1, step=step)
         if self.registry is not None:
             name = self.SPAN_NAMES.get(category, category)
             self.registry.histogram(
@@ -187,19 +262,30 @@ class Recorder:
             ).observe(dt)
         return dt
 
-    def note_time(self, category: str, dt: float) -> float:
+    def span(self, category: str, step: int) -> Optional[tuple[int, int]]:
+        """``(t0_ns, dur_ns)`` of ``category``'s bracket of ``step``, on
+        the ``clock_ns`` clock; None where none is held."""
+        ring = self.span_rings.get(category)
+        return None if ring is None else ring.get(step)
+
+    def note_time(self, category: str, dt: float,
+                  step: Optional[int] = None) -> float:
         """Record an externally measured bracket duration without a
         ``start``/``end`` pair — the dispatch pipeline's amortized
-        spaced-sync timing (utils/dispatch.py). Feeds the same sinks a
-        bracket would: the timings list, the obs histogram, and an
-        ``amortized``-flagged span line (the duration must already
-        EXCLUDE overlapping owner-thread spans, e.g. data waits, so the
-        span summary's fraction invariant holds)."""
+        spaced-sync timing (utils/dispatch.py), whose window closes with
+        the bracket closed last (``t_end_ns``: no clock is read here).
+        Feeds the same sinks a bracket would: the timings list, the obs
+        histogram, and an ``amortized``-flagged span line (the duration
+        must already EXCLUDE overlapping owner-thread spans, e.g. data
+        waits, so the span summary's fraction invariant holds)."""
         dt = float(dt)
         self.timings[category].append(dt)
         name = self.SPAN_NAMES.get(category, category)
         if self.spans is not None:
-            self.spans.note(name, dt)
+            self.spans.note(
+                name, dt, step=step,
+                t0_wall=self.t_end_ns * 1e-9 - dt if self.t_end_ns else None,
+            )
         if self.registry is not None:
             self.registry.histogram(
                 f"tmpi_{name}_seconds",
