@@ -9,6 +9,8 @@ run. This wrapper runs the same command and
   duration of each of the driver's five spans and of the step's period over
   the steps after the first 60: a slow-mode process (PERF.md: one in nine,
   every step 5-6 ms longer) shows which span grew;
+- prints the run summary's ``keys_ready_share`` (PR 27: how many of the
+  steps' random keys were made ahead; ``None`` on a commit before it);
 - with ``--obs-dir DIR`` puts ``obs_dir=DIR`` into the driver's
   ``run_training`` call, for the one comparison PERF.md reports (PR 26): what
   the JSONL sink costs a step;
@@ -30,7 +32,7 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
 
-FIVE = ("wait", "key_split", "dispatch", "drain", "emit")
+FIVE = ("wait", "dispatch", "key_split", "drain", "emit")
 SKIP = 60  # warm-up, the compared steps and a traced run's capture
 
 
@@ -65,9 +67,19 @@ def main(argv) -> None:
     from theanompi_tpu.launch import worker
     from theanompi_tpu.utils.recorder import Recorder
 
+    run_training = worker.run_training
     if obs_dir is not None:
-        # the driver imports the name when it measures: it gets this one
-        worker.run_training = functools.partial(worker.run_training, obs_dir=obs_dir)
+        run_training = functools.partial(run_training, obs_dir=obs_dir)
+
+    def run_and_tell(*args, **kwargs):
+        summary = run_training(*args, **kwargs)
+        # PR 27: keys that were waiting when taken over keys taken (None before it)
+        print(f"[spans] keys_ready_share {summary.get('keys_ready_share')} over "
+              f"{summary.get('steps')} steps", flush=True)
+        return summary
+
+    # the driver imports the name when it measures: it gets this one
+    worker.run_training = run_and_tell
     close = Recorder.close
 
     told = []

@@ -720,8 +720,12 @@ def run_training(
             return _srecipe().place_state(st)
         return st
 
-    rng = jax.random.PRNGKey(seed)
-    state = _commit(engine.init_state(rng))
+    from theanompi_tpu.utils.dispatch import KeyStream, MetricsDispatcher
+
+    # every step's random key, split off the carry one dispatch unit
+    # ahead (utils/dispatch.py); keys.carry is what a checkpoint saves
+    keys = KeyStream(jax.random.PRNGKey(seed), ahead=fuse)
+    state = _commit(engine.init_state(keys.carry))
     start_epoch = 0
     summary_resumed_from = None
     # set when an elastic resume actually resharded: the obs facade is
@@ -839,7 +843,7 @@ def run_training(
             if saved_rng is not None:
                 # already wrapped with the impl that wrote it — a
                 # pre-rbg-default threefry checkpoint keeps resuming
-                rng = saved_rng
+                keys.reset(saved_rng)
             # positioning counts BATCHES CONSUMED, not steps: rollback
             # skips consumed batches without training steps, and the
             # checkpoint records how many (see skipped_prior above)
@@ -1014,12 +1018,10 @@ def run_training(
         # anomalous step's params/opt state, NaNs and all); closure
         # reads the CURRENT state/step — the dump happens at drain
         # time, on the driver thread
-        sync_save(dump_dir, state, step_count, rng=rng, keep=1,
+        sync_save(dump_dir, state, step_count, rng=keys.carry, keep=1,
                   topology=topo_meta)
 
     obs.set_flight_state_saver(_flight_state_saver)
-    from theanompi_tpu.utils.dispatch import MetricsDispatcher
-
     # Async dispatch pipeline (utils/dispatch.py): the ONLY
     # host<->device sync in the train loops below lives in the
     # dispatcher's drain (lint: tools/check_hot_loop.py). depth=1
@@ -1043,24 +1045,29 @@ def run_training(
             flush=True,
         )
 
-    def _split_and_dispatch(step_fn, state, xs, ys, rng, n, stacked, numerics):
-        """The ``key_split`` and ``dispatch`` spans of one dispatch, for
-        both train loops: ``n`` sequential key splits (stacked for a
-        fused group), then the call of the step program, each a recorder
-        bracket under the dispatched group's last step number.
-        -> (state, metrics, rng)."""
+    def _dispatch_and_split(step_fn, state, xs, ys, n, stacked, numerics):
+        """The ``dispatch`` and ``key_split`` spans of one dispatch, for
+        both train loops: the call of the step program on the next ``n``
+        keys of the stream (stacked for a fused group), then the splits
+        that make the NEXT unit's keys, queued behind the program just
+        dispatched; each a recorder bracket under the dispatched group's
+        last step number. -> (state, metrics)."""
         last = step_count + n
-        rec.start("key_split")
-        subs = []
-        for _ in range(n):
-            rng, sub = jax.random.split(rng)
-            subs.append(sub)
-        keys = jnp.stack(subs) if stacked else subs[0]
-        rec.end("key_split", step=last)
+        carry = keys.carry
+        subs = keys.take(n, stacked)
         rec.start("dispatch")
-        state, metrics = step_fn(state, xs, ys, keys, numerics=numerics)
+        try:
+            state, metrics = step_fn(state, xs, ys, subs, numerics=numerics)
+        except Exception:
+            # no step happened: the crash save pairs this state with the
+            # carry BEFORE the step's keys, which is where a resume starts
+            keys.reset(carry)
+            raise
         rec.end("dispatch", step=last)
-        return state, metrics, rng
+        rec.start("key_split")
+        keys.refill()
+        rec.end("key_split", step=last)
+        return state, metrics
 
     train_loop_s = 0.0  # wall time inside the train loops (the
     # denominator of summary['host_blocked_frac'])
@@ -1225,8 +1232,8 @@ def run_training(
                         )
                         # the SAME sequential splits the per-step path draws,
                         # shipped stacked — fused training is bit-identical
-                        state, metrics, rng = _split_and_dispatch(
-                            engine.fused_train_step, state, xs, ys, rng,
+                        state, metrics = _dispatch_and_split(
+                            engine.fused_train_step, state, xs, ys,
                             g, True, nm_group,
                         )
                         step_count += g
@@ -1276,7 +1283,7 @@ def run_training(
                         if skip_data_batches:
                             skip_data_batches -= 1
                             skipped_steps_total += 1
-                            rng, _ = jax.random.split(rng)
+                            keys.take(1)
                             continue
                         if _preempt["flag"]:
                             raise Preempted(step_count)
@@ -1289,8 +1296,8 @@ def run_training(
                         # numerics variant of the SAME compiled step
                         # (extra scalar outputs; obs/numerics.py) — the
                         # scalars drain with the loss, no host sync here
-                        state, metrics, rng = _split_and_dispatch(
-                            engine.train_step, state, xg, yg, rng, 1, False,
+                        state, metrics = _dispatch_and_split(
+                            engine.train_step, state, xg, yg, 1, False,
                             bool(nfreq) and (step_count + 1) % nfreq == 0,
                         )
                         step_count += 1
@@ -1381,11 +1388,11 @@ def run_training(
                     # finally below before the summary returns) — this
                     # bracket times only the enqueue; the real write is
                     # spanned inside utils/checkpoint.py on its thread
-                    ckpt_writer.save(ckpt_dir, state, step_count, rng=rng,
-                                     extra_meta=_save_meta(),
+                    ckpt_writer.save(ckpt_dir, state, step_count,
+                                     rng=keys.carry, extra_meta=_save_meta(),
                                      topology=topo_meta)
                 else:
-                    sync_save(ckpt_dir, state, step_count, rng=rng,
+                    sync_save(ckpt_dir, state, step_count, rng=keys.carry,
                               extra_meta=_save_meta(), topology=topo_meta)
                 rec.end("checkpoint", step=step_count)
                 last_ckpt_step = step_count
@@ -1454,7 +1461,7 @@ def run_training(
             restored, saved_rng = load_checkpoint(path, state)
             state = _place_restored(restored)
             if saved_rng is not None:
-                rng = saved_rng
+                keys.reset(saved_rng)
             step_count = engine.get_step(state)
             last_ckpt_step = step_count
             # replay from the restored boundary; the per-step path
@@ -1528,7 +1535,7 @@ def run_training(
                     # boundary checkpoint is still a valid resume
                     # point, and the marker below records it
                     try:
-                        sync_save(ckpt_dir, state, step_count, rng=rng,
+                        sync_save(ckpt_dir, state, step_count, rng=keys.carry,
                                   extra_meta=_save_meta(),
                                   topology=topo_meta)
                         last_ckpt_step = step_count
@@ -1599,7 +1606,7 @@ def run_training(
                 try:
                     if ckpt_writer is not None:
                         ckpt_writer.wait()
-                    sync_save(ckpt_dir, state, step_count, rng=rng,
+                    sync_save(ckpt_dir, state, step_count, rng=keys.carry,
                               extra_meta=_save_meta(), topology=topo_meta)
                     last_ckpt_step = step_count
                     print(
@@ -1689,6 +1696,9 @@ def run_training(
     # spent BLOCKED on device syncs (the per-step tax dispatch_depth>1
     # removes; bench.py reports this as host_blocked_frac)
     summary["dispatch_depth"] = disp.depth
+    # the key stream's engagement: keys that were ready when taken over
+    # keys taken (1 less the first unit; falling = refill not ahead)
+    summary["keys_ready_share"] = keys.ready_share
     # numerics flight recorder: anomalies seen at drain time (0 when
     # numerics is off) — a nonzero count with policy 'record'/'dump' is
     # the "check the triage bundle" signal for sweep drivers

@@ -13,8 +13,10 @@ Span kinds used by the training stack (callers may add their own):
 ``checkpoint_write`` sub-spans utils/checkpoint.py opens inside a save
 (named apart so a synchronous save does not count the same wall time
 twice under one kind), and the driver's phases inside a step's
-amortized window, ``key_split``, ``dispatch``, ``drain`` and ``emit``
-(depth 1: children of ``step``, so they stay out of the fractions).
+amortized window, in a step's order ``dispatch``, ``key_split`` (the
+NEXT unit's keys, split under the step just dispatched), ``drain`` and
+``emit`` (depth 1: children of ``step``, so they stay out of the
+fractions).
 Schema: tools/check_obs_schema.py.
 
 Clock: ``t0`` is seconds of ``time.time_ns()``, the clock a profiler

@@ -7,7 +7,9 @@ steps in flight. This lint keeps it that way: it fails if a host-
 materializing call (``float(...)``, ``.item(...)``, ``np.asarray(...)``,
 ``jax.device_get(...)``, ``block_until_ready(...)``) reappears inside a
 train loop — the kind of one-line "just print the loss" patch that
-silently reinstates a full round trip per step.
+silently reinstates a full round trip per step. The same pass fails on
+an eager ``jax.random.split`` anywhere in ``run_training`` (ISSUE 27):
+the per-step keys come off ``utils/dispatch.py``'s ``KeyStream``.
 
 Scope: every ``for ... in loader`` loop inside ``run_training`` (the
 per-step and fused dispatch loops). The epoch-level code around them —
@@ -117,19 +119,19 @@ def _forbidden_call(node: ast.Call) -> Optional[str]:
     return None
 
 
+def _function(source: str, func: str) -> ast.FunctionDef:
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.FunctionDef) and node.name == func:
+            return node
+    raise ValueError(f"no function {func!r} found to lint")
+
+
 def _train_loops(source: str, func: str = "run_training") -> list[ast.For]:
     """Every ``for ... in <something mentioning 'loader'>`` loop inside
     ``func`` — the worker train loops. Raises if the function or the
     loops are missing, so a refactor that moves them cannot turn this
     lint into a silent pass."""
-    tree = ast.parse(source)
-    fn: Optional[ast.FunctionDef] = None
-    for node in ast.walk(tree):
-        if isinstance(node, ast.FunctionDef) and node.name == func:
-            fn = node
-            break
-    if fn is None:
-        raise ValueError(f"no function {func!r} found to lint")
+    fn = _function(source, func)
     loops = [
         sub for sub in ast.walk(fn)
         if isinstance(sub, ast.For) and "loader" in ast.unparse(sub.iter)
@@ -165,6 +167,18 @@ def check_source(source: str, func: str = "run_training") -> list[str]:
                     "(metric fetches belong in utils/dispatch.py's "
                     "drain)"
                 )
+    # the whole driver, its helpers included: a step's key comes off
+    # utils/dispatch.py's KeyStream (one jitted split, made under the
+    # step before); an eager split is five small programs dispatched
+    # one by one in the bare gap between two steps
+    for node in ast.walk(_function(source, func)):
+        if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "split"
+                and ast.unparse(node.func.value).endswith("random")):
+            errs.append(
+                f"line {node.lineno}: eager key split inside {func!r}: "
+                f"{ast.unparse(node)} (take the key from the KeyStream)"
+            )
     return errs
 
 

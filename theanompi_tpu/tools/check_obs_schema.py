@@ -60,7 +60,7 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
         # so trace readers can tell the two apart
         "amortized": ((bool,), False),
         # the training step a span belongs to (utils/recorder.py: the
-        # driver's wait/key_split/dispatch/drain/emit brackets and the
+        # driver's wait/dispatch/key_split/drain/emit brackets and the
         # amortized step); spans outside the train loop carry none.
         # ``t0`` is seconds of time.time_ns(), the profiler's clock
         "step": ((int,), False),

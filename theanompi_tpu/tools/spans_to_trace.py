@@ -9,7 +9,8 @@ files into ONE trace viewable in ``chrome://tracing`` / Perfetto /
 - bracketed spans on thread 0 (``spans``) as complete ``"ph": "X"``
   events — nesting renders from the timestamps, ``depth`` rides in
   ``args``, and so does the ``step`` number of a train-loop span
-  (``data_wait``, ``key_split``, ``dispatch``, ``drain``, ``emit``);
+  (in a step's order ``data_wait``, ``dispatch``, ``key_split``,
+  ``drain``, ``emit``);
 - ``amortized`` spans (the dispatch pipeline's attributed step windows,
   utils/dispatch.py) on their OWN lane (thread 1, ``amortized``),
   flagged in ``args`` — attributed time is not a measured bracket and

@@ -43,6 +43,11 @@ Spans (utils/recorder.py ``SpanRing``): the blocking D2H is the
 print) the ``emit`` bracket, each under the number of the step it
 drains — at depth 2 the step before the one just dispatched. The
 amortized windows close on the drain bracket's own end stamp.
+
+:class:`KeyStream` is the pipeline's other half on the way IN: each
+step's random key is split off the carry one dispatch unit ahead, by
+one jitted program queued behind the step that is running, so the host
+has the next key in hand when the drain returns.
 """
 
 from __future__ import annotations
@@ -50,6 +55,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Callable, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -257,3 +264,74 @@ class MetricsDispatcher:
                     {k: float(v) for k, v in plain.items()},
                     {k: float(v) for k, v in nm.items()},
                 )
+
+
+@jax.jit
+def _split(carry):
+    """``carry, sub = jax.random.split(carry)`` as ONE program. Key
+    derivation is integer arithmetic: the traced split gives the eager
+    one's bits, whatever implementation ``carry`` has (a raw key under
+    the default, or a typed key a checkpoint wrapped)."""
+    pair = jax.random.split(carry)
+    return pair[0], pair[1]
+
+
+class KeyStream:
+    """The driver's per-step random keys — the chain ``carry, sub =
+    jax.random.split(carry)``, one split a step — made ahead of the
+    step that uses them.
+
+    ``carry`` is the COMMITTED carry: the key after the splits of every
+    step taken so far, what a checkpoint saves as ``rng=``. Ahead of it
+    sits a queue of ready ``(carry_after, sub)`` pairs that
+    :meth:`refill` tops up to ``ahead`` (the dispatch unit: 1, or
+    ``steps_per_dispatch``). The driver refills right after a step
+    program's dispatch returns: the split's host work and its tiny
+    device program then run under that step, not in the bare gap
+    between two steps. A key that is not ready when :meth:`take` wants
+    it (first step, after :meth:`reset`, a group larger than the queue)
+    is made on the spot — late, never wrong.
+    """
+
+    def __init__(self, carry, ahead: int = 1):
+        self.carry = carry
+        self.ahead = ahead
+        self._ready: deque = deque()
+        self.taken = 0
+        self.taken_ready = 0  # of those, the keys that were waiting
+
+    def take(self, n: int = 1, stacked: bool = False):
+        """The next ``n`` keys (``stacked``: one ``[n, ...]`` array for a
+        fused group; else ``n`` must be 1: the key itself), committing
+        the carry past them."""
+        subs = []
+        for _ in range(n):
+            if self._ready:
+                self.taken_ready += 1
+            else:
+                self._make()
+            self.carry, sub = self._ready.popleft()
+            subs.append(sub)
+        self.taken += n
+        return jnp.stack(subs) if stacked else subs[0]
+
+    def refill(self) -> None:
+        """Top the ready queue up to ``ahead`` keys."""
+        while len(self._ready) < self.ahead:
+            self._make()
+
+    def reset(self, carry) -> None:
+        """Continue from a restored carry; what was ready belonged to
+        the timeline the restore erased."""
+        self.carry = carry
+        self._ready.clear()
+
+    @property
+    def ready_share(self) -> Optional[float]:
+        """Keys taken from the ready queue over keys taken (steady
+        state: 1 less the first unit; falling = refill not ahead)."""
+        return self.taken_ready / self.taken if self.taken else None
+
+    def _make(self) -> None:
+        tip = self._ready[-1][0] if self._ready else self.carry
+        self._ready.append(_split(tip))
