@@ -174,3 +174,84 @@ def test_lm_136m_train_step_fits_one_v5e(topo, one_chip, no_persistent_cache,
                    for a in jax.tree_util.tree_leaves(state.params))
     assert 130e6 < n_params < 140e6
     assert live < V5E_HBM_BYTES, f"{live / 1e9:.2f} GB does not fit one v5e"
+
+
+def test_grouped_matmuls_forward_and_backward_at_the_trinity_mini_shape(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One routed projection of the cut Trinity-Mini layer: 8,192 tokens x
+    8 choices in the worst-case buffer, 16 experts of 2048 x 1024, bf16,
+    256-row tiles: the scalar-prefetched tile map, the transposed-weight
+    form (dX) and the accumulating ``moe_tgmm`` (dW)."""
+    from theanompi_tpu.ops import pallas_moe as pm
+
+    _mosaic(monkeypatch, pm)
+    M = pm.padded_rows(8192 * 8, 16, 256)
+    x = jax.ShapeDtypeStruct((M, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((16, 2048, 1024), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, sizes):
+        return pm.gmm(x, w, sizes).astype(jnp.float32).sum()
+
+    fwd = jax.jit(pm.gmm).lower(x, w, sizes).compile()
+    assert _kernels(fwd) == 1
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w, sizes).compile()
+    assert _kernels(bwd) == 2  # dX + dW (a sum's gradient needs no forward value)
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window_2048", "full"])
+def test_flash_attention_at_8192_tokens_with_grouped_heads(
+        one_chip, no_persistent_cache, monkeypatch, window):
+    """T=8192, 32 query heads over 4 K/V heads of 128, bf16: the forward
+    keeps one head's K and V whole in VMEM, the backward is the 2-D grid
+    (under a window only the window's blocks long)."""
+    from theanompi_tpu.ops import pallas_attention as pa
+
+    _mosaic(monkeypatch, pa)
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return pa.flash_attention(q, k, v, causal=True,
+                                  window=window).astype(jnp.float32).sum()
+
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    assert _kernels(bwd) == 3
+    assert "flash_bwd_dq_2d" in bwd.as_text()
+
+
+@pytest.mark.slow  # 52 s of a many-threaded compile: run it before a chip call, not in tier-1
+def test_trinity_mini_cut_train_step_fits_one_v5e(topo, one_chip,
+                                                  no_persistent_cache, monkeypatch):
+    """The whole jitted BSP-1 step of TrinityMini_EP8 (the benchmark's cell
+    ``trinity-mini-bsp1-train8k``): 705 M parameters with fp32 gradients and
+    Adam moments, 8,192 tokens, remat per layer; 4 flash and 12 grouped
+    kernels a layer."""
+    from theanompi_tpu.models.afmoe import TrinityMini_EP8
+    from theanompi_tpu.ops import pallas_attention as pa
+    from theanompi_tpu.ops import pallas_moe as pm
+    from theanompi_tpu.parallel.bsp import make_bsp_train_step
+    from theanompi_tpu.train import init_train_state
+
+    _mosaic(monkeypatch, pa)
+    _mosaic(monkeypatch, pm)
+    model = TrinityMini_EP8()
+    r = model.recipe
+
+    def described(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(described, jax.eval_shape(
+        lambda: init_train_state(model, jax.random.PRNGKey(0))))
+    key = described(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((r.batch_size, *r.input_shape), jnp.int32,
+                                  sharding=one_chip)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    compiled = make_bsp_train_step(model, mesh).lower(
+        state, tokens, tokens, key).compile()
+
+    assert _kernels(compiled) == 4 * len(model.kinds) + 12 * model.n_routed
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 11e9 < live < 14e9, f"{live / 1e9:.2f} GB"

@@ -27,6 +27,8 @@ MODEL_REGISTRY = {
     "transformer_lm": ("theanompi_tpu.models.lm", "TransformerLMModel"),
     "transformer_lm_136m": ("theanompi_tpu.models.lm", "TransformerLM_136M"),
     "moe_lm": ("theanompi_tpu.models.lm", "MoELMModel"),
+    "afmoe_lm": ("theanompi_tpu.models.afmoe", "AfmoeLM"),
+    "trinity_mini_ep8": ("theanompi_tpu.models.afmoe", "TrinityMini_EP8"),
 }
 
 

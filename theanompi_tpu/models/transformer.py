@@ -51,12 +51,12 @@ SEQ_AXIS = "seq"
 MODEL_AXIS = "model"
 
 
-def _rms(x, g):
+def _rms(x, g, eps=1e-6):
     # statistics in fp32 even when x is bf16 (the normalizer is a
     # variance sweep — bf16's 8-bit mantissa visibly degrades it);
     # output returns to x's compute dtype for the next matmul
     xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6) * g
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps) * g
     return y.astype(x.dtype)
 
 
@@ -66,7 +66,9 @@ def cast_block_params(blk: dict, dtype) -> dict:
     accumulates their grads back in fp32), norm gains left fp32 — they
     are consumed inside :func:`_rms`'s fp32 statistics path. No-op for
     fp32 compute. Works for dense and MoE blocks (any non-``ln*`` leaf
-    is a matmul operand)."""
+    is a matmul operand). The skip-by-name list below is THIS block's;
+    the kinds-built block (models/afmoe.py) says its precision where a
+    tensor is used and does not come through here."""
     if dtype == jnp.float32:
         return blk
     # 'gate' (MoE router) also stays fp32: routing is an argmax over its

@@ -23,6 +23,13 @@ Tokens beyond an expert's capacity are dropped (the residual stream
 carries them unchanged) — Switch semantics. With ``axis_name=None`` the
 same code runs dense on one device (the test oracle and the small-scale
 fallback).
+
+Beside it, :func:`route_topk` and :func:`routed_experts`: top-k routing
+over ALL experts with NO capacity and NO drop, computed for the share of
+the experts that this chip holds (``first`` .. ``first + count`` of
+``total``) by grouped matrix products over the token-expert pairs sorted
+by expert (:mod:`theanompi_tpu.ops.pallas_moe`). What the absent experts
+would add is left out: on one chip the layer runs without its exchange.
 """
 
 from __future__ import annotations
@@ -33,6 +40,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from theanompi_tpu.ops.pallas_moe import gmm, group_tiles, padded_rows
 
 
 class MoEStats(NamedTuple):
@@ -112,3 +121,116 @@ def switch_moe(
         n_drop = lax.pmean(n_drop, a)
     aux = E * jnp.sum(f_e * P_e)
     return y, MoEStats(aux_loss=aux, dropped_frac=n_drop / S)
+
+
+# -- top-k routing without dropped tokens over a share of the experts --------
+
+
+class RoutedStats(NamedTuple):
+    counts: jax.Array  # [total] int32: this step's tokens of EVERY expert
+    pairs_here: jax.Array  # token-expert pairs computed here
+    pairs_absent: jax.Array  # pairs that fell to experts held elsewhere
+    pad_rows: jax.Array  # rows of padding inside the grouped products' live tiles
+    load_max_over_mean: jax.Array  # largest over mean rows of a held expert
+
+
+def route_topk(h, router_w, bias, k: int, scale: float):
+    """Sigmoid scores over all experts in fp32 (``highest``: a score's
+    rounding decides which expert a pair lands on), the ``k`` best by
+    ``score + bias`` (the bias selects only), weights the chosen scores
+    normalised to ``scale``. -> (idx [T, k] int32, w [T, k] fp32)."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(bias), k)
+    sel = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, sel / (jnp.sum(sel, axis=-1, keepdims=True) + 1e-20) * scale
+
+
+@jax.custom_vjp
+def _to_rows(x, row_pair, pos):
+    """``[T, d] -> [M, d]``: buffer row ``r`` is the token of pair
+    ``row_pair[r]``; rows of no pair are zero. Transposed as a gather
+    through ``pos`` (the pairs' rows), so no row of a dead tile is read."""
+    return jnp.take(x, row_pair // pos.shape[1], axis=0, mode="fill", fill_value=0)
+
+
+def _to_rows_bwd(res, g):
+    row_pair, pos = res
+    back = jnp.take(g, pos, axis=0, mode="fill", fill_value=0)  # [T, k, d]
+    return jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+
+
+_to_rows.defvjp(lambda x, row_pair, pos: (_to_rows(x, row_pair, pos), (row_pair, pos)),
+                _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_rows(y, row_pair, pos):
+    """``[M, d] -> [T, k, d]``: each pair's row, zero for a pair whose
+    expert is absent (``pos`` past the buffer). Transposed as a gather
+    through ``row_pair``: every buffer row has one pair or none."""
+    return jnp.take(y, pos, axis=0, mode="fill", fill_value=0)
+
+
+def _from_rows_bwd(res, g):
+    row_pair, pos = res
+    flat = g.reshape(-1, g.shape[-1])
+    return jnp.take(flat, row_pair, axis=0, mode="fill", fill_value=0), None, None
+
+
+_from_rows.defvjp(lambda y, row_pair, pos: (_from_rows(y, row_pair, pos), (row_pair, pos)),
+                  _from_rows_bwd)
+
+
+def routed_experts(
+    x: jax.Array,  # [T, d] tokens, compute dtype
+    idx: jax.Array,  # [T, k] int32 chosen experts of `total`
+    w: jax.Array,  # [T, k] fp32 their weights
+    w_gate: jax.Array,  # [count, d, f] the experts held here
+    w_up: jax.Array,  # [count, d, f]
+    w_down: jax.Array,  # [count, f, d]
+    first: int,  # the first expert held: first .. first + count of total
+    total: int,
+    *,
+    tm: int = 256,
+) -> tuple[jax.Array, RoutedStats]:
+    """``sum_j w[t, j] * swiglu_{idx[t, j]}(x[t])`` over the chosen experts
+    that are held here. No capacity and no drop: the pairs whose expert
+    is held are sorted by expert (stable) into a buffer sized for the
+    case that ALL ``T * k`` pairs land here, each expert's rows starting
+    at a tile; gate, up and down projection are one grouped product each
+    (tiles past the real rows skip their work); the weighted rows are
+    gathered back by token."""
+    T, k = idx.shape
+    count, P = w_gate.shape[0], T * k
+    M = padded_rows(P, count, tm)
+    le = idx.reshape(P) - first
+    le = jnp.where((le >= 0) & (le < count), le, count)  # `count`: held elsewhere
+    sizes_all = jnp.zeros((count + 1,), jnp.int32).at[le].add(1)
+    sizes = sizes_all[:count]
+    tiles = group_tiles(sizes, tm)
+    # where each group starts, among the sorted pairs and in the padded buffer
+    sorted_start = jnp.cumsum(sizes_all) - sizes_all
+    row_start = jnp.append(tm * (jnp.cumsum(tiles) - tiles), M)
+    order = jnp.argsort(le, stable=True)  # pair ids by expert, absent last
+    g = le[order]
+    dest = jnp.where(g < count, row_start[g] + jnp.arange(P) - sorted_start[g], M)
+    row_pair = jnp.full((M,), P, jnp.int32).at[dest].set(order.astype(jnp.int32), mode="drop")
+    pos = jnp.zeros((P,), jnp.int32).at[order].set(dest.astype(jnp.int32)).reshape(T, k)
+
+    xs = _to_rows(x, row_pair, pos)
+    gate = gmm(xs, w_gate, sizes, tm=tm)
+    up = gmm(xs, w_up, sizes, tm=tm)
+    mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(x.dtype)
+    ys = _from_rows(gmm(mid, w_down, sizes, tm=tm), row_pair, pos)
+    y = jnp.einsum("tk,tkd->td", w, ys.astype(jnp.float32)).astype(x.dtype)
+
+    here = jnp.sum(sizes)
+    stats = RoutedStats(
+        counts=jnp.zeros((total,), jnp.int32).at[idx.reshape(P)].add(1),
+        pairs_here=here,
+        pairs_absent=P - here,
+        pad_rows=tm * jnp.sum(tiles) - here,
+        load_max_over_mean=jnp.max(sizes) * count / jnp.maximum(here, 1).astype(jnp.float32),
+    )
+    return y, stats

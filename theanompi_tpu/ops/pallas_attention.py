@@ -25,7 +25,10 @@ inputs the result matches the unfused reference to float tolerance
 
 Layout contract matches :func:`theanompi_tpu.ops.ring_attention.
 full_attention_reference`: ``[B, T, H, D] -> [B, Tq, H, D]``, optional
-causal masking in GLOBAL position order (query i attends keys <= i).
+causal masking in GLOBAL position order (query i attends keys <= i),
+optionally within a sliding ``window`` (keys > i - window; single shard
+only), K/V allowed fewer heads than the queries (a divisor; repeated
+outside the kernels).
 Off-TPU the kernels run through the Pallas interpreter — identical
 numerics on the CPU test meshes. ``TMPI_PALLAS=0`` falls back to the
 unfused reference implementation.
@@ -39,10 +42,14 @@ shard — the same online-softmax recurrence, distributed).
 The ``block_q=block_k=512`` defaults come from a block sweep on an
 earlier development backend (wider blocks amortize the accumulator
 rescale; the causal block skip, :func:`_k_blocks_for`, drops the
-all-masked half of the blocks). Kernel time and speedup over the
-unfused lowering are not measured on the current machine; what IS
-checked there without a chip is that forward and backward compile for
-a v5e at the 136M shape (tests/test_tpu_compile.py).
+all-masked half of the blocks, and under a window
+:func:`_k_block_start` drops those older than the window). Measured on
+the v5e (PERF.md): 11.92 % of the bf16 peak over forward and backward
+at the 136M shape (B=8, T=1024, 12 heads of 64; PR 26), and what the
+windowed layers at T=8192 and head size 128 reach is in PERF.md section
+5 (PR 28). No speedup over the unfused lowering has been measured.
+Checked without a chip: forward and backward compile for a v5e at both
+shapes (tests/test_tpu_compile.py).
 
 Long-context operation: the classic backward kernels keep the FULL
 opposite sequence VMEM-resident per grid step, which overflows the
@@ -53,8 +60,12 @@ BOTH sides in blocks and accumulate outputs across sequential grid
 revisits — residency is O(block x D) regardless of T, no compiler
 flags, and 512-wide blocks stay usable. The 1-D kernels keep the
 short-T regime (their in-register fori_loop skips causal-dead blocks
-entirely; the 2-D grid only masks them). Long-context throughput is
-not measured on the current machine.
+entirely; the 2-D grid only masks the causally dead ones: it still
+steps through them and copies their blocks). Under a WINDOW the 2-D
+grids are only as long as the window in blocks (:func:`_win_steps`: 5
+of 16 steps at T=8192, window 2048, 512-wide blocks), their index maps
+counting from the window's first block, so blocks outside it are
+skipped, not masked.
 """
 
 from __future__ import annotations
@@ -84,6 +95,9 @@ class _Cfg(NamedTuple):
     BQ: int
     BK: int
     interpret: bool
+    # sliding window (causal only): query t sees keys s with
+    # t - window < s <= t; None = every earlier key, as before
+    window: Optional[int] = None
 
 
 def _mask(cfg: _Cfg, i, j, q_off, k_off):
@@ -97,6 +111,8 @@ def _mask(cfg: _Cfg, i, j, q_off, k_off):
     valid = lcol < cfg.Tk
     if cfg.causal:
         valid = valid & ((q_off + lrow) >= (k_off + lcol))
+    if cfg.window is not None:
+        valid = valid & ((q_off + lrow) - (k_off + lcol) < cfg.window)
     return valid
 
 
@@ -111,12 +127,57 @@ def _k_blocks_for(cfg: _Cfg, i, nk, q_off, k_off):
     return jnp.clip(jmax, 0, nk)
 
 
+def _k_block_start(cfg: _Cfg, i, q_off, k_off):
+    """First k-block index query block ``i`` touches: under a sliding
+    window the blocks wholly older than the window of the block's FIRST
+    row are all-masked and skipped, as the future ones are."""
+    if cfg.window is None:
+        return 0
+    return jnp.maximum(0, q_off + i * cfg.BQ - (cfg.window - 1) - k_off) // cfg.BK
+
+
 def _q_block_start(cfg: _Cfg, j, q_off, k_off):
     """First q-block index whose rows can (causally) see key block
     ``j`` — the dkv-kernel mirror of :func:`_k_blocks_for`."""
     if not cfg.causal:
         return 0
     return jnp.maximum(0, (k_off + j * cfg.BK - q_off) // cfg.BQ)
+
+
+def _q_block_end(cfg: _Cfg, j, nq, q_off, k_off):
+    """Last q-block index (exclusive) whose rows still hold key block
+    ``j`` in their window — the mirror of :func:`_k_block_start`."""
+    if cfg.window is None:
+        return nq
+    last = k_off + j * cfg.BK + cfg.BK - 1 + cfg.window - 1 - q_off  # last row that sees the block
+    return jnp.clip(last // cfg.BQ + 1, 0, nq)
+
+
+def _n_blocks(T: int, B: int) -> int:
+    return -(-T // B)
+
+
+def _win_k_first(cfg: _Cfg, i):
+    """Single shard (offsets zero): first key block in the window of
+    query block ``i`` — what the windowed 2-D grid counts its k steps from."""
+    return _k_block_start(cfg, i, 0, 0)
+
+
+def _win_q_first(cfg: _Cfg, j):
+    return _q_block_start(cfg, j, 0, 0)
+
+
+def _win_steps(cfg: _Cfg) -> tuple[int, int]:
+    """Static inner extents of the windowed 2-D grids: the most key
+    blocks any query block's window touches, and the most query blocks
+    that hold one key block in theirs."""
+    nq, nk = _n_blocks(cfg.Tq, cfg.BQ), _n_blocks(cfg.Tk, cfg.BK)
+    w = cfg.window - 1
+    nj = max(min((i * cfg.BQ + cfg.BQ - 1) // cfg.BK, nk - 1)
+             - max(i * cfg.BQ - w, 0) // cfg.BK + 1 for i in range(nq))
+    ni = max(min((j * cfg.BK + cfg.BK - 1 + w) // cfg.BQ, nq - 1)
+             - (j * cfg.BK) // cfg.BQ + 1 for j in range(nk))
+    return nj, ni
 
 
 FWD_NAME = "flash_fwd"  # the kernel's name in a device trace
@@ -153,7 +214,8 @@ def _fwd_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
         return acc, m_new, l
 
     acc, m, l = lax.fori_loop(
-        0, _k_blocks_for(cfg, i, nk, q_off, k_off), body, (acc0, m0, l0)
+        _k_block_start(cfg, i, q_off, k_off),
+        _k_blocks_for(cfg, i, nk, q_off, k_off), body, (acc0, m0, l0)
     )
     # l == 0 only for rows with no visible key at all — impossible
     # single-shard (causal: the diagonal key is local), but routine for
@@ -195,7 +257,8 @@ def _dq_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         )
 
     dq = lax.fori_loop(
-        0, _k_blocks_for(cfg, i, nk, q_off, k_off), body,
+        _k_block_start(cfg, i, q_off, k_off),
+        _k_blocks_for(cfg, i, nk, q_off, k_off), body,
         jnp.zeros(q.shape, jnp.float32),
     )
     dq_ref[0] = dq  # f32: ring hops accumulate partials losslessly
@@ -243,7 +306,8 @@ def _dkv_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref, dsum_ref,
     # causal: query blocks strictly below this key block's diagonal see
     # none of it — start at the first overlapping block
     dk, dv = lax.fori_loop(
-        _q_block_start(cfg, j, q_off, k_off), nq, body, (dk0, dv0)
+        _q_block_start(cfg, j, q_off, k_off),
+        _q_block_end(cfg, j, nq, q_off, k_off), body, (dk0, dv0)
     )
     dk_ref[0] = dk  # f32: ring hops accumulate partials losslessly
     dv_ref[0] = dv
@@ -270,11 +334,15 @@ def _dq_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
     k dim innermost so ``dq_ref``'s block is revisited sequentially and
     accumulates in VMEM (written back when the q index advances)."""
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    jj = pl.program_id(2)
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
-    nk = pl.num_programs(2)
+    if cfg.window is None:
+        j, nk = jj, pl.num_programs(2)
+    else:
+        # the grid's k dim spans the window's blocks only (_dq_call_2d)
+        j, nk = _win_k_first(cfg, i) + jj, _n_blocks(cfg.Tk, cfg.BK)
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
@@ -311,17 +379,24 @@ def _dkv_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref,
     the q dim innermost so the per-key-block outputs accumulate in VMEM
     across the q sweep."""
     j = pl.program_id(1)
-    i = pl.program_id(2)
+    ii = pl.program_id(2)
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
+    # windowed: the grid's q dim spans the blocks that see key block j
+    i = ii if cfg.window is None else _win_q_first(cfg, j) + ii
 
-    @pl.when(i == 0)
+    @pl.when(ii == 0)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
     istart = _q_block_start(cfg, j, q_off, k_off)
+    if cfg.window is None:
+        live = i >= istart
+    else:
+        live = (i >= istart) & (
+            i < _q_block_end(cfg, j, _n_blocks(cfg.Tq, cfg.BQ), q_off, k_off))
 
-    @pl.when(i >= istart)
+    @pl.when(live)
     def _acc():
         k = k_ref[0]
         v = v_ref[0]
@@ -394,6 +469,19 @@ def _by(which: str, shape):
 
     pick = (lambda b, x, y: (b, x) + (0,) * (len(shape) - 2)) if which == "x" \
         else (lambda b, x, y: (b, y) + (0,) * (len(shape) - 2))
+    return pl.BlockSpec(shape, pick, memory_space=pltpu.VMEM)
+
+
+def _by_window(shape, first, n):
+    """The 'y' operand of a WINDOWED 2-D grid: grid dim 2 counts from
+    ``first(x)``, the first block in the window of grid dim 1's block,
+    clamped into the ``n`` blocks there are (a clamped step is dead: the
+    kernel skips it and the block is not copied again)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def pick(b, x, y):
+        return (b, jnp.minimum(first(x) + y, n - 1)) + (0,) * (len(shape) - 2)
+
     return pl.BlockSpec(shape, pick, memory_space=pltpu.VMEM)
 
 
@@ -487,14 +575,23 @@ def _dsum_of(g, o):
 def _dq_call_2d(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
     BH, Tqp, D = q3.shape
     Tkp = k3.shape[1]
+    nk = Tkp // cfg.BK
+    if cfg.window is None:
+        kv = _by("y", (1, cfg.BK, D))
+    else:
+        # skip, not mask, the blocks outside the window: the k dim of
+        # the grid is as long as the widest window in blocks
+        nk = _win_steps(cfg)[0]
+        kv = _by_window((1, cfg.BK, D), functools.partial(_win_k_first, cfg),
+                        Tkp // cfg.BK)
     return pl.pallas_call(
         functools.partial(_dq_kernel_2d, cfg),
-        grid=(BH, Tqp // cfg.BQ, Tkp // cfg.BK),
+        grid=(BH, Tqp // cfg.BQ, nk),
         in_specs=[
             _smem_spec3(), _smem_spec3(),
             _by("x", (1, cfg.BQ, D)),         # q
-            _by("y", (1, cfg.BK, D)),         # k
-            _by("y", (1, cfg.BK, D)),         # v
+            kv,                               # k
+            kv,                               # v
             _by("x", (1, cfg.BQ, D)),         # dO
             _by("x", (1, cfg.BQ, 1)),         # lse
             _by("x", (1, cfg.BQ, 1)),         # dsum
@@ -509,15 +606,22 @@ def _dq_call_2d(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
 def _dkv_call_2d(cfg: _Cfg, q3, g, lse, dsum, k3, v3, q_off, k_off):
     BH, Tqp, D = q3.shape
     Tkp = k3.shape[1]
+    nq = Tqp // cfg.BQ
+    if cfg.window is None:
+        qside = functools.partial(_by, "y")
+    else:
+        nq = _win_steps(cfg)[1]
+        qside = functools.partial(
+            _by_window, first=functools.partial(_win_q_first, cfg), n=Tqp // cfg.BQ)
     return pl.pallas_call(
         functools.partial(_dkv_kernel_2d, cfg),
-        grid=(BH, Tkp // cfg.BK, Tqp // cfg.BQ),
+        grid=(BH, Tkp // cfg.BK, nq),
         in_specs=[
             _smem_spec3(), _smem_spec3(),
-            _by("y", (1, cfg.BQ, D)),         # q
-            _by("y", (1, cfg.BQ, D)),         # dO
-            _by("y", (1, cfg.BQ, 1)),         # lse
-            _by("y", (1, cfg.BQ, 1)),         # dsum
+            qside((1, cfg.BQ, D)),            # q
+            qside((1, cfg.BQ, D)),            # dO
+            qside((1, cfg.BQ, 1)),            # lse
+            qside((1, cfg.BQ, 1)),            # dsum
             _by("x", (1, cfg.BK, D)),         # k block
             _by("x", (1, cfg.BK, D)),         # v block
         ],
@@ -575,7 +679,7 @@ def _to_heads_major(x, B, T, H, D):
     return jnp.transpose(x, (0, 2, 1, 3)).reshape(B * H, T, D)
 
 
-def _prepare(q, k, v, causal, scale, precision, block_q, block_k):
+def _prepare(q, k, v, causal, scale, precision, block_q, block_k, window=None):
     """Shared prologue of the public entry points: precision upcast,
     block sizing, heads-major reshape, padding. Returns
     ``(cfg, q3, k3, v3, shape_meta)`` where shape_meta =
@@ -589,7 +693,8 @@ def _prepare(q, k, v, causal, scale, precision, block_q, block_k):
     sc = scale if scale is not None else 1.0 / math.sqrt(D)
     BQ, BK = min(block_q, _ceil_to(Tq, 8)), min(block_k, _ceil_to(Tk, 8))
     Tqp, Tkp = _ceil_to(Tq, BQ), _ceil_to(Tk, BK)
-    cfg = _Cfg(bool(causal), float(sc), Tq, Tk, BQ, BK, _interpret())
+    cfg = _Cfg(bool(causal), float(sc), Tq, Tk, BQ, BK, _interpret(),
+               None if window is None else int(window))
 
     q3 = _to_heads_major(q, B, Tq, H, D)
     k3 = _to_heads_major(k, B, Tk, H, D)
@@ -619,9 +724,17 @@ def flash_attention(
     *,
     block_q: int = 512,
     block_k: int = 512,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused blockwise attention, differentiable: drop-in for
     :func:`~theanompi_tpu.ops.ring_attention.full_attention_reference`.
+
+    ``window`` (causal only): query ``t`` sees keys ``s`` with ``t -
+    window < s <= t``; blocks wholly outside the window are skipped in
+    the forward and in both backward forms. ``k``/``v`` may carry fewer
+    heads than ``q`` (a divisor): query head ``i`` reads K/V head ``i //
+    (H / H_kv)``; they are repeated to the query heads outside the kernel
+    (the backward of the repeat sums each group).
 
     Sequence lengths are padded up to the block sizes internally
     (padded keys masked, padded query rows discarded); head dim is used
@@ -632,15 +745,22 @@ def flash_attention(
     the q/k/v tiles to fp32 — same numerics knob as the unfused
     reference, at ~2x matmul cost for bf16 inputs.
     """
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a sliding window needs causal=True")
+    if k.shape[2] != q.shape[2]:
+        if q.shape[2] % k.shape[2]:
+            raise ValueError(
+                f"flash_attention: {q.shape[2]} query heads over {k.shape[2]} K/V heads")
+        k, v = (jnp.repeat(t, q.shape[2] // t.shape[2], axis=2) for t in (k, v))
     if not _use_pallas():
         from theanompi_tpu.ops.ring_attention import full_attention_reference
 
         return full_attention_reference(
-            q, k, v, causal=causal, scale=scale, precision=precision
+            q, k, v, causal=causal, scale=scale, precision=precision, window=window
         )
 
     cfg, q3, k3, v3, meta = _prepare(
-        q, k, v, causal, scale, precision, block_q, block_k
+        q, k, v, causal, scale, precision, block_q, block_k, window
     )
     return _finish(_flash(cfg, q3, k3, v3), meta)
 

@@ -160,9 +160,13 @@ def ulysses_attention(
     return lax.all_to_all(out, axis_name, split_axis=1, concat_axis=2, tiled=True)
 
 
-def full_attention_reference(q, k, v, causal=False, scale=None, precision=None):
+def full_attention_reference(q, k, v, causal=False, scale=None, precision=None,
+                             window=None):
     """Plain full-softmax attention — the single-device oracle for tests
-    and the local per-head kernel inside :func:`ulysses_attention`."""
+    and the local per-head kernel inside :func:`ulysses_attention`.
+    ``window`` (causal only): query ``t`` sees keys ``t - window < s <= t``."""
+    if window is not None and not causal:
+        raise ValueError("full_attention_reference: a sliding window needs causal=True")
     B, T, H, D = q.shape
     Tk = k.shape[1]
     sc = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -174,6 +178,8 @@ def full_attention_reference(q, k, v, causal=False, scale=None, precision=None):
         # position-aligned-at-start convention, valid for Tq != Tk too
         # (matches pallas_attention's global row >= col mask)
         mask = jnp.arange(T)[:, None] >= jnp.arange(Tk)[None, :]
+        if window is not None:
+            mask &= jnp.arange(T)[:, None] - jnp.arange(Tk)[None, :] < window
         s = jnp.where(mask[None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum(

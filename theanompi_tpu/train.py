@@ -206,6 +206,11 @@ def make_train_step(
             loss_scale=loss_scale, param_sync=param_sync,
         )
         metrics = {"loss": loss, **model.metrics(logits, labels)}
+        if hasattr(model, "state_metrics"):
+            # counters a model's apply left in its state (models/afmoe.py:
+            # the step's routing counts) ride the row; other models'
+            # compiled steps are the programs they were
+            metrics.update(model.state_metrics(new_model_state))
         return new_model_state, grads, metrics
 
     def train_step(state: TrainState, images, labels, rng):
