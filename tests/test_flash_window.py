@@ -1,7 +1,7 @@
 """``flash_attention(..., window=W)`` and fewer K/V heads than query heads
 (ops/pallas_attention.py), forward and gradients against a masked softmax:
-W below, at and above a block, T no multiple of the block, through the 1-D
-and the 2-D backward."""
+W below, at and above a block, T no multiple of the block, through both
+forms of the backward (the query side resident, and the 2-D grid)."""
 
 import math
 
@@ -77,9 +77,9 @@ def test_unequal_blocks_under_a_window_through_the_2d_backward(qkvg, blocks, mon
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
-def test_windowed_2d_grids_span_the_window_not_the_sequence():
+def test_the_windowed_2d_grid_spans_the_window_not_the_sequence():
     cfg = pa._Cfg(True, 1.0, 8192, 8192, 512, 512, True, 2048)
-    assert pa._win_steps(cfg) == (5, 5)  # of 16 blocks a side
+    assert pa._win_q_steps(cfg, 16, 16) == 5  # of 16 query blocks
 
 
 def test_a_window_without_causal_is_refused(qkvg):

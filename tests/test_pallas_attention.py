@@ -224,12 +224,18 @@ def test_ring_flash_matches_dense_oracle(causal, mesh8):
                                atol=2e-5, rtol=1e-4)
 
 
-def test_ring_flash_grads_match_dense_oracle(mesh8):
+@pytest.mark.parametrize("backward", ["resident", "2d"])
+def test_ring_flash_grads_match_dense_oracle(mesh8, backward, monkeypatch):
     """Whole-ring custom VJP (dq local-accumulated, dk/dv traveling with
-    their shard) == jax AD of the dense oracle."""
+    their shard) == jax AD of the dense oracle; each hop's backward is
+    the one kernel, in the form its local length selects."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    import theanompi_tpu.ops.pallas_attention as pa
     from theanompi_tpu.ops.pallas_attention import ring_flash_attention
+
+    if backward == "2d":
+        monkeypatch.setattr(pa, "_BWD_2D_MIN_T", 1)
 
     B, T, H, D = 1, 32, 2, 8
     qg, kg, vg = qkv((B, T, H, D), seed=29)

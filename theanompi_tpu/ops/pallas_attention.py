@@ -12,16 +12,19 @@ instead of O(T^2). Both passes are Pallas TPU kernels:
 - forward: one kernel, grid over (batch·heads, query blocks); K/V loops
   run as ``fori_loop`` over VMEM slices; per-row logsumexp is saved as
   the softmax residual.
-- backward: the classic two-kernel split — a dq kernel gridded over
-  query blocks and a dk/dv kernel gridded over key blocks — each
-  recomputing the probability tiles from (q, k, lse) so the O(T^2)
-  matrix never exists in either pass.
+- backward: ONE kernel (``flash_bwd``), gridded over (batch·heads, key
+  blocks), that recomputes each live probability tile from (q, k, lse)
+  once and takes all three gradients from it (:func:`_tile_grads`, five
+  products a tile): dk and dv leave by key block, dq is an fp32 output
+  that stays whole in VMEM while the grid revisits it over the key
+  blocks. The O(T^2) matrix never exists in either pass.
 
-Numerics: the q·k^T and p·v matmuls run in the INPUT dtype on the MXU
-with fp32 accumulation (``preferred_element_type``); softmax statistics,
-probability tiles, and all gradient accumulators are fp32. For fp32
-inputs the result matches the unfused reference to float tolerance
-(tests/test_pallas_attention.py).
+Numerics: EVERY product, forward and backward, runs in the INPUT dtype
+on the MXU with fp32 accumulation (``preferred_element_type``); softmax
+statistics, probability tiles until their one cast, and all gradient
+accumulators are fp32 (tests/test_flash_backward.py walks the kernels'
+jaxprs for it). For fp32 inputs the result matches the unfused reference
+to float tolerance (tests/test_pallas_attention.py).
 
 Layout contract matches :func:`theanompi_tpu.ops.ring_attention.
 full_attention_reference`: ``[B, T, H, D] -> [B, Tq, H, D]``, optional
@@ -44,28 +47,30 @@ earlier development backend (wider blocks amortize the accumulator
 rescale; the causal block skip, :func:`_k_blocks_for`, drops the
 all-masked half of the blocks, and under a window
 :func:`_k_block_start` drops those older than the window). Measured on
-the v5e (PERF.md): 11.92 % of the bf16 peak over forward and backward
-at the 136M shape (B=8, T=1024, 12 heads of 64; PR 26), and what the
-windowed layers at T=8192 and head size 128 reach is in PERF.md section
-5 (PR 28). No speedup over the unfused lowering has been measured.
+the v5e (PERF.md section 5, PR 29), a live 512 x 512 tile inside the
+training step: forward 1.70 us at the 136M shape (B=8, T=1024, 12 heads
+of 64) and 1.33-1.46 us at T=8192 with heads of 128; backward 2.26 us
+and 2.34-2.53 us, against 0.34 us of MXU time a product. The forward writes ``lse`` as
+[BH, T, 1], which the chip pads to 128 lanes, and the backward wants it
+as rows: the relayout between them takes 0.11 ms a layer at the 136M
+shape. No speedup over the unfused lowering has been measured.
 Checked without a chip: forward and backward compile for a v5e at both
 shapes (tests/test_tpu_compile.py).
 
-Long-context operation: the classic backward kernels keep the FULL
-opposite sequence VMEM-resident per grid step, which overflows the
-16 MB scoped VMEM stack at T >= 8192 (a compile failure). The fix is
-structural: at T >= ``_BWD_2D_MIN_T`` the backward dispatches to
-2-D-grid kernels (``_dq_kernel_2d``/``_dkv_kernel_2d``) that stream
-BOTH sides in blocks and accumulate outputs across sequential grid
-revisits — residency is O(block x D) regardless of T, no compiler
-flags, and 512-wide blocks stay usable. The 1-D kernels keep the
-short-T regime (their in-register fori_loop skips causal-dead blocks
-entirely; the 2-D grid only masks the causally dead ones: it still
-steps through them and copies their blocks). Under a WINDOW the 2-D
-grids are only as long as the window in blocks (:func:`_win_steps`: 5
-of 16 steps at T=8192, window 2048, 512-wide blocks), their index maps
-counting from the window's first block, so blocks outside it are
-skipped, not masked.
+Long-context operation: ``flash_bwd`` keeps the FULL query side (q, dO,
+lse, dsum) VMEM-resident per grid step. At T >= ``_BWD_2D_MIN_T`` the
+backward is the same tile algorithm on a 3-D grid (``flash_bwd_2d``:
+batch·heads, key blocks, query steps) that streams BOTH sides in blocks
+and accumulates dk and dv across the query steps; only dq stays whole
+(fp32: 4 MB a buffer at T=8192, D=128, so the call states its VMEM
+limit, :func:`_bwd_2d_vmem_bytes`). The query steps of a key block count
+from the first block that sees it (the offsets reach the index maps as
+prefetched scalars, so this holds under the ring too) and are clamped to
+the last block: a causally dead step is skipped in the kernel and copies
+nothing (136 of 256 steps are live at T=8192 under full causal
+attention). Under a WINDOW the grid is only as long as the window in
+blocks (:func:`_win_q_steps`: 5 of 16 steps at T=8192, window 2048,
+512-wide blocks), so blocks outside it are neither visited nor copied.
 """
 
 from __future__ import annotations
@@ -100,14 +105,15 @@ class _Cfg(NamedTuple):
     window: Optional[int] = None
 
 
-def _mask(cfg: _Cfg, i, j, q_off, k_off):
-    """[BQ, BK] validity of (query block i, key block j): key PADDING is
-    masked in local coordinates (padding is per-shard); the causal
-    triangle compares GLOBAL positions ``q_off + local`` vs ``k_off +
-    local`` — offsets are zero for single-shard use and ``rank * T``
-    under the ring."""
-    lrow = i * cfg.BQ + lax.broadcasted_iota(jnp.int32, (cfg.BQ, cfg.BK), 0)
-    lcol = j * cfg.BK + lax.broadcasted_iota(jnp.int32, (cfg.BQ, cfg.BK), 1)
+def _mask(cfg: _Cfg, i, j, q_off, k_off, key_major: bool = False):
+    """[BQ, BK] validity of (query block i, key block j) ([BK, BQ] where
+    ``key_major``): key PADDING is masked in local coordinates (padding
+    is per-shard); the causal triangle compares GLOBAL positions ``q_off
+    + local`` vs ``k_off + local`` — offsets are zero for single-shard
+    use and ``rank * T`` under the ring."""
+    shape = (cfg.BK, cfg.BQ) if key_major else (cfg.BQ, cfg.BK)
+    lrow = i * cfg.BQ + lax.broadcasted_iota(jnp.int32, shape, int(key_major))
+    lcol = j * cfg.BK + lax.broadcasted_iota(jnp.int32, shape, int(not key_major))
     valid = lcol < cfg.Tk
     if cfg.causal:
         valid = valid & ((q_off + lrow) >= (k_off + lcol))
@@ -153,31 +159,11 @@ def _q_block_end(cfg: _Cfg, j, nq, q_off, k_off):
     return jnp.clip(last // cfg.BQ + 1, 0, nq)
 
 
-def _n_blocks(T: int, B: int) -> int:
-    return -(-T // B)
-
-
-def _win_k_first(cfg: _Cfg, i):
-    """Single shard (offsets zero): first key block in the window of
-    query block ``i`` — what the windowed 2-D grid counts its k steps from."""
-    return _k_block_start(cfg, i, 0, 0)
-
-
-def _win_q_first(cfg: _Cfg, j):
-    return _q_block_start(cfg, j, 0, 0)
-
-
-def _win_steps(cfg: _Cfg) -> tuple[int, int]:
-    """Static inner extents of the windowed 2-D grids: the most key
-    blocks any query block's window touches, and the most query blocks
-    that hold one key block in theirs."""
-    nq, nk = _n_blocks(cfg.Tq, cfg.BQ), _n_blocks(cfg.Tk, cfg.BK)
-    w = cfg.window - 1
-    nj = max(min((i * cfg.BQ + cfg.BQ - 1) // cfg.BK, nk - 1)
-             - max(i * cfg.BQ - w, 0) // cfg.BK + 1 for i in range(nq))
-    ni = max(min((j * cfg.BK + cfg.BK - 1 + w) // cfg.BQ, nq - 1)
-             - (j * cfg.BK) // cfg.BQ + 1 for j in range(nk))
-    return nj, ni
+def _win_q_steps(cfg: _Cfg, nq: int, nk: int) -> int:
+    """Single shard (offsets zero): static inner extent of the WINDOWED
+    2-D grid, the most query blocks that hold one key block in theirs."""
+    return max(min((j * cfg.BK + cfg.BK - 1 + cfg.window - 1) // cfg.BQ, nq - 1)
+               - (j * cfg.BK) // cfg.BQ + 1 for j in range(nk))
 
 
 FWD_NAME = "flash_fwd"  # the kernel's name in a device trace
@@ -227,199 +213,118 @@ def _fwd_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
     lse_ref[0] = m + jnp.log(l_safe)  # [BQ, 1]
 
 
-DQ_NAME = "flash_bwd_dq"  # the kernel's name in a device trace
+BWD_NAME = "flash_bwd"  # the kernel's name in a device trace
 
 
-def _dq_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
-               lse_ref, dsum_ref, dq_ref):
-    i = pl.program_id(1)
-    q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
-    q = q_ref[0]
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]  # [BQ, 1]
-    dsum = dsum_ref[0]
-    nk = k_ref.shape[1] // cfg.BK
-
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * cfg.BK, cfg.BK), :]
-        v = v_ref[0, pl.ds(j * cfg.BK, cfg.BK), :]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * cfg.scale
-        p = jnp.where(_mask(cfg, i, j, q_off, k_off), jnp.exp(s - lse), 0.0)
-        dp = lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - dsum) * cfg.scale).astype(k.dtype)
-        return dq + lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    dq = lax.fori_loop(
-        _k_block_start(cfg, i, q_off, k_off),
-        _k_blocks_for(cfg, i, nk, q_off, k_off), body,
-        jnp.zeros(q.shape, jnp.float32),
+def _tile_grads(cfg: _Cfg, i, j, q_off, k_off, q, do, lse, dsum, k, v):
+    """The backward of ONE live (query block ``i``, key block ``j``) tile:
+    ``s``, ``p``, ``dp`` and ``ds`` are made once, in fp32, and the tile's
+    three partials ``(dq_i, dk_j, dv_j)`` are taken from them: five
+    products on operands of the input dtype with fp32 accumulation. The
+    tile is KEY-major (``s^T = k q^T``, [BK, BQ]; ``lse``, ``dsum`` are
+    rows [1, BQ]), which gives ``p^T`` and ``ds^T`` as ``dv`` and ``dk``
+    want them and leaves one transposed contraction, ``dq``'s."""
+    st = lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * cfg.scale
+    pt = jnp.where(_mask(cfg, i, j, q_off, k_off, True), jnp.exp(st - lse), 0.0)
+    dv = lax.dot_general(
+        pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
-    dq_ref[0] = dq  # f32: ring hops accumulate partials losslessly
+    dpt = lax.dot_general(
+        v, do.astype(v.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dst = (pt * (dpt - dsum) * cfg.scale).astype(q.dtype)
+    dk = lax.dot_general(
+        dst, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    dq = lax.dot_general(
+        dst.astype(k.dtype), k, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return dq, dk, dv
 
 
-DKV_NAME = "flash_bwd_dkv"  # the kernel's name in a device trace
-
-
-def _dkv_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref, dsum_ref,
-                k_ref, v_ref, dk_ref, dv_ref):
+def _bwd_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref, dsum_ref,
+                k_ref, v_ref, dq_ref, dk_ref, dv_ref):
+    """Grid (BH, key blocks): the queries' side whole in VMEM, ``dk`` and
+    ``dv`` by key block, and ``dq`` whole and resident, revisited over
+    the key blocks: zeroed at the first, each live tile adds its part."""
     j = pl.program_id(1)
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
     k = k_ref[0]
     v = v_ref[0]
     nq = q_ref.shape[1] // cfg.BQ
 
+    @pl.when(j == 0)
+    def _init():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * cfg.BQ, cfg.BQ), :]
-        do = do_ref[0, pl.ds(i * cfg.BQ, cfg.BQ), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * cfg.BQ, cfg.BQ), :]   # [BQ, 1]
-        dsum = dsum_ref[0, pl.ds(i * cfg.BQ, cfg.BQ), :]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * cfg.scale
-        p = jnp.where(
-            _mask(cfg, i, j, q_off, k_off), jnp.exp(s - lse), 0.0
-        )  # [BQ, BK]
-        dv = dv + lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - dsum) * cfg.scale).astype(q.dtype)
-        dk = dk + lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return dk, dv
+        rows = pl.ds(i * cfg.BQ, cfg.BQ)
+        dq_i, dk_i, dv_i = _tile_grads(
+            cfg, i, j, q_off, k_off, q_ref[0, rows, :], do_ref[0, rows, :],
+            lse_ref[0, :, rows], dsum_ref[0, :, rows], k, v)
+        dq_ref[0, rows, :] += dq_i
+        return dk + dk_i, dv + dv_i
 
-    dk0 = jnp.zeros(k.shape, jnp.float32)
-    dv0 = jnp.zeros(v.shape, jnp.float32)
     # causal: query blocks strictly below this key block's diagonal see
-    # none of it — start at the first overlapping block
+    # none of it, and under a window those past it: neither is visited
     dk, dv = lax.fori_loop(
         _q_block_start(cfg, j, q_off, k_off),
-        _q_block_end(cfg, j, nq, q_off, k_off), body, (dk0, dv0)
+        _q_block_end(cfg, j, nq, q_off, k_off), body,
+        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)),
     )
     dk_ref[0] = dk  # f32: ring hops accumulate partials losslessly
     dv_ref[0] = dv
 
 
-# Threshold (local sequence length) above which the backward runs on the
-# 2-D-grid kernels below: the classic 1-D kernels keep the FULL opposite
-# sequence VMEM-resident per grid step, which overflows the scoped VMEM
-# stack at long T (module docstring); the 2-D variants stream both sides
-# in blocks, so residency is O(BQ x D + BK x D) regardless of T. Kept at
-# 8192 (not lower) because the 1-D kernels' in-register fori_loop avoids
-# the 2-D grid's per-(i, j) output read-modify-write and its masked
-# causal-skip steps in the short-T regime where they already fit.
-# Tests monkeypatch this to exercise the 2-D path at small T.
+# Threshold (local sequence length) at which the backward streams BOTH
+# sides in blocks (``_bwd_kernel_2d``) where ``_bwd_kernel`` keeps the
+# whole query side VMEM-resident per grid step: the same tiles, another
+# residency. Kept at 8192 (not lower): below it the resident form fits
+# (it compiles for a v5e through T=8064 at D=128) and its in-kernel
+# fori_loop visits live tiles only, with no grid step, no block copy and
+# no dk/dv read-modify-write a tile. Tests monkeypatch this to exercise
+# the 2-D form at small T.
 _BWD_2D_MIN_T = 8192
 
 
-DQ_2D_NAME = "flash_bwd_dq_2d"  # the kernel's name in a device trace
+BWD_2D_NAME = "flash_bwd_2d"  # the kernel's name in a device trace
 
 
-def _dq_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
-                  lse_ref, dsum_ref, dq_ref):
-    """dq with BOTH sides blocked: grid (BH, q blocks, k blocks), the
-    k dim innermost so ``dq_ref``'s block is revisited sequentially and
-    accumulates in VMEM (written back when the q index advances)."""
-    i = pl.program_id(1)
-    jj = pl.program_id(2)
-    q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
-    if cfg.window is None:
-        j, nk = jj, pl.num_programs(2)
-    else:
-        # the grid's k dim spans the window's blocks only (_dq_call_2d)
-        j, nk = _win_k_first(cfg, i) + jj, _n_blocks(cfg.Tk, cfg.BK)
-
-    @pl.when(jj == 0)
-    def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
-
-    jmax = _k_blocks_for(cfg, i, nk, q_off, k_off)
-
-    @pl.when(j < jmax)
-    def _acc():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        dsum = dsum_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * cfg.scale
-        p = jnp.where(_mask(cfg, i, j, q_off, k_off), jnp.exp(s - lse), 0.0)
-        dp = lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - dsum) * cfg.scale).astype(k.dtype)
-        dq_ref[0] += lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-
-DKV_2D_NAME = "flash_bwd_dkv_2d"  # the kernel's name in a device trace
-
-
-def _dkv_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref,
-                   dsum_ref, k_ref, v_ref, dk_ref, dv_ref):
-    """(dk, dv) with both sides blocked: grid (BH, k blocks, q blocks),
-    the q dim innermost so the per-key-block outputs accumulate in VMEM
-    across the q sweep."""
+def _bwd_kernel_2d(cfg: _Cfg, qo_ref, ko_ref, q_ref, do_ref, lse_ref, dsum_ref,
+                   k_ref, v_ref, dq_ref, dk_ref, dv_ref):
+    """:func:`_bwd_kernel` with BOTH sides blocked: grid (BH, key blocks,
+    query steps), the query steps innermost and counted from the first
+    block that sees the key block, so ``dk`` and ``dv`` accumulate in
+    VMEM over them; ``dq`` is whole and resident over a batch-head."""
     j = pl.program_id(1)
     ii = pl.program_id(2)
-    q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
-    # windowed: the grid's q dim spans the blocks that see key block j
-    i = ii if cfg.window is None else _win_q_first(cfg, j) + ii
+    q_off, k_off = qo_ref[0], ko_ref[0]
+    nq = dq_ref.shape[1] // cfg.BQ
+    i = _q_block_start(cfg, j, q_off, k_off) + ii
+
+    @pl.when((j == 0) & (ii == 0))
+    def _init_dq():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     @pl.when(ii == 0)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    istart = _q_block_start(cfg, j, q_off, k_off)
-    if cfg.window is None:
-        live = i >= istart
-    else:
-        live = (i >= istart) & (
-            i < _q_block_end(cfg, j, _n_blocks(cfg.Tq, cfg.BQ), q_off, k_off))
-
-    @pl.when(live)
+    @pl.when(i < _q_block_end(cfg, j, nq, q_off, k_off))
     def _acc():
-        k = k_ref[0]
-        v = v_ref[0]
-        q = q_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        dsum = dsum_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * cfg.scale
-        p = jnp.where(_mask(cfg, i, j, q_off, k_off), jnp.exp(s - lse), 0.0)
-        dv_ref[0] += lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - dsum) * cfg.scale).astype(q.dtype)
-        dk_ref[0] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dq_i, dk_i, dv_i = _tile_grads(
+            cfg, i, j, q_off, k_off, q_ref[0], do_ref[0], lse_ref[0],
+            dsum_ref[0], k_ref[0], v_ref[0])
+        dq_ref[0, pl.ds(i * cfg.BQ, cfg.BQ), :] += dq_i
+        dk_ref[0] += dk_i
+        dv_ref[0] += dv_i
 
 
 def _zero_offs():
@@ -449,40 +354,6 @@ def _full(shape):
 
     return pl.BlockSpec(shape, lambda b, i: (b,) + (0,) * (len(shape) - 1),
                         memory_space=pltpu.VMEM)
-
-
-# NOTE: _smem_spec3/_by mirror _smem_spec/_q_major/_full for the 3-dim
-# (b, x, y) grids of the 2-D backward kernels — the index-map arity is
-# part of pallas_call's contract, so the families cannot share a lambda;
-# keep the two groups in sync when changing memory spaces or layouts.
-def _smem_spec3():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pl.BlockSpec((1, 1), lambda b, x, y: (0, 0),
-                        memory_space=pltpu.SMEM)
-
-
-def _by(which: str, shape):
-    """3-index-grid block spec selecting the grid dim that indexes this
-    operand's second axis: 'x' = grid dim 1, 'y' = grid dim 2."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    pick = (lambda b, x, y: (b, x) + (0,) * (len(shape) - 2)) if which == "x" \
-        else (lambda b, x, y: (b, y) + (0,) * (len(shape) - 2))
-    return pl.BlockSpec(shape, pick, memory_space=pltpu.VMEM)
-
-
-def _by_window(shape, first, n):
-    """The 'y' operand of a WINDOWED 2-D grid: grid dim 2 counts from
-    ``first(x)``, the first block in the window of grid dim 1's block,
-    clamped into the ``n`` blocks there are (a clamped step is dead: the
-    kernel skips it and the block is not copied again)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    def pick(b, x, y):
-        return (b, jnp.minimum(first(x) + y, n - 1)) + (0,) * (len(shape) - 2)
-
-    return pl.BlockSpec(shape, pick, memory_space=pltpu.VMEM)
 
 
 def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
@@ -515,51 +386,34 @@ def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
     return o, lse
 
 
-def _dq_call(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
-    """dq partial (f32) for one K/V shard, given the GLOBAL lse/dsum."""
+def _bwd_call(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
+    """(dq, dk, dv) partials (f32) of these queries against one K/V
+    shard, given the GLOBAL lse/dsum as rows [BH, 1, Tqp], in one kernel."""
     BH, Tqp, D = q3.shape
     Tkp = k3.shape[1]
     return pl.pallas_call(
-        functools.partial(_dq_kernel, cfg),
-        grid=(BH, Tqp // cfg.BQ),
-        in_specs=[
-            _smem_spec(), _smem_spec(),
-            _q_major((1, cfg.BQ, D)),         # q
-            _full((1, Tkp, D)),               # k
-            _full((1, Tkp, D)),               # v
-            _q_major((1, cfg.BQ, D)),         # dO
-            _q_major((1, cfg.BQ, 1)),         # lse
-            _q_major((1, cfg.BQ, 1)),         # dsum
-        ],
-        out_specs=_q_major((1, cfg.BQ, D)),
-        out_shape=jax.ShapeDtypeStruct((BH, Tqp, D), jnp.float32),
-        name=DQ_NAME,
-        interpret=cfg.interpret,
-    )(q_off, k_off, q3, k3, v3, g, lse, dsum)
-
-
-def _dkv_call(cfg: _Cfg, q3, g, lse, dsum, k3, v3, q_off, k_off):
-    """(dk, dv) partials (f32) for one K/V shard vs these queries."""
-    BH, Tqp, D = q3.shape
-    Tkp = k3.shape[1]
-    return pl.pallas_call(
-        functools.partial(_dkv_kernel, cfg),
+        functools.partial(_bwd_kernel, cfg),
         grid=(BH, Tkp // cfg.BK),
         in_specs=[
             _smem_spec(), _smem_spec(),
             _full((1, Tqp, D)),               # q
             _full((1, Tqp, D)),               # dO
-            _full((1, Tqp, 1)),               # lse
-            _full((1, Tqp, 1)),               # dsum
+            _full((1, 1, Tqp)),               # lse, as a row
+            _full((1, 1, Tqp)),               # dsum
             _q_major((1, cfg.BK, D)),         # k block
             _q_major((1, cfg.BK, D)),         # v block
         ],
-        out_specs=(_q_major((1, cfg.BK, D)), _q_major((1, cfg.BK, D))),
+        out_specs=(
+            _full((1, Tqp, D)),               # dq: revisited over the key blocks
+            _q_major((1, cfg.BK, D)),
+            _q_major((1, cfg.BK, D)),
+        ),
         out_shape=(
+            jax.ShapeDtypeStruct((BH, Tqp, D), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
         ),
-        name=DKV_NAME,
+        name=BWD_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, g, lse, dsum, k3, v3)
 
@@ -572,82 +426,77 @@ def _dsum_of(g, o):
     )
 
 
-def _dq_call_2d(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
+def _bwd_call_2d(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
+    """:func:`_bwd_call` with both sides streamed in blocks."""
+    from jax.experimental.pallas import tpu as pltpu
+
     BH, Tqp, D = q3.shape
     Tkp = k3.shape[1]
-    nk = Tkp // cfg.BK
-    if cfg.window is None:
-        kv = _by("y", (1, cfg.BK, D))
-    else:
-        # skip, not mask, the blocks outside the window: the k dim of
-        # the grid is as long as the widest window in blocks
-        nk = _win_steps(cfg)[0]
-        kv = _by_window((1, cfg.BK, D), functools.partial(_win_k_first, cfg),
-                        Tkp // cfg.BK)
-    return pl.pallas_call(
-        functools.partial(_dq_kernel_2d, cfg),
-        grid=(BH, Tqp // cfg.BQ, nk),
-        in_specs=[
-            _smem_spec3(), _smem_spec3(),
-            _by("x", (1, cfg.BQ, D)),         # q
-            kv,                               # k
-            kv,                               # v
-            _by("x", (1, cfg.BQ, D)),         # dO
-            _by("x", (1, cfg.BQ, 1)),         # lse
-            _by("x", (1, cfg.BQ, 1)),         # dsum
-        ],
-        out_specs=_by("x", (1, cfg.BQ, D)),   # revisited over the k dim
-        out_shape=jax.ShapeDtypeStruct((BH, Tqp, D), jnp.float32),
-        name=DQ_2D_NAME,
-        interpret=cfg.interpret,
-    )(q_off, k_off, q3, k3, v3, g, lse, dsum)
+    nq, nk = Tqp // cfg.BQ, Tkp // cfg.BK
 
+    def q_side(shape):
+        # the step's query block, clamped into the blocks there are: a
+        # step past the last live block is dead, the kernel skips it and
+        # its block is not copied again
+        def pick(b, x, y, qo, ko):
+            i = jnp.minimum(_q_block_start(cfg, x, qo[0], ko[0]) + y, nq - 1)
+            return (b, 0, i) if shape[1] == 1 else (b, i, 0)  # a row, or rows
 
-def _dkv_call_2d(cfg: _Cfg, q3, g, lse, dsum, k3, v3, q_off, k_off):
-    BH, Tqp, D = q3.shape
-    Tkp = k3.shape[1]
-    nq = Tqp // cfg.BQ
-    if cfg.window is None:
-        qside = functools.partial(_by, "y")
-    else:
-        nq = _win_steps(cfg)[1]
-        qside = functools.partial(
-            _by_window, first=functools.partial(_win_q_first, cfg), n=Tqp // cfg.BQ)
+        return pl.BlockSpec(shape, pick, memory_space=pltpu.VMEM)
+
+    def k_side(shape):
+        return pl.BlockSpec(shape, lambda b, x, y, qo, ko: (b, x, 0),
+                            memory_space=pltpu.VMEM)
+
     return pl.pallas_call(
-        functools.partial(_dkv_kernel_2d, cfg),
-        grid=(BH, Tkp // cfg.BK, nq),
-        in_specs=[
-            _smem_spec3(), _smem_spec3(),
-            qside((1, cfg.BQ, D)),            # q
-            qside((1, cfg.BQ, D)),            # dO
-            qside((1, cfg.BQ, 1)),            # lse
-            qside((1, cfg.BQ, 1)),            # dsum
-            _by("x", (1, cfg.BK, D)),         # k block
-            _by("x", (1, cfg.BK, D)),         # v block
-        ],
-        out_specs=(_by("x", (1, cfg.BK, D)), _by("x", (1, cfg.BK, D))),
+        functools.partial(_bwd_kernel_2d, cfg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,            # q_off, k_off: the index maps read them
+            grid=(BH, nk, nq if cfg.window is None else _win_q_steps(cfg, nq, nk)),
+            in_specs=[
+                q_side((1, cfg.BQ, D)),       # q
+                q_side((1, cfg.BQ, D)),       # dO
+                q_side((1, 1, cfg.BQ)),       # lse, as a row
+                q_side((1, 1, cfg.BQ)),       # dsum
+                k_side((1, cfg.BK, D)),       # k block
+                k_side((1, cfg.BK, D)),       # v block
+            ],
+            out_specs=(
+                pl.BlockSpec((1, Tqp, D), lambda b, x, y, qo, ko: (b, 0, 0),
+                             memory_space=pltpu.VMEM),
+                k_side((1, cfg.BK, D)),
+                k_side((1, cfg.BK, D)),
+            ),
+        ),
         out_shape=(
+            jax.ShapeDtypeStruct((BH, Tqp, D), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tkp, D), jnp.float32),
         ),
-        name=DKV_2D_NAME,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_2d_vmem_bytes(Tqp, D)),
+        name=BWD_2D_NAME,
         interpret=cfg.interpret,
-    )(q_off, k_off, q3, g, lse, dsum, k3, v3)
+    )(q_off.reshape(1), k_off.reshape(1), q3, g, lse, dsum, k3, v3)
+
+
+def _bwd_2d_vmem_bytes(Tqp: int, D: int) -> int:
+    """What :func:`_bwd_kernel_2d` may take of VMEM: the resident ``dq``
+    (fp32, lanes padded to 128, two buffers), and 24 MiB for the blocks
+    of the two sides and the tile's fp32 intermediates."""
+    return 2 * Tqp * _ceil_to(D, 128) * 4 + (24 << 20)
 
 
 def _bwd_dispatch(cfg: _Cfg, q3, k3, v3, g, lse, dsum, q_off, k_off):
-    """(dq, dk, dv) partials via the 1-D kernels, or the block-streamed
-    2-D kernels when either side's LOCAL length reaches _BWD_2D_MIN_T —
-    the one dispatch shared by the local backward and every ring hop
-    (a ring shard of 8k+ would otherwise rebuild the full-residency
-    kernels the threshold exists to avoid)."""
-    if max(q3.shape[1], k3.shape[1]) >= _BWD_2D_MIN_T:
-        dq = _dq_call_2d(cfg, q3, k3, v3, g, lse, dsum, q_off, k_off)
-        dk, dv = _dkv_call_2d(cfg, q3, g, lse, dsum, k3, v3, q_off, k_off)
-    else:
-        dq = _dq_call(cfg, q3, k3, v3, g, lse, dsum, q_off, k_off)
-        dk, dv = _dkv_call(cfg, q3, g, lse, dsum, k3, v3, q_off, k_off)
-    return dq, dk, dv
+    """(dq, dk, dv) partials (f32) via ``flash_bwd``, or the block-streamed
+    ``flash_bwd_2d`` when either side's LOCAL length reaches
+    _BWD_2D_MIN_T — the one dispatch shared by the local backward and
+    every ring hop (a ring shard of 8k+ would otherwise rebuild the
+    full-residency kernel the threshold exists to avoid)."""
+    call = _bwd_call_2d if max(q3.shape[1], k3.shape[1]) >= _BWD_2D_MIN_T else _bwd_call
+    # the tiles are key-major: the per-row statistics [BH, Tqp, 1] go in as rows
+    lse, dsum = (x.reshape(x.shape[0], 1, x.shape[1]) for x in (lse, dsum))
+    return call(cfg, q3, k3, v3, g, lse, dsum, q_off, k_off)
 
 
 def _bwd(cfg: _Cfg, q3, k3, v3, o, lse, g):
@@ -731,7 +580,7 @@ def flash_attention(
 
     ``window`` (causal only): query ``t`` sees keys ``s`` with ``t -
     window < s <= t``; blocks wholly outside the window are skipped in
-    the forward and in both backward forms. ``k``/``v`` may carry fewer
+    the forward and in both forms of the backward. ``k``/``v`` may carry fewer
     heads than ``q`` (a divisor): query head ``i`` reads K/V head ``i //
     (H / H_kv)``; they are repeated to the query heads outside the kernel
     (the backward of the repeat sums each group).
