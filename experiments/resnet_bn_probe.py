@@ -1,10 +1,11 @@
 """ResNet-50 BN-bottleneck probe (round-3 verdict item 2).
 
-tools/op_profile.py's committed case study shows the batch-256 ResNet-50
-step spends ~half its time in BN-statistic reduce fusions + the
+An op-level profile on an earlier backend put a large share of the
+batch-256 ResNet-50 step in BN-statistic reduce fusions + the
 normalize sweeps (each BN re-reads the conv output from HBM: the step is
 bandwidth-bound, not MXU-bound). This probe measures candidate fixes on
-the real chip, one variable at a time:
+the chip, one variable at a time (not run on the current chip: its old
+record left in PR 30):
 
   baseline       BatchNorm as shipped (fp32 upcast sweeps)
   dtype_reduce   stats via dtype=f32 reduction args on the bf16 x
@@ -17,8 +18,7 @@ the real chip, one variable at a time:
                  bigger reduce tiles)
   combo512       dtype_reduce + bf16_norm at batch 512
 
-Writes experiments/results/resnet_bn_probe.json; the winner (with the
-measured table) graduates into nn/layers.py like the LRN matmul did.
+Writes experiments/results/resnet_bn_probe.json.
 """
 
 from __future__ import annotations

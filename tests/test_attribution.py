@@ -1,9 +1,8 @@
-"""Step-time attribution profiler (obs/attribution.py, tools/profile.py,
-tools/perf_gate.py): fraction math + roofline classification, the live
+"""Step-time attribution profiler (obs/attribution.py, tools/profile.py):
+fraction math + roofline classification, the live
 gauge/record path through the obs facade, the `tmpi profile` report
-(cross-checked against traffic_model under the SPMD101 tolerance), the
-op-table join on the checked-in synthetic trace fixture, and the perf
-regression gate's pass/fail semantics."""
+(cross-checked against traffic_model under the SPMD101 tolerance), and the
+op-table join on the checked-in synthetic trace fixture."""
 
 import json
 import os
@@ -417,137 +416,3 @@ def test_profile_rejects_bad_args(tmp_path):
     with pytest.raises(ValueError, match="LM models"):
         run_profile(model_name="mlp", engine_name="nd",
                     out_dir=str(tmp_path))
-
-
-# -- perf gate ---------------------------------------------------------------
-
-def _profile_report(tmp_path):
-    from theanompi_tpu.tools.profile import run_profile
-
-    return run_profile(model_name="mlp", engine_name="bsp", steps=3,
-                       devices=4, out_dir=str(tmp_path / "gate_prof"))
-
-
-def test_perf_gate_self_passes_and_2x_mfu_fails(tmp_path):
-    """The acceptance gate: a report diffs clean against itself and a
-    mutated (2x MFU) copy fails — through the CLI entry point, both
-    orders (the band is symmetric: unexplained jumps are drift too)."""
-    from theanompi_tpu.tools.perf_gate import main as gate_main
-
-    report = _profile_report(tmp_path)
-    p = str(tmp_path / "gate_prof" / "report.json")
-    assert gate_main([p, p]) == 0
-    mutated = dict(report, mfu=report["mfu"] * 2)
-    mp = str(tmp_path / "mutated.json")
-    with open(mp, "w") as f:
-        json.dump(mutated, f)
-    assert gate_main([p, mp]) == 1
-    assert gate_main([mp, p]) == 1
-
-
-def test_perf_gate_fraction_sum_invariant(tmp_path):
-    from theanompi_tpu.tools.perf_gate import gate
-
-    report = _profile_report(tmp_path)
-    broken = json.loads(json.dumps(report))
-    broken["attribution"]["fractions"]["host"] += 0.5  # sum 1.5
-    res = gate(report, broken)
-    assert not res["ok"]
-    assert any(c["metric"] == "current_fractions_sum" and not c["ok"]
-               for c in res["checks"])
-
-
-def test_perf_gate_accepts_bench_and_snapshot_shapes():
-    """Bench raw results and kind=metrics snapshot lines carry the same
-    invariants; missing-everything and vanished-metric inputs fail
-    loudly instead of passing vacuously."""
-    from theanompi_tpu.obs.metrics import result_to_snapshot
-    from theanompi_tpu.tools.perf_gate import extract_invariants, gate
-
-    bench = {"metric": "x", "value": 1.0, "mfu": 0.4,
-             "host_blocked_frac": 0.05, "compression_ratio": 3.9}
-    assert extract_invariants(bench) == {
-        "mfu": 0.4, "host_blocked_frac": 0.05, "compression_ratio": 3.9}
-    snap = result_to_snapshot(bench, source="bench")
-    assert extract_invariants(snap)["mfu"] == 0.4
-    assert gate(bench, snap)["ok"]
-    drifted = dict(bench, mfu=0.1)
-    assert not gate(bench, drifted)["ok"]
-    # a metric the baseline carried must not vanish silently
-    res = gate(bench, {"mfu": 0.4, "host_blocked_frac": 0.05})
-    assert not res["ok"] and any("compression_ratio" in e
-                                 for e in res["errors"])
-    assert not gate({"no": 1}, {"metrics": 2})["ok"]
-
-
-def test_perf_gate_zero_valued_baseline_is_carried_not_vanished():
-    """ISSUE 12 satellite regression: a baseline metric valued EXACTLY
-    0.0 (a fast host rounds host_blocked_frac to zero) is a CARRIED
-    metric — presence is key membership, never value truthiness. It
-    must be diffed (absolutely, within ZERO_BASELINE_ABS_TOL — no
-    ratio exists at 0), not reported as vanished, and a genuine drift
-    off the zero baseline still fails."""
-    from theanompi_tpu.tools.perf_gate import (
-        ZERO_BASELINE_ABS_TOL,
-        extract_invariants,
-        gate,
-    )
-
-    base = {"mfu": 0.4, "host_blocked_frac": 0.0}
-    # extraction keeps the 0.0 (truthiness would drop it)
-    assert extract_invariants(base)["host_blocked_frac"] == 0.0
-    # same-zero current: compared OK, no vanished-metric error
-    res = gate(base, {"mfu": 0.4, "host_blocked_frac": 0.0})
-    assert res["ok"] and res["errors"] == []
-    assert any(c["metric"] == "host_blocked_frac" and c["ok"]
-               for c in res["checks"])
-    # sub-tolerance noise off the zero baseline passes...
-    noisy = {"mfu": 0.4,
-             "host_blocked_frac": ZERO_BASELINE_ABS_TOL / 2}
-    assert gate(base, noisy)["ok"]
-    # ...a real drift fails as a CHECK (not an error)
-    drifted = gate(base, {"mfu": 0.4, "host_blocked_frac": 0.3})
-    assert not drifted["ok"] and drifted["errors"] == []
-    assert any(c["metric"] == "host_blocked_frac" and not c["ok"]
-               for c in drifted["checks"])
-    # and ACTUALLY removing the metric is still the vanished error
-    gone = gate(base, {"mfu": 0.4})
-    assert not gone["ok"]
-    assert any("host_blocked_frac" in e for e in gone["errors"])
-    # the 0.0 also survives the kind=metrics snapshot path
-    snap = {"kind": "metrics", "t": 1.0,
-            "metrics": {"bench_mfu": 0.4,
-                        "bench_host_blocked_frac": 0.0}}
-    assert extract_invariants(snap)["host_blocked_frac"] == 0.0
-
-
-def test_perf_gate_snapshot_prefers_measured_over_peak_constant():
-    """In an obs snapshot the static spec-peak gauge
-    (tmpi_cost_peak_hbm_gbps) sorts BEFORE the achieved tmpi_hbm_gbps —
-    the extractor must gate on the measurement, never the constant
-    (gating 819 vs 819 would pass any real bandwidth regression)."""
-    from theanompi_tpu.tools.perf_gate import extract_invariants, gate
-
-    snap = {"kind": "metrics", "t": 1.0, "metrics": {
-        "tmpi_cost_peak_hbm_gbps": 819.0, "tmpi_hbm_gbps": 300.0,
-        "tmpi_mfu": 0.4}}
-    assert extract_invariants(snap) == {"hbm_gbps": 300.0, "mfu": 0.4}
-    regressed = {"kind": "metrics", "t": 2.0, "metrics": {
-        "tmpi_cost_peak_hbm_gbps": 819.0, "tmpi_hbm_gbps": 100.0,
-        "tmpi_mfu": 0.4}}
-    assert not gate(snap, regressed)["ok"]
-
-
-def test_perf_gate_cli_reads_jsonl_tail(tmp_path):
-    """metrics.jsonl-style inputs gate on their last parseable object."""
-    from theanompi_tpu.tools.perf_gate import main as gate_main
-
-    p = str(tmp_path / "snap.jsonl")
-    with open(p, "w") as f:
-        f.write(json.dumps({"kind": "metrics", "t": 1.0,
-                            "metrics": {"bench_mfu": 0.4}}) + "\n")
-        f.write(json.dumps({"kind": "metrics", "t": 2.0,
-                            "metrics": {"bench_mfu": 0.41,
-                                        "bench_hbm_gbps": 5.0}}) + "\n")
-    assert gate_main([p, p]) == 0
-    assert gate_main([str(tmp_path / "missing.json"), p]) == 2

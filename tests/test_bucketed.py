@@ -239,32 +239,6 @@ def test_traffic_model_reports_bucket_geometry():
     assert "n_buckets" not in t_plain.detail
 
 
-def test_bench_bucket_sweep_table_shape():
-    """bench.py --bucket-sweep (in-process): the size-0 baseline row +
-    one bucketed row per engine variant, geometry columns filled, and
-    the mini-runs' val losses IDENTICAL across bucket sizes (the
-    sweep's own parity proof)."""
-    import importlib.util
-    import os as _os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", _os.path.join(_os.path.dirname(_os.path.dirname(
-            _os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    result = bench.bench_bucket_sweep(engines=("bsp",),
-                                      bucket_mbs=(0.0, 0.001),
-                                      max_steps=2)
-    rows = result["table"]
-    assert [r["bucket_mb"] for r in rows] == [0.0, 0.001]
-    base, bkt = rows
-    assert base["n_buckets"] == 1 and base["overlap_frac"] == 0.0
-    assert bkt["n_buckets"] > 1 and bkt["overlap_frac"] > 0
-    # bit-identical trajectory -> identical mini-run val loss
-    assert base["val_loss"] == bkt["val_loss"]
-    assert result["metric"] == "bucket_sweep_best_speedup_vs_unbucketed"
-    assert result["value"] is not None
-
 
 def test_traced_wire_bytes_match_declared_under_buckets():
     """The live SPMD101 cross-check (obs/attribution.traced_wire_bytes)

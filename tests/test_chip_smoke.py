@@ -17,7 +17,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _two_cores():
     # the rehearsal's children compile on every core they are given;
     # held to two, they cannot starve the timing-sensitive tests that
-    # other xdist workers run meanwhile (tests/test_decode_bench.py)
+    # other xdist workers run meanwhile
     os.sched_setaffinity(0, sorted(os.sched_getaffinity(0))[:2])
 
 

@@ -214,8 +214,8 @@ def test_abort_rejects_and_conserves_pages():
 
 
 def test_static_mode_runs_batches_to_completion():
-    """mode='static' is the bench strawman: admission only into an
-    empty batch. It must still serve everything correctly."""
+    """mode='static' admits only into an empty batch. It must still
+    serve everything correctly."""
     eng = make_engine(mode="static", max_seqs=2)
     set_tiny_params(eng)
     eng.warmup()
@@ -227,6 +227,31 @@ def test_static_mode_runs_batches_to_completion():
         eng.drain(timeout=60)
     assert all(len(r.tokens) == 4 for r in res)
     assert eng.stats()["tmpi_decode_served_total"] == 5.0
+
+
+def test_continuous_needs_fewer_iterations_than_static():
+    """The structural claim of continuous batching, as a count: the same
+    mixed-length requests, all queued before the engine starts (so the
+    schedule is the scheduler's alone, no clock in it), take strictly
+    fewer decode iterations when a freed slot is refilled at once than
+    when a batch runs to its longest member. ``mode="static"`` is this
+    count's reference: it fails if static is made to admit mid-batch."""
+    budgets = [12, 1, 12, 1, 1, 1, 1, 1]
+    iterations = {}
+    for mode in ("continuous", "static"):
+        eng = make_engine(mode=mode, max_seqs=2, max_new_tokens=12)
+        set_tiny_params(eng)
+        eng.warmup()
+        futs = [eng.submit(prompt(i + 1, i + 2), max_new_tokens=n)
+                for i, n in enumerate(budgets)]
+        eng.start()
+        try:
+            res = [f.result(60) for f in futs]
+        finally:
+            eng.drain(timeout=60)
+        assert [len(r.tokens) for r in res] == budgets
+        iterations[mode] = eng.stats()["tmpi_decode_iterations_total"]
+    assert 0 < iterations["continuous"] < iterations["static"], iterations
 
 
 def test_router_fronts_decode_replicas_unchanged(tmp_path):

@@ -208,7 +208,7 @@ def test_drain_equivalence_bsp(tmp_path):
     s4, r4 = _run(tmp_path, "async", 4, rule="bsp", n_epochs=2)
     assert s1["steps"] == s4["steps"] == 4
     assert r1 == r4
-    # dispatch accounting surfaced in the summary (bench.py reads these)
+    # dispatch accounting surfaced in the summary
     assert s4["dispatch_depth"] == 4
     assert s4["host_blocked_s"] >= 0.0
     assert 0.0 <= s4["host_blocked_frac"] <= 1.0

@@ -221,7 +221,7 @@ def test_facade_drain_writes_record_anomaly_and_bundle(tmp_path):
     # the breach gets its OWN flight bundle dir (not the numerics
     # anomaly budget)
     assert os.path.isdir(os.path.join(obs_dir, "anomaly_rank0-drift"))
-    # gauges: perf_gate's inputs are live
+    # gauges are live
     prom = obs.registry.to_prometheus()
     assert "tmpi_model_err_cost 0.5" in prom
     assert "tmpi_drift_breaches_total 1" in prom

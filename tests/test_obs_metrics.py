@@ -9,7 +9,6 @@ import pytest
 from theanompi_tpu.obs.metrics import (
     DEFAULT_BUCKETS,
     MetricsRegistry,
-    result_to_snapshot,
 )
 from theanompi_tpu.tools.check_obs_schema import validate_record
 
@@ -109,31 +108,6 @@ def test_emit_snapshot_writes_jsonl(tmp_path):
     lines = [json.loads(l) for l in p.read_text().splitlines()]
     assert [l["step"] for l in lines] == [1, 2]
     assert all(validate_record(l) == [] for l in lines)
-
-
-def test_result_to_snapshot_bench_satellite():
-    """bench.py emission rides the snapshot schema: numerics become
-    gauges, strings/bools/None become labels (ISSUE satellite)."""
-    result = {
-        "metric": "alexnet_imagenet_bsp_images_per_sec_1chip",
-        "value": 18500.3,
-        "unit": "images/sec",
-        "vs_baseline": 2.31,
-        "mfu": None,
-        "baseline_estimated": True,
-        "n_devices": 1,
-        "timing": {"k": 5, "median_s": 0.1},  # nested: must not leak
-    }
-    snap = result_to_snapshot(result, source="bench")
-    assert validate_record(snap) == []
-    assert snap["source"] == "bench"
-    assert snap["metrics"]["bench_value"] == pytest.approx(18500.3)
-    assert snap["metrics"]["bench_n_devices"] == 1
-    assert snap["labels"]["unit"] == "images/sec"
-    assert snap["labels"]["mfu"] == "None"
-    assert snap["labels"]["baseline_estimated"] == "True"
-    assert "bench_timing" not in snap["metrics"]
-    json.dumps(snap)
 
 
 def test_registry_thread_safety_smoke():

@@ -307,30 +307,3 @@ def test_cli_over_budget_refuses_naming_buffers(tmp_path):
     assert recs[0]["fit"] is False
     assert recs[1]["metrics"]["tmpi_preflight_fit"] == 0.0
 
-
-def test_preflight_record_feeds_perf_gate(tmp_path):
-    """The kind=preflight record is a gate snapshot: same peak passes,
-    a 2x memory regression fails, and the 0.0-shortfall trajectory is
-    keyed on presence (the `preflight_peak_bytes` invariant)."""
-    from theanompi_tpu.tools.perf_gate import extract_invariants, gate
-
-    base = {"kind": "preflight", "t": 1.0, "model": "mlp",
-            "engine": "bsp", "codec": "none", "n_devices": 8,
-            "peak_bytes": 2.0e6}
-    assert extract_invariants(base) == {"preflight_peak_bytes": 2.0e6}
-    assert gate(base, dict(base, peak_bytes=2.1e6))["ok"]
-    assert not gate(base, dict(base, peak_bytes=4.0e6))["ok"]
-    # the gauge spelling in a metrics snapshot resolves to the same key
-    snap = {"kind": "metrics", "t": 2.0,
-            "metrics": {"tmpi_preflight_peak_bytes": 2.0e6}}
-    assert extract_invariants(snap) == {"preflight_peak_bytes": 2.0e6}
-    assert gate(base, snap)["ok"]
-
-
-def test_profile_report_memory_block_feeds_perf_gate():
-    from theanompi_tpu.tools.perf_gate import extract_invariants
-
-    rep = {"kind": "profile_report", "mfu": 0.4,
-           "memory": {"peak_bytes": 3.0e6}}
-    inv = extract_invariants(rep)
-    assert inv["preflight_peak_bytes"] == 3.0e6 and inv["mfu"] == 0.4

@@ -188,15 +188,24 @@ def test_clean_dir_reads_completed(tmp_path):
     assert rep["steps"] == 10
 
 
-def test_committed_profile_dirs_are_reportable():
-    """The committed experiments/profile snapshots stay valid `tmpi
-    report` inputs (the lint_all budget test drives the CLI over them)."""
-    root = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "experiments", "profile")
-    for name in ("r11_baseline", "r17_flat"):
-        rep = build_report(os.path.join(root, name))
-        assert rep["verdict"] == "completed"
-        assert validate_record(rep) == []
+def test_profile_report_only_dir_is_reportable(tmp_path, capsys):
+    """A `tmpi profile` output dir (one ``report.json`` holding a
+    ``profile_report`` record, no JSONL stream) is a valid `tmpi report`
+    input: ``--json`` is schema-valid, says ``completed`` and prints the
+    same bytes twice (nothing wall-clock-derived rides the body)."""
+    (tmp_path / "report.json").write_text(json.dumps({
+        "kind": "profile_report", "model": "mlp", "engine": "bsp",
+        "codec": "none", "n_devices": 1, "device_kind": "cpu", "steps": 8,
+        "mfu": 0.5, "mfu_source": "calibrated",
+        "attribution": {"fractions": {"compute": 0.9, "comm": 0.0,
+                                      "host": 0.1, "residual": 0.0}}}))
+    assert report_main([str(tmp_path), "--json"]) == 0
+    out = capsys.readouterr().out
+    assert report_main([str(tmp_path), "--json"]) == 0
+    assert capsys.readouterr().out == out
+    rep = json.loads(out)
+    assert rep["verdict"] == "completed" and rep["n_events"] == 0
+    assert validate_record(rep) == []
 
 
 def write_serving_dir(obs, with_drop=False):

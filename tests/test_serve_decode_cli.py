@@ -1,8 +1,5 @@
-"""Acceptance: ``python bench.py --decode-bench`` runs on
-JAX_PLATFORMS=cpu, continuous batching beats the static strawman on the
-same mixed workload, and the TTFT/tokens-per-sec gauges ride the
-snapshot schema into perf_gate; ``tmpi serve --decode --selftest``
-serves generated tokens from a real checkpoint end-to-end."""
+"""``tmpi serve --decode --selftest`` serves generated tokens from a real
+checkpoint end-to-end, from the CLI."""
 
 import json
 import os
@@ -25,55 +22,6 @@ def _run(cmd, timeout=600):
     )
     assert p.returncode == 0, f"{cmd} failed:\n{p.stderr[-3000:]}"
     return [l for l in p.stdout.strip().splitlines() if l.strip()]
-
-
-def test_decode_bench_continuous_beats_static():
-    """ISSUE 20 acceptance: the bench runs on CPU, the continuous
-    engine serves the same mixed-length workload in strictly fewer
-    decode iterations than static batching (deterministic), the
-    wall-clock ratio agrees (> 1), and the gated gauges extract."""
-    lines = _run([
-        sys.executable, "bench.py", "--decode-bench",
-        "--serve-duration", "0.8",
-    ])
-    result = json.loads(lines[-1])
-    assert result["metric"] == "decode_tokens_per_sec"
-    assert result["unit"] == "tokens/sec"
-    assert result["value"] > 0
-    assert (0 < result["decode_p50_ttft_ms"]
-            <= result["decode_p99_ttft_ms"])
-    assert result["decode_tpot_ms"] > 0
-    # continuous batching is the tentpole claim: fewer iterations for
-    # the same tokens (structural, jitter-free) and higher tokens/sec
-    assert result["continuous_iterations"] < result["static_iterations"]
-    assert result["continuous_vs_static"] > 1.0, result
-    # len(prefill_buckets) + 1 programs, proven by the trace counter
-    assert result["compiled_programs"] == 3
-    # snapshot schema (second-to-last line), perf_gate's input shape
-    snapshot = json.loads(lines[-2])
-    assert snapshot["kind"] == "metrics"
-    assert validate_record(snapshot) == []
-    from theanompi_tpu.tools.perf_gate import extract_invariants
-
-    inv = extract_invariants(snapshot)
-    assert inv["decode_tokens_per_sec"] == result["decode_tokens_per_sec"]
-    assert inv["decode_p99_ttft_ms"] == result["decode_p99_ttft_ms"]
-
-
-def test_decode_baseline_gates(tmp_path):
-    """The committed experiments/decode_bench/baseline.json is a usable
-    perf_gate baseline: gating it against itself passes, and a 3x TTFT
-    regression fails."""
-    from theanompi_tpu.tools.perf_gate import main as gate_main
-
-    base = os.path.join(REPO_ROOT, "experiments", "decode_bench",
-                        "baseline.json")
-    assert gate_main([base, base]) == 0
-    snap = json.loads(open(base).read())
-    snap["metrics"]["bench_decode_p99_ttft_ms"] *= 3.0
-    cur = tmp_path / "regressed.json"
-    cur.write_text(json.dumps(snap))
-    assert gate_main([base, str(cur)]) == 1
 
 
 def test_cli_serve_decode_selftest_roundtrip(tmp_path):

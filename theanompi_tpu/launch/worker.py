@@ -1685,7 +1685,7 @@ def run_training(
     # device-truth step counter (host-fetched AFTER training): the host
     # loop counts dispatches, the counter inside the compiled step
     # counts executions — a dispatch that never ran on the device shows
-    # up as a mismatch here (chip_smoke.py and bench.py check it)
+    # up as a mismatch here (chip_smoke.py and benchmark/ check it)
     summary["device_steps"] = engine.get_step(state)
     # the devices this run's mesh actually held (not jax.devices(): an
     # explicit device list or a capped world may differ)
@@ -1694,7 +1694,7 @@ def run_training(
                          "kind": _dev0.device_kind, "count": int(n_dev)}
     # dispatch-pipeline accounting: how much of the train loop the host
     # spent BLOCKED on device syncs (the per-step tax dispatch_depth>1
-    # removes; bench.py reports this as host_blocked_frac)
+    # removes)
     summary["dispatch_depth"] = disp.depth
     # the key stream's engagement: keys that were ready when taken over
     # keys taken (1 less the first unit; falling = refill not ahead)
@@ -1728,8 +1728,7 @@ def run_training(
     )
     if obs.cost is not None and summary["images_per_sec"]:
         # achieved utilization from the SHARED cost model (the same
-        # numbers the live gauges carry; bench e2e/codec-sweep read
-        # these off the summary): per-step seconds recovered from the
+        # numbers the live gauges carry): per-step seconds recovered from the
         # throughput ledger so fused dispatches amortize correctly
         _sps = batch / summary["images_per_sec"]
         _mfu = obs.cost.mfu(_sps)

@@ -9,15 +9,11 @@ paper's channel table, two auxiliary classifiers during training
 Recipe per the reference: batch 32/worker scaled to the 32-worker BSP
 config, momentum 0.9, weight decay 1e-4(ish), polynomial LR decay.
 
-Single-chip performance ceiling (round-5 profile + layout probe,
-experiments/results/googlenet_layout.json): the step is ~35% max-pool
-sweeps (select-and-scatter backward 18% — already the measured optimum,
-see ops/pallas_pool.py) + 46% conv/elementwise fusions; channels-major
-trunk and concat-free inception were measured and REJECTED (XLA:TPU
-layout assignment makes both moot), batch 512 adopted for the
-single-chip bench row (+10% over 1024). The residual MFU gap vs the
-big-conv models is the inception architecture's pool-heavy,
-small-channel-conv structure itself, not a missing kernel.
+Layout: NHWC with channel-axis concats. A channels-major trunk and a
+concat-free inception were tried on an earlier backend and not adopted
+(XLA:TPU assigns its own internal layouts); the zoo's single-chip batch
+is 512 (models/zoo.py). No benchmark cell runs this model: its speed on
+the current chip is not measured.
 """
 
 from __future__ import annotations

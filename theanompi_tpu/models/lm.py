@@ -197,17 +197,16 @@ class MoELMModel(TransformerLMModel):
 
 
 class TransformerLM_136M(TransformerLMModel):
-    """GPT-2-small-scale benchable config (~136M params): the
-    single-chip throughput row for the beyond-parity LM stack
-    (``python bench.py --model transformer_lm``). 12 layers x d=768,
+    """GPT-2-small-scale config (~136M params): the benchmark's
+    ``lm136m`` configuration (``benchmark/configs/lm136m.json``, cell
+    ``lm136m-bsp1-train``). 12 layers x d=768,
     T=1024, 32k vocab, fused Pallas flash attention; bf16 compute
     (params stored fp32, matmuls/activations bf16 with fp32 softmax
-    statistics — transformer.py::cast_block_params), so the reported
-    MFU is measured against the bf16 peak the math actually runs at.
+    statistics — transformer.py::cast_block_params), so an
+    MFU is read against the bf16 peak the math actually runs at.
     Sized so TWO full f32 states (params + adam m/v) fit one v5e
-    alongside the un-sharded 32k-vocab logits: the bench runner cannot
-    donate its input state (it re-times from the same state), so a
-    350M config OOMs."""
+    alongside the un-sharded 32k-vocab logits: a caller that cannot
+    donate its input state still fits."""
 
     name = "transformer_lm_136m"
 
@@ -236,11 +235,10 @@ class TransformerLM_350M(TransformerLMModel):
     """GPT-2-medium-scale benchable config (~360M params): 24 layers x
     d=1024, T=1024, 32k vocab, fused Pallas flash attention, bf16
     compute, per-block remat (activation memory, not weights, is what
-    remains after donation). This size only fits one v5e because the
-    bench runner DONATES and threads the train state through its timed
-    trials for this row (``bench.py --model transformer_lm_350m``) —
+    remains after donation). This size only fits one v5e when the
+    caller DONATES the train state (``run_training`` does) —
     without donation two full f32 states (params + adam m/v ~ 4.3 GB)
-    coexist and OOM, which is why the 136M row was the round-4 cap."""
+    coexist and OOM."""
 
     name = "transformer_lm_350m"
 

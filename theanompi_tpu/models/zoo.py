@@ -1,12 +1,10 @@
-"""Benchable model registry: name -> (model class, single-chip global
-batch). Shared by the root ``bench.py`` harness and
+"""Model registry: name -> (model class, single-chip global batch).
+Shared by ``tmpi profile``, ``tmpi preflight``, ``tmpi chaos`` and
 ``tools/op_profile.py`` so the batch policy lives in one place.
 
 Batch policy: AlexNet runs the reference workload's GLOBAL batch
 (BASELINE config #2: 8 workers x 128 = 1024 — same SGD trajectory);
-GoogLeNet uses 512 (an earlier batch sweep,
-experiments/results/googlenet_layout.json, put its single-chip knee
-there and ran out of memory at 2048; config #3's global 1024 is a
+GoogLeNet uses 512 (config #3's global 1024 is a
 32-WORKER batch — at pod scale each chip sees 32 rows, so the
 single-chip batch is a free parameter). ResNet-50 uses config #4's
 batch 256; VGG16/WRN use the largest power-of-two that fits one chip's
@@ -46,13 +44,13 @@ def zoo_entry(name: str):
         return WRN, 1024
     if name == "transformer_lm":
         # beyond-parity LM row: ~136M params, T=1024, flash attention;
-        # batch in SEQUENCES (bench reports tokens/sec alongside)
+        # batch in SEQUENCES
         from theanompi_tpu.models.lm import TransformerLM_136M
 
         return TransformerLM_136M, 8
     if name == "transformer_lm_350m":
-        # GPT-2-medium scale (~370M params): needs the bench runner's
-        # donate-and-thread timing path (two f32 states would OOM a v5e)
+        # GPT-2-medium scale (~370M params): the caller must donate the
+        # train state (two f32 states would OOM a v5e)
         from theanompi_tpu.models.lm import TransformerLM_350M
 
         return TransformerLM_350M, 8

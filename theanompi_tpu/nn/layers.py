@@ -168,19 +168,16 @@ class Pool(Layer):
         sh, sw = self.stride
         dims, strides = (1, kh, kw, 1), (1, sh, sw, 1)
         if self.mode == "max":
-            # NOTE: AD of reduce_window-max lowers to select-and-scatter,
-            # and that IS the measured optimum on v5e for NHWC. The
-            # Theano-style eq-mask backward was tried three ways and all
-            # lost: plain jnp in two formulations (~2x slower end-to-end;
-            # round-4 re-measurement 135 ms vs ~3 ms for one batch-1024
-            # 28x28x480 stride-1 pool — XLA won't fuse the 9-way
-            # accumulation), and a register-resident Pallas kernel
-            # (ops/pallas_pool.py: GoogLeNet 5094 -> 2472 img/s — NHWC
-            # puts W on the sublane dim so shifted reads are misaligned
-            # shuffles, and the custom call is a fusion barrier; full
-            # analysis in that module's docstring). The Pallas kernel
-            # stays as an opt-in (TMPI_PALLAS_POOL=1) with Theano's
-            # all-maxima tie semantics.
+            # NOTE: AD of reduce_window-max lowers to select-and-scatter.
+            # The Theano-style eq-mask backward was tried three ways on
+            # an earlier backend and all lost: plain jnp in two
+            # formulations (XLA won't fuse the 9-way accumulation), and
+            # a register-resident Pallas kernel (ops/pallas_pool.py:
+            # NHWC puts W on the sublane dim so shifted reads are
+            # misaligned shuffles, and the custom call is a fusion
+            # barrier; full analysis in that module's docstring). The
+            # Pallas kernel stays as an opt-in (TMPI_PALLAS_POOL=1) with
+            # Theano's all-maxima tie semantics.
             from theanompi_tpu.ops import pallas_pool
 
             if pallas_pool.routable(self.window, self.stride, self.padding, x):
@@ -311,30 +308,21 @@ class BatchNorm(Layer):
     (``shard_map``/``pmap``), batch stats are averaged across replicas
     with ``lax.pmean`` — cross-replica BN for small per-device batches.
 
-    Performance note (round-4 probe, experiments/resnet_bn_probe.py, TPU
-    v5e, ResNet-50 batch 256, 8-step fused runs): the BN statistic
-    sweeps are ~51% of the train step (op_profile: 104
-    ``convert_reduce_fusion``s ≈ one fused two-moment pass per BN per
-    direction), and they are already near bandwidth-optimal — ~7 GB of
-    activation re-reads/step at an effective ~700 GB/s. Measured and
-    REJECTED alternatives:
-
-    - ``dtype=f32`` reduction args instead of an explicit upcast:
-      2370.7 vs 2370.4 img/s — XLA already fuses the convert (no-op).
-    - variadic ``lax.reduce`` computing (Σx, Σx²) in one declared pass:
-      334.9 img/s, 7.1x SLOWER — XLA:TPU lowers generic variadic
-      reduce as scalar code; the moments were already sibling-fused.
-    - batch 512: 2343 img/s (-1%) — the sweeps scale with the batch.
-
-    ADOPTED: normalize sweep computed in bf16 when x is bf16 (scale/
-    offset still derived in fp32): 2403 vs 2370 img/s (+1.4%), MFU
-    0.2905. The residual gap to MXU-bound MFU is the cost of two-pass
-    BN itself — removing it needs stats fused into the producer conv's
-    epilogue, which XLA does not expose; a Pallas conv is not worth
-    losing the MXU conv emitters for (the LRN matmul precedent,
-    measured at theanompi_tpu/nn/layers.py LRN, does not transfer:
-    LRN replaced a bandwidth-bound op with a matmul, BN's reduce IS
-    already minimal traffic).
+    Design note: the statistic sweeps are one fused two-moment pass per
+    BN per direction over the activations (bandwidth-bound, not
+    MXU-bound), and the normalize sweep is computed in bf16 when x is
+    bf16 (scale/offset still derived in fp32). Tried on an earlier
+    backend and not adopted (``experiments/resnet_bn_probe.py`` repeats
+    the comparison; no benchmark cell runs a BatchNorm model, so nothing
+    here is measured on the current chip): ``dtype=f32`` reduction args
+    instead of an explicit upcast (XLA already fuses the convert), and a
+    variadic ``lax.reduce`` computing (Σx, Σx²) in one declared pass
+    (XLA:TPU lowers a generic variadic reduce as scalar code; the
+    moments were already sibling-fused). Removing the second pass needs
+    stats fused into the producer conv's epilogue, which XLA does not
+    expose (the LRN matmul above does not transfer: LRN replaced a
+    bandwidth-bound op with a matmul, BN's reduce is already minimal
+    traffic).
     """
 
     def __init__(
@@ -377,9 +365,9 @@ class BatchNorm(Layer):
             new_state = state
         inv = lax.rsqrt(var + self.eps) * params["scale"]
         if x.dtype == jnp.bfloat16:
-            # bf16 normalize sweep (+1.4% measured, docstring table):
-            # per-channel constants derived in fp32, the big elementwise
-            # pass reads/writes bf16 only
+            # bf16 normalize sweep (class docstring): per-channel
+            # constants derived in fp32, the big elementwise pass
+            # reads/writes bf16 only
             y = (x - mean.astype(x.dtype)) * inv.astype(x.dtype) + params[
                 "bias"
             ].astype(x.dtype)

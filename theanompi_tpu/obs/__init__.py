@@ -76,9 +76,7 @@ On-disk layout under ``obs_dir`` (schemas:
                             step, budget + fit verdict when a budget
                             exists) next to a snapshot carrying the
                             tmpi_preflight_peak_bytes /
-                            tmpi_preflight_fit gauges — the memory
-                            trajectory tools/perf_gate.py gates via
-                            its preflight_peak_bytes invariant; runs
+                            tmpi_preflight_fit gauges; runs
                             with a checkpoint scrubber active
                             (--scrub-interval, or the supervisor's
                             retry-time pass) add one kind=scrub record
@@ -100,7 +98,7 @@ On-disk layout under ``obs_dir`` (schemas:
                             per-model EWMA relative error of predicted
                             vs measured (model_err_cost / model_err_
                             traffic / model_err_memory, matching the
-                            tmpi_model_err_* gauges perf_gate diffs),
+                            tmpi_model_err_* gauges),
                             the worst-offending component per model
                             (per-link for traffic, per-leaf-family for
                             memory), the tolerance band, and the
@@ -253,7 +251,6 @@ from theanompi_tpu.obs.health import Heartbeat, StallWatchdog  # noqa: F401
 from theanompi_tpu.obs.metrics import (  # noqa: F401
     REGISTRY,
     MetricsRegistry,
-    result_to_snapshot,
 )
 from theanompi_tpu.obs.numerics import (  # noqa: F401
     AnomalyDetector,
@@ -408,7 +405,7 @@ class Observability:
         if self._metrics_f is not None:
             # one comm record per declaration (schema:
             # tools/check_obs_schema.py kind=comm): the codec proof line
-            # bench --codec-sweep and plot_history read back
+            # plot_history reads back
             import json as _json
             import time as _time
 
@@ -911,8 +908,7 @@ class Observability:
             if self._last_attr is not None:
                 # one kind=profile record per snapshot: the newest
                 # step-time attribution (schema:
-                # tools/check_obs_schema.py) — the machine-readable
-                # trail tools/perf_gate.py diffs. Written BEFORE the
+                # tools/check_obs_schema.py). Written BEFORE the
                 # snapshot line: downstream readers (and tests) may
                 # treat the file's last record as the metrics snapshot.
                 import json as _json

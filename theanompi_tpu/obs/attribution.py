@@ -1,9 +1,9 @@
 """Step-time attribution: where does the training step go?
 
-The evidence for where a step's time goes was scattered across four
-tools that did not compose: XLA
-cost-analysis math lived only inside ``bench.py --compute``,
-``tools/op_profile.py`` needed a manually captured trace, spans measure
+The evidence for where a step's time goes was scattered across
+pieces that did not compose: XLA cost analysis
+(``utils/flops.compiled_cost``), ``tools/op_profile.py`` needed a
+manually captured trace, spans measure
 host wall only, and ``traffic_model()`` comm bytes were never
 reconciled against measured step time. This module is the one place
 the pieces meet (GC3, PAPERS.md arXiv:2201.11840: you can't schedule

@@ -14,9 +14,7 @@ HBM high-water from ``jax.local_devices()[i].memory_stats()`` where the
 backend exposes it — and the watchdog maintains one EWMA relative
 error per model, surfaced three ways:
 
-- live gauges ``tmpi_model_err_{cost,traffic,memory}`` (the numbers
-  ``perf_gate`` learns to diff, so model honesty regressions fail CI
-  exactly like MFU regressions);
+- live gauges ``tmpi_model_err_{cost,traffic,memory}``;
 - change-gated ``kind=drift`` JSONL records in ``metrics.jsonl`` naming
   the worst-offending component (per-link for traffic, per-leaf-family
   for memory) — schema: tools/check_obs_schema.py;
@@ -306,6 +304,6 @@ class DriftWatchdog:
     def as_metrics(self) -> dict:
         """Live gauge map (facade prefixes ``tmpi_``):
         ``model_err_{cost,traffic,memory}`` for every source that has
-        at least one sample — the values ``perf_gate`` diffs."""
+        at least one sample."""
         return {f"{DRIFT_GAUGE_PREFIX}{src}": float(self.ewma[src])
                 for src in DRIFT_SOURCES if self.ewma[src] is not None}

@@ -13,9 +13,8 @@ history), which remain the Recorder's job. Two expositions:
 - **JSONL snapshots** (``snapshot()``): one self-contained
   ``{"kind": "metrics", "t": ..., "step": ..., "metrics": {...}}``
   object per line, the same machine-readable stream the Recorder
-  emits — downstream parsing (bench.py, tools/plot_history.py,
-  tools/check_obs_schema.py) reads one format for bench results and
-  training telemetry alike.
+  emits — downstream parsing (tools/plot_history.py,
+  tools/check_obs_schema.py) reads one format.
 
 ``REGISTRY`` is the process-wide default; the training driver builds a
 fresh ``MetricsRegistry`` per run so tests and stacked runs in one
@@ -288,26 +287,6 @@ class MetricsRegistry:
         fileobj.write(json.dumps(rec) + "\n")
         fileobj.flush()
         return rec
-
-
-def result_to_snapshot(result: dict, source: str = "bench") -> dict:
-    """Re-express a bench.py-style result dict in the metrics-snapshot
-    schema (numeric fields become ``<source>_<key>`` samples; strings
-    ride along under ``labels``), so bench output and training telemetry
-    share one JSONL format (ISSUE satellite: bench emission)."""
-    reg = MetricsRegistry()
-    labels = {}
-    for k, v in result.items():
-        if isinstance(v, bool) or v is None:
-            labels[k] = str(v)
-        elif isinstance(v, (int, float)) and math.isfinite(float(v)):
-            reg.gauge(f"{source}_{k}").set(float(v))
-        elif isinstance(v, str):
-            labels[k] = v
-        # nested dicts/lists (timing, scaling table, dispatch_sweep)
-        # stay in the native bench line only — snapshot metrics are a
-        # flat numeric map by schema (tools/check_obs_schema.py)
-    return reg.snapshot(extra={"source": source, "labels": labels})
 
 
 # process-wide default registry (the training driver uses a fresh

@@ -1,22 +1,20 @@
 """Pallas max-pool (3x3, stride 1, SAME) with a fused eq-mask backward
-— MEASURED AND REJECTED as the default path; opt-in via
-``TMPI_PALLAS_POOL=1``.
+— not the default path; opt-in via ``TMPI_PALLAS_POOL=1``.
 
 Why this kernel was built: GoogLeNet's nine inception pool branches are
 3x3/stride-1 max pools, and XLA lowers the AD of ``reduce_window`` max
-to ``select-and-scatter`` — ~36 ms of a 202 ms batch-1024 step on one
-v5e (round-4 ``tools/op_profile`` table), ~18% of the step in pool
-BACKWARD alone. The classic eq-mask backward
+to ``select-and-scatter``, the pool BACKWARD's whole cost. The classic
+eq-mask backward
 (``dx[p] = sum_over_window_offsets g[q] * [x[p] == y[q]]``) is
 bandwidth-optimal on paper; the pure-jnp formulation loses because XLA
-won't fuse the 9-way shifted accumulation (135 ms for ONE batch-1024
-28x28x480 pool vs ~3 ms s-a-s), so this Pallas version keeps the whole
-spatial map in one VMEM block (inception maps are <= 28x28) and runs
-the accumulation register-resident.
+won't fuse the 9-way shifted accumulation, so this Pallas version keeps
+the whole spatial map in one VMEM block (inception maps are <= 28x28)
+and runs the accumulation register-resident.
 
-**Measured result (round 4, v5e, batch 1024): end-to-end GoogLeNet
-5094 -> 2472 img/s with this kernel routed in — a 2.1x LOSS.** Two
-physics reasons, recorded for the next person who tries:
+**Routed in, it lost end to end on an earlier backend; on the current
+chip it is not measured** (no benchmark cell runs it; ROADMAP S5/D6: a
+parent-against-flipped pair decides, and the loser's path and switch
+go). Two reasons it lost, recorded for the next person who tries:
 
 1. In NHWC the +-1 spatial shifts fall on W — the SUBLANE dim of the
    (8, 128) vector tile — so every shifted read is a misaligned

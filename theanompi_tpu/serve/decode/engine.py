@@ -114,8 +114,8 @@ class DecodeEngine:
     ``kv_pages`` fixed device pages of ``page_size`` positions bound
     total cache capacity; ``max_seqs`` bounds the decode batch width.
     ``mode="static"`` disables iteration-level admission (a batch runs
-    to completion before the next forms) — the strawman the decode
-    bench's continuous-vs-static ratio measures against.
+    to completion before the next forms) — kept only as a test's
+    reference for the iteration count (tests/test_decode_engine.py).
     ``temperature`` is the default sampling temperature (0 = greedy);
     sampling draws from a PRNG stream keyed by ``seed`` and the
     iteration counter INSIDE the jitted step, so replays are

@@ -9,8 +9,8 @@ subsystem, kept free of jax/threading so it unit-tests in microseconds:
   page exhaustion mid-generation; a reservation that doesn't fit stops
   admission (head-of-line FIFO — no starvation of long prompts behind
   short ones). ``mode="static"`` only admits into an EMPTY batch and
-  then runs it to completion — the classic static-batching strawman the
-  bench's continuous-vs-static ratio measures against.
+  then runs it to completion — classic static batching, kept only as a
+  test's reference for the iteration count.
 * **Eviction**: deadline sweeps over both waiting and running
   sequences, finish-on-max-tokens, and drain-time aborts — every exit
   path releases the sequence's pages back to the free-list (the chaos

@@ -2,13 +2,13 @@
 
 Every machine-readable line this framework emits — Recorder history
 (``<run>.jsonl``), span traces (``obs/spans_rank*.jsonl``), metric
-snapshots (``obs/metrics.jsonl``, bench.py's snapshot line), heartbeat
+snapshots (``obs/metrics.jsonl``), heartbeat
 and stall reports, the serving engine's ``serve``/``reload`` records
 (``obs/serve.jsonl``), the continuous-batching decode engine's
 ``decode`` records (``obs/decode.jsonl``, ``tmpi_decode_*`` metric
 family) — must match ONE of the record kinds below, keyed
-by the ``kind`` field. Downstream parsing (bench.py drivers, BENCH_*.json
-diffing, tools/plot_history.py) reads these streams; without an
+by the ``kind`` field. Downstream parsing (``tmpi report``, ``tmpi top``,
+tools/plot_history.py) reads these streams; without an
 enforced schema they drift silently and the first symptom is a broken
 plot three PRs later. The schema table here is the single source of
 truth (README "Observability" documents it for humans) and a test
@@ -84,8 +84,7 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
     # Observability.set_traffic_model when the engine declares its
     # traffic model): the sustained per-step bytes a codec run moves
     # (`wire_bytes`) next to the fp32 equivalent (`raw_bytes`) and the
-    # codec that did it — the per-run compression proof line bench.py
-    # --codec-sweep reads back.
+    # codec that did it — the per-run compression proof line.
     "comm": {
         "t": (_NUM, True),
         "rule": ((str,), True),
@@ -276,7 +275,7 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
     # below: they must sum to 1.0 +/- 0.02, the decomposition's own
     # invariant), the roofline classification, and the utilization
     # readings (mfu vs spec peak, or mfu_calibrated on devices without
-    # one; achieved hbm_gbps). tools/perf_gate.py diffs these.
+    # one; achieved hbm_gbps).
     "profile": {
         "rank": ((int,), True),
         "t": (_NUM, True),
@@ -294,8 +293,7 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
     # tools/preflight.py): one record per pre-flight run appended to
     # metrics.jsonl next to a metrics snapshot carrying the
     # tmpi_preflight_peak_bytes / tmpi_preflight_fit /
-    # tmpi_preflight_state_bytes gauges — the memory trajectory line
-    # tools/perf_gate.py diffs (gate metric `preflight_peak_bytes`).
+    # tmpi_preflight_state_bytes gauges.
     # `peak_bytes` is the PREDICTED per-device peak (XLA memory
     # analysis of the lowered step + the declared donation audit);
     # `fit`/`budget_bytes` appear when a budget exists (--budget-gb or

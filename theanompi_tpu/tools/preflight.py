@@ -27,9 +27,7 @@ Exit codes: 0 = fits and no findings, 1 = over budget or findings,
 
 With ``--obs-dir`` a ``kind=preflight`` JSONL record plus a metrics
 snapshot carrying ``tmpi_preflight_peak_bytes`` / ``tmpi_preflight_fit``
-land in ``<obs-dir>/metrics.jsonl`` — the same trajectory hooks
-``tools/perf_gate.py`` diffs (``preflight_peak_bytes`` is a gate
-metric), so the memory trajectory is enforceable like MFU.
+land in ``<obs-dir>/metrics.jsonl``.
 
 The SAME rule families run over the committed tiny-model matrix inside
 ``tmpi lint`` (tools/analyze/memory.py / precision.py) with golden
@@ -94,7 +92,7 @@ def _build(model_name: str, engine_name: str, mesh_dims: Optional[tuple],
     else:
         mesh = make_mesh(mesh_dims[0] if mesh_dims else None)
     # batch semantics shared with `tmpi profile` — same flags, same
-    # configured program (the perf gate compares their outputs)
+    # configured program
     model, global_batch = resolve_model_and_batch(
         model_cls, engine_name, mesh.devices.size, batch)
     if engine_name == "nd" and len(mesh.axis_names) > 1:
@@ -196,8 +194,7 @@ def run_preflight(
 def _write_obs(obs_dir: str, report: dict) -> None:
     """The ``kind=preflight`` record + a metrics snapshot with the
     ``tmpi_preflight_*`` gauges, appended to ``<obs_dir>/metrics.jsonl``
-    (schema: tools/check_obs_schema.py) — the memory-trajectory line
-    ``tools/perf_gate.py`` diffs."""
+    (schema: tools/check_obs_schema.py)."""
     os.makedirs(obs_dir, exist_ok=True)
     t = time.time()
     rec = {

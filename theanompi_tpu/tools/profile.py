@@ -13,9 +13,7 @@ Optionally captures a ``jax.profiler`` trace and joins the
 ops the model does NOT explain: the fusion-work candidates.
 
 Writes ``report.json`` (+ ``trace/`` under ``--trace``) into ``--out``
-and prints the human table. The report is the unit
-``tools/perf_gate.py`` diffs — run it in CI against a committed
-baseline to make the BENCH_r* trajectory enforceable.
+and prints the human table.
 
 Usage::
 
@@ -95,7 +93,7 @@ def resolve_model_and_batch(model_cls, engine_name: str, n_dev: int,
     device (global = n x batch), everything else shards one global
     batch rounded up to the mesh. Shared with ``tmpi preflight`` so
     the two tools always configure the SAME program for the same
-    flags (the perf gate compares their outputs)."""
+    flags."""
     recipe = model_cls.default_recipe()
     base = int(batch or recipe.batch_size)
     if engine_name in ("easgd", "gosgd"):
@@ -358,9 +356,7 @@ def run_profile(
         "device_kind": jax.devices()[0].device_kind,
         "steps": steps,
         "global_batch": global_batch,
-        # the MFU-push knobs this reading was taken under — the
-        # committed before/after pair (experiments/profile/) is
-        # meaningless without them
+        # the knobs this reading was taken under
         "knobs": {"fused_update": bool(fused_update),
                   "allreduce_buckets": float(allreduce_buckets or 0.0),
                   "strategy": strategy,
@@ -374,8 +370,8 @@ def run_profile(
             ) if med else None,
             "k": steps,
         },
-        # top-level mfu: the one number the perf gate diffs — spec MFU
-        # where the device has a peak, the calibrated stand-in elsewhere
+        # top-level mfu: spec MFU where the device has a peak, the
+        # calibrated stand-in elsewhere
         "mfu": attr.mfu if attr.mfu is not None else attr.mfu_calibrated,
         "mfu_source": attr.peak_source,
         "host_blocked_frac": round(host_frac, 6),
@@ -402,8 +398,7 @@ def run_profile(
             "raw_bytes_per_step": traffic.raw_bytes_per_step_amortized,
             "wire_bytes_per_step": traffic.bytes_per_step_amortized,
             "compression_ratio": traffic.compression_ratio,
-            # per-link-class split (0 on single-slice meshes): the
-            # perf-gate's DCN-byte invariant diffs these like MFU
+            # per-link-class split (0 on single-slice meshes)
             "ici_bytes_per_step": traffic.ici_bytes_per_step,
             "dcn_bytes_per_step": traffic.dcn_bytes_per_step,
             "raw_ici_bytes_per_step": traffic.raw_ici_bytes_per_step,

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache + compile-time accounting.
 
 One rule for every entry point that compiles (``tmpi`` train / serve /
-profile, ``bench.py``): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+profile, ``benchmark/``): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 reads it itself and this module sets nothing; otherwise the cache lives
 at ONE fixed, git-ignored path inside the checkout. The directory is
 part of every cache key, so a temp name, a pid or a timestamp in it

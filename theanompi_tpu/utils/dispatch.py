@@ -33,7 +33,7 @@ n_images attribution), just later — tests/test_dispatch.py proves the
 streams bit-identical modulo the wall-clock ``images_per_sec`` field.
 
 ``host_blocked_s`` accumulates the time the host actually spent blocked
-inside drains — ``host_blocked_frac`` in the run summary / bench output
+inside drains — ``host_blocked_frac`` in the run summary
 is this over the train-loop wall time, the direct measurement of the
 per-step host tax this module exists to remove. It is the sum of the
 recorder's ``drain`` brackets, not a timer of its own.

@@ -4,7 +4,7 @@ single per-compiled-executable cost authority.
 The reference had no FLOPs accounting at all — its recorder reported
 images/sec only (reference: ``lib/recorder.py``, SURVEY.md §5.1). On TPU
 the honest scaling story needs achieved TFLOP/s vs the chip's peak, so
-the bench and recorder report MFU alongside img/s (BASELINE metric
+the recorder reports MFU alongside img/s (BASELINE metric
 "scaling eff" is defined in those terms).
 
 FLOPs and HBM bytes come from XLA's own cost model on the COMPILED
@@ -14,8 +14,8 @@ accounted for. Peak numbers are small device-kind tables (public
 spec-sheet bf16 FLOP/s and HBM GB/s); unknown devices (CPU test meshes)
 report ``mfu=None`` rather than a made-up number.
 
-Every consumer shares this module (attribution-profiler PR): bench.py's
-compute mode, the ``tmpi profile`` subcommand (tools/profile.py), the
+Every consumer shares this module (attribution-profiler PR):
+the ``tmpi profile`` subcommand (tools/profile.py), the
 live ``tmpi_mfu``/``tmpi_hbm_gbps`` gauges (obs/attribution.py via each
 engine's ``cost_model()`` hook), and the run summary's ``mfu`` field —
 one :class:`CostModel` per compiled step, no hand-rolled duplicates.
