@@ -10,8 +10,10 @@ seed. Unpack the parent into a directory that ``.gitignore`` lists, then:
 
 A run spec is ``side:cell:seed:trace`` with side ``P`` or ``C``; a fifth
 field ``spans`` runs the cell through ``experiments/bench_spans.py`` of that
-checkout (span means, ``keys_ready_share``). Each run is the benchmark's own
-command in a process of its own, from its checkout's root; this process never
+checkout (span means, ``keys_ready_share``, ``dispatch_ahead_share``), and
+``spans1`` the same at ``--dispatch-depth 1`` (a control on one commit; only
+for a checkout whose wrapper knows the option: PR 31 on). Each run is the
+benchmark's own command in a process of its own, from its checkout's root; this process never
 touches JAX, so the chip is the child's. Per run: the whole output under
 ``--out`` (a traced run: also its ``breakdown`` and its ``*.xplane.pb``) and,
 on standard output, one line of the result's numbers and the
@@ -28,13 +30,16 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TELL = ("[bench] between two runs", "[spans]", "[bench] longest step brackets")
+# a run spec's fifth field -> the script, and its own options, the cell runs through
+VIA = {"": ["benchmark/run.py"],
+       "spans": ["experiments/bench_spans.py"],
+       "spans1": ["experiments/bench_spans.py", "--dispatch-depth", "1"]}
 
 
 def one(spec, roots, out, seconds, tiny):
-    side, cell, seed, trace, *rest = spec.split(":")
+    side, cell, seed, trace, via = (spec.split(":") + [""])[:5]
     cwd = os.path.join(ROOT, roots[side])
-    script = "experiments/bench_spans.py" if rest == ["spans"] else "benchmark/run.py"
-    cmd = [sys.executable, script, "--workload", cell, "--seed", seed,
+    cmd = [sys.executable, *VIA[via], "--workload", cell, "--seed", seed,
            "--seconds", str(seconds), "--trace", trace] + (["--tiny"] if tiny else [])
     try:
         done = subprocess.run(cmd, cwd=cwd, capture_output=True, text=True, timeout=900)
@@ -42,7 +47,7 @@ def one(spec, roots, out, seconds, tiny):
         lines = done.stdout.strip().splitlines()
     except subprocess.TimeoutExpired as e:
         rc, text, lines = 124, f"timeout: {e}", []
-    with open(os.path.join(out, f"{cell}_{side}_{seed}_t{trace}.log"), "w") as f:
+    with open(os.path.join(out, f"{cell}_{side}{via}_{seed}_t{trace}.log"), "w") as f:
         f.write(text)
     try:
         result = json.loads(lines[-1])

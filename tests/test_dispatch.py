@@ -193,14 +193,120 @@ def _rows(save_dir, name):
 
 
 def _run(tmp_path, tag, depth, **kw):
+    """``depth`` None: the driver's default."""
     args = dict(_TINY)
     args.update(kw)
+    if depth is not None:
+        args["dispatch_depth"] = depth
     d = str(tmp_path / tag)
     summary = run_training(
-        model_cls=TinyCNN, devices=8, save_dir=d, run_name="run",
-        dispatch_depth=depth, **args,
+        model_cls=TinyCNN, devices=8, save_dir=d, run_name="run", **args,
     )
     return summary, _rows(d, "run")
+
+
+# -- two steps in flight by default (ISSUE 31) -------------------------------
+
+class _Pending:
+    """A step's metric as the driver sees it: not ready until it is
+    drained (``ready`` False), or ready at once."""
+
+    def __init__(self, value, ready):
+        self.value, self.ready = value, ready
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        self.ready = True
+        return self
+
+    def __array__(self, *a, **kw):
+        self.ready = True
+        return np.asarray(self.value)
+
+    def __float__(self):  # a boundary's flush hands the leaf on as it is
+        return float(np.asarray(self))
+
+
+@pytest.mark.parametrize("depth,ready,want", [
+    (1, False, 0.0), (2, True, 0.0), (2, False, 4 / 6), (4, False, 4 / 6)],
+    ids=["depth1", "depth2_ready_at_once", "depth2_pending", "depth4_pending"])
+def test_dispatcher_counts_dispatches_made_ahead_of_the_device(depth, ready, want):
+    disp = MetricsDispatcher(FakeRecorder(), depth=depth)
+    assert disp.ahead_share is None  # no dispatch yet
+    for s in range(1, 7):
+        if s == 4:
+            disp.flush()  # a boundary: the next dispatch finds nothing in flight
+        disp.note_dispatch()
+        disp.push(s, {"loss": _Pending(np.float32(s), ready)})
+    assert disp.dispatches == 6
+    assert disp.ahead_share == pytest.approx(want)
+    disp.flush()
+    assert [r[0] for r in disp.rec.rows] == [1, 2, 3, 4, 5, 6]
+
+
+def test_the_default_is_two_steps_in_flight():
+    import inspect
+
+    from theanompi_tpu.cli import build_parser
+
+    assert inspect.signature(run_training).parameters["dispatch_depth"].default == 2
+    args = build_parser().parse_args(["BSP", "1", "tinymodel.py", "TinyCNN"])
+    assert args.dispatch_depth == 2
+    args = build_parser().parse_args(
+        ["BSP", "1", "tinymodel.py", "TinyCNN", "--dispatch-depth", "1"])
+    assert args.dispatch_depth == 1  # the classic sync stays a legal value
+
+
+@pytest.mark.parametrize("fuse", [1, 2], ids=["per_step", "fused2"])
+def test_default_run_keeps_one_entry_in_flight_and_emits_depth1s_rows(
+        tmp_path, monkeypatch, fuse):
+    in_flight = []
+    push = MetricsDispatcher.push
+
+    def spying_push(disp, *a, **kw):
+        push(disp, *a, **kw)
+        in_flight.append(disp.in_flight)
+
+    monkeypatch.setattr(MetricsDispatcher, "push", spying_push)
+    kw = dict(rule="bsp", n_epochs=2, steps_per_dispatch=fuse,
+              dataset_kwargs={**_TINY["dataset_kwargs"], "n_train": 128})
+    s2, r2 = _run(tmp_path, "default", None, **kw)
+    assert s2["dispatch_depth"] == 2 and s2["steps"] == 8
+    assert in_flight == [1] * (8 // fuse)
+    in_flight.clear()
+    s1, r1 = _run(tmp_path, "sync", 1, **kw)
+    assert s1["dispatch_depth"] == 1 and in_flight == [0] * (8 // fuse)
+    # the same rows (steps, values, n_images), one step later
+    assert r1 == r2
+    assert s1["dispatch_ahead_share"] == 0.0
+
+
+@pytest.mark.parametrize("fuse,ready,want", [
+    (1, True, 0.0), (1, False, 6 / 8), (2, False, 2 / 4)],
+    ids=["ready_at_once", "pending_until_drained", "fused2_pending"])
+def test_a_runs_dispatch_ahead_share(monkeypatch, fuse, ready, want):
+    """2 epochs of 4 steps at the default depth: every dispatch but the
+    first after each boundary's flush finds the step before still running,
+    when its results are not ready until drained; none does when they are
+    ready at once."""
+    from theanompi_tpu.parallel.bsp import BSPEngine
+
+    name = "train_step" if fuse == 1 else "fused_train_step"
+    orig = getattr(BSPEngine, name)
+
+    def step(engine, state, images, labels, rng, numerics=False):
+        state, metrics = orig(engine, state, images, labels, rng, numerics=numerics)
+        return state, {k: _Pending(v, ready) for k, v in metrics.items()}
+
+    monkeypatch.setattr(BSPEngine, name, step)
+    summary = run_training(
+        model_cls=TinyCNN, devices=8, rule="bsp", n_epochs=2,
+        steps_per_dispatch=fuse,
+        **{**_TINY, "dataset_kwargs": {**_TINY["dataset_kwargs"], "n_train": 128}})
+    assert summary["steps"] == 8
+    assert summary["dispatch_ahead_share"] == pytest.approx(want)
 
 
 def test_drain_equivalence_bsp(tmp_path):

@@ -48,9 +48,10 @@ def per_step(request):
     return request.param, summary, summary.pop("recorder")
 
 
-@pytest.fixture(scope="module")
-def fused():
-    summary = run_training(steps_per_dispatch=2, **_TINY)
+@pytest.fixture(scope="module", params=[1, None], ids=["depth1", "default_depth"])
+def fused(request):
+    depth = {} if request.param is None else {"dispatch_depth": request.param}
+    summary = run_training(steps_per_dispatch=2, **depth, **_TINY)
     return summary, summary.pop("recorder")
 
 
@@ -145,6 +146,11 @@ def test_a_fused_group_has_one_span_of_each_name_under_its_last_step(fused):
     for s in range(2, STEPS + 1, 2):
         t0 = [rec.span(name, s)[0] for name in FIVE]
         assert t0 == sorted(t0)
+        if s < STEPS:
+            # the default keeps two groups in flight: a group is drained
+            # after the next one's dispatch (ISSUE 31); depth 1: before
+            ahead = rec.span("dispatch", s + 2)[0] < rec.span("drain", s)[0]
+            assert ahead == (summary["dispatch_depth"] == 2)
     # the rows stay one a step
     assert [r["step"] for r in rec.history["train"]] == list(range(1, STEPS + 1))
 

@@ -135,12 +135,12 @@ def test_no_eager_split_is_left_in_the_driver():
     assert len(errs) == 1 and "eager key split" in errs[0]
 
 
-@pytest.mark.parametrize("fuse,depth", [(1, 1), (1, 2), (2, 1)],
-                         ids=["per_step", "per_step_depth2", "fused2"])
+@pytest.mark.parametrize("fuse,depth", [(1, 1), (1, 2), (2, 1), (2, 2)],
+                         ids=["per_step", "per_step_depth2", "fused2", "fused2_depth2"])
 def test_a_run_takes_its_keys_ready_and_saves_the_chains_carry(fuse, depth, tmp_path):
     summary = run_training(ckpt_dir=str(tmp_path), seed=5, steps_per_dispatch=fuse,
                            dispatch_depth=depth, **_TINY)
-    assert summary["steps"] == STEPS
+    assert summary["steps"] == STEPS and summary["dispatch_depth"] == depth
     # all but the first dispatch unit's keys were waiting
     assert summary["keys_ready_share"] == (STEPS - fuse) / STEPS
     path = latest_checkpoint(str(tmp_path), verify=True)

@@ -169,3 +169,68 @@ def test_cli_rollback_requires_ckpt_dir():
     with pytest.raises(SystemExit, match="sigterm-grace requires --ckpt-dir"):
         tmpi_main(["BSP", "8", tiny, "TinyCNN", "--synthetic",
                    "--sigterm-grace", "10"])
+
+
+# -- the anomaly paths with two steps in flight (ISSUE 31) -------------------
+# 4 steps an epoch, the poisoned batch at step 6: step 7 follows it inside
+# the epoch, so at the default depth its row drains with step 7 dispatched.
+_MID = dict(_TINY, n_epochs=2,
+            dataset_kwargs={**_TINY["dataset_kwargs"], "n_train": 128})
+
+
+def _train_rows(save_dir):
+    with open(os.path.join(save_dir, "run.jsonl")) as f:
+        rows = [json.loads(line) for line in f]
+    return [r["step"] for r in rows if r.get("kind") == "train"]
+
+
+@pytest.mark.parametrize("depth,state_step", [(1, 6), (None, 7)],
+                         ids=["depth1", "default_depth"])
+def test_halt_mid_epoch_leaves_the_state_of_the_newest_dispatched_step(
+        tmp_path, depth, state_step):
+    """A halt names the anomalous step whatever the depth; the state it
+    leaves (the triage bundle's capture) is that of the newest DISPATCHED
+    step, one further at the default; no crash save makes it resumable."""
+    from theanompi_tpu.utils.checkpoint import checkpoint_step, latest_checkpoint
+
+    kw = {} if depth is None else {"dispatch_depth": depth}
+    with pytest.raises(NumericsAnomaly):
+        run_training(
+            ckpt_dir=str(tmp_path / "ck"), obs_dir=str(tmp_path / "obs"),
+            save_dir=str(tmp_path), run_name="run",
+            numerics_freq=1, on_anomaly="halt",
+            inject_faults=["nan_batch@6"], **kw, **_MID,
+        )
+    recs = [json.loads(l) for l in
+            (tmp_path / "obs" / "numerics_rank0.jsonl").read_text().splitlines()]
+    assert min(r["step"] for r in recs if r["kind"] == "anomaly") == 6
+    # rows up to the newest dispatched step: the unwinding flush drains it
+    assert _train_rows(str(tmp_path)) == list(range(1, state_step + 1))
+    newest = latest_checkpoint(str(tmp_path / "ck"), verify=True)
+    assert checkpoint_step(newest) == 4  # the boundary before the anomaly
+    captured = latest_checkpoint(
+        str(tmp_path / "obs" / "anomaly_rank0" / "state"), verify=True)
+    assert checkpoint_step(captured) == state_step
+
+
+def test_rollback_mid_epoch_lands_where_depth1_lands(tmp_path):
+    """The rollback discards the step dispatched past the anomalous one:
+    restore, skip and replay give depth 1's final parameters, bit for bit."""
+    import numpy as np
+
+    from theanompi_tpu.utils.checkpoint import latest_checkpoint
+
+    finals = []
+    for tag, kw in (("sync", {"dispatch_depth": 1}), ("default", {})):
+        out = run_training(
+            ckpt_dir=str(tmp_path / tag), obs_dir=str(tmp_path / tag / "obs"),
+            numerics_freq=1, on_anomaly="rollback",
+            rollback_budget=1, rollback_skip=1,
+            inject_faults=["nan_batch@6"], **kw, **_MID,
+        )
+        assert (out["rollbacks"], out["skipped_steps"], out["steps"]) == (1, 1, 7)
+        with np.load(latest_checkpoint(str(tmp_path / tag), verify=True)) as z:
+            finals.append({k: z[k] for k in z.files if not k.startswith("__")})
+    assert finals[0].keys() == finals[1].keys()
+    for k in finals[0]:
+        np.testing.assert_array_equal(finals[0][k], finals[1][k], err_msg=k)

@@ -136,14 +136,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "exchange in the scan, GoSGD keeps its gossip "
                         "cadence per substep; amortizes per-step host "
                         "dispatch latency")
-    p.add_argument("--dispatch-depth", type=int, default=1,
+    p.add_argument("--dispatch-depth", type=int, default=2,
                    help="async dispatch pipeline: keep up to K steps in "
                         "flight before the host blocks on a metrics "
-                        "fetch (utils/dispatch.py). 1 = classic per-step "
+                        "fetch (utils/dispatch.py). 2 (default): step N "
+                        "is queued before step N-1's metrics are "
+                        "drained, so the device never waits for the "
+                        "host between two steps; rows, the anomaly "
+                        "policies and the heartbeat's drained step lag "
+                        "the dispatch by one step. 1 = classic per-step "
                         "sync; recorder JSONL rows are bit-identical "
-                        "either way, deeper pipelines just emit them "
-                        "later. Costs K extra in-flight input batches "
-                        "of HBM; see README 'Async dispatch pipeline'")
+                        "either way. State is donated (no second copy); "
+                        "see README 'Async dispatch pipeline'")
     p.add_argument("--accum-steps", type=int, default=1,
                    help="gradient accumulation: split each (per-device) "
                         "batch into this many microbatches inside the step "

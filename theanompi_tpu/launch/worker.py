@@ -157,9 +157,12 @@ def run_training(
     n_slices: Optional[int] = None,
     steps_per_dispatch: int = 1,
     # async dispatch pipeline (utils/dispatch.py): keep up to this many
-    # steps in flight before the host blocks on a metrics D2H; 1 = the
-    # classic per-step sync (bit-identical recorder rows either way)
-    dispatch_depth: int = 1,
+    # steps in flight before the host blocks on a metrics D2H. 2 (the
+    # default): step N is queued before step N-1's metrics are drained,
+    # so the device goes from one step to the next without the host;
+    # 1 = the classic per-step sync (bit-identical recorder rows either
+    # way; at 2 a row, and what reads it, lags its dispatch by one step)
+    dispatch_depth: int = 2,
     accum_steps: int = 1,
     # N-D parallelism axes (BSP rule only; LM models — parallel/nd.py):
     tp: int = 1,
@@ -1024,7 +1027,8 @@ def run_training(
     obs.set_flight_state_saver(_flight_state_saver)
     # Async dispatch pipeline (utils/dispatch.py): the ONLY
     # host<->device sync in the train loops below lives in the
-    # dispatcher's drain (lint: tools/check_hot_loop.py). depth=1
+    # dispatcher's drain (lint: tools/check_hot_loop.py). depth=2, the
+    # default, drains step N-1 with step N already queued; depth=1
     # reproduces the classic per-step sync exactly. on_row feeds each
     # drained row (already host-side) to the flight ring + anomaly
     # detection — numerics telemetry adds no sync of its own. Wired
@@ -1052,6 +1056,7 @@ def run_training(
         that make the NEXT unit's keys, queued behind the program just
         dispatched; each a recorder bracket under the dispatched group's
         last step number. -> (state, metrics)."""
+        disp.note_dispatch()  # before the call: is the device still busy?
         last = step_count + n
         carry = keys.carry
         subs = keys.take(n, stacked)
@@ -1699,6 +1704,11 @@ def run_training(
     # the key stream's engagement: keys that were ready when taken over
     # keys taken (1 less the first unit; falling = refill not ahead)
     summary["keys_ready_share"] = keys.ready_share
+    # the pipeline's engagement: dispatches made while the step before
+    # was still running on the device, over dispatches (depth 2: 1 less
+    # the first after each flush; about 0 at depth 1; falling = the host
+    # has become the pace)
+    summary["dispatch_ahead_share"] = disp.ahead_share
     # numerics flight recorder: anomalies seen at drain time (0 when
     # numerics is off) — a nonzero count with policy 'record'/'dump' is
     # the "check the triage bundle" signal for sweep drivers

@@ -3,7 +3,14 @@
 ISSUE 2 removed the per-step host sync from ``launch/worker.py``'s
 train loops — metric D2H fetches live ONLY in the dispatch pipeline's
 drain (``utils/dispatch.py``), so the host can keep ``--dispatch-depth``
-steps in flight. This lint keeps it that way: it fails if a host-
+steps in flight: two by default (ISSUE 31), so that step N is queued
+before step N-1's metrics are fetched and the device never waits for the
+host between two steps. What lags the dispatch by one step at that
+default is what rides the drain (the recorder row, ``on_row``'s anomaly
+detection, the heartbeat's ``last_drained_step``); the state, the step
+count and the key carry do not, and every boundary flushes first. One
+extra sync in the loop body would put the host back between every two
+steps. This lint keeps it that way: it fails if a host-
 materializing call (``float(...)``, ``.item(...)``, ``np.asarray(...)``,
 ``jax.device_get(...)``, ``block_until_ready(...)``) reappears inside a
 train loop — the kind of one-line "just print the loss" patch that

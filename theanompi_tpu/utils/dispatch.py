@@ -25,6 +25,22 @@ device or the host is the bottleneck. ``flush()`` (epoch / exchange /
 checkpoint boundaries) blocks once on the newest in-flight step and
 attributes the remaining window evenly across the drained entries.
 
+**Depth 2 is the driver's default** (``run_training``, ``tmpi``; PR 31):
+the driver dispatches step N, refills the key stream, and ``push(N)``
+drains step N-1 — its blocking D2H returns with N already queued, so the
+device goes from N-1's last operation to N's first without the host, and
+the drain's wake, the row, the fetch and the next dispatch run under
+step N. What lags by one step: step N-1's recorder row, ``on_row`` (the
+flight ring, anomaly detection and with it ``--on-anomaly`` halt and
+rollback, which then act with step N already dispatched),
+``last_drained_step`` in the heartbeat, and the amortized step seconds.
+What does not: the state, the step count and the key carry (a
+checkpoint pairs the three as of the newest DISPATCHED step), the
+watchdog's ``on_step``, and every boundary (epoch end, EASGD exchange,
+validation, checkpoint, preemption), each of which ``flush()``es first.
+:meth:`MetricsDispatcher.note_dispatch` counts how often the pipeline was
+in fact ahead of the device (``summary["dispatch_ahead_share"]``).
+
 With ``depth=1`` every push drains immediately — the attributed time is
 dispatch + block, exactly what the old ``end("step", sync=...)`` bracket
 measured, and rows are emitted at the same points in the JSONL stream.
@@ -70,6 +86,16 @@ def _block_on(metrics: dict) -> None:
             return
 
 
+def _still_running(metrics: dict) -> bool:
+    """Whether the step that produced ``metrics`` has NOT yet executed,
+    asked without blocking (``jax.Array.is_ready``; host values: done)."""
+    for v in metrics.values():
+        is_ready = getattr(v, "is_ready", None)
+        if is_ready is not None:
+            return not is_ready()  # one leaf, as in ``_block_on``
+    return False
+
+
 class MetricsDispatcher:
     """Ring buffer of in-flight step metrics (see module docstring).
 
@@ -112,13 +138,32 @@ class MetricsDispatcher:
         # amortized per-substep seconds of the most recent sync; None
         # while steps are in flight without a completed sync
         self.last_step_seconds: Optional[float] = None
+        # step dispatches seen by note_dispatch, and those of them made
+        # while the step before was still running on the device
+        self.dispatches = 0
+        self.dispatches_ahead = 0
 
     @property
     def in_flight(self) -> int:
         """Entries pushed but not yet drained."""
         return len(self._buf)
 
+    @property
+    def ahead_share(self) -> Optional[float]:
+        """Dispatches made while the step before was still running, over
+        dispatches (depth 2, device the pace: 1 less the first after each
+        flush; depth 1: 0; falling = the host has become the pace)."""
+        return self.dispatches_ahead / self.dispatches if self.dispatches else None
+
     # -- driver hooks --------------------------------------------------------
+    def note_dispatch(self) -> None:
+        """Call right before a step program's dispatch: counts it, and
+        whether the newest in-flight step is still running (no blocking:
+        one ``is_ready`` on one leaf; nothing in flight = not ahead)."""
+        self.dispatches += 1
+        if self._buf and _still_running(self._buf[-1][1]):
+            self.dispatches_ahead += 1
+
     def note_wait(self, dt: float) -> None:
         """Report data-wait time (the recorder's ``wait`` bracket) so the
         amortized step attribution excludes it — keeping the wait/step
