@@ -25,9 +25,9 @@ def tiny_lm(**kw):
 
 
 def make_cache(arch, n_pages=16, max_seqs=2, max_pages_per_seq=8):
+    page = (PAGE, arch.n_heads, arch.d_model // arch.n_heads)
     return PagedKVCache(
-        n_layers=arch.n_layers, n_heads=arch.n_heads,
-        head_dim=arch.d_model // arch.n_heads, page_size=PAGE,
+        n_layers=arch.n_layers, page_size=PAGE, k_page=page, v_page=page,
         n_pages=n_pages, max_seqs=max_seqs,
         max_pages_per_seq=max_pages_per_seq,
     )
@@ -191,21 +191,10 @@ def test_moe_decode_smoke():
     arch = MoETransformerLM(vocab=32, d_model=32, n_heads=2, n_layers=2,
                             d_ff=64, max_len=64, n_experts=4, attn="ring")
     params = arch.init(jax.random.PRNGKey(1))
-    cache = PagedKVCache(
-        n_layers=2, n_heads=2, head_dim=16, page_size=PAGE, n_pages=16,
-        max_seqs=2, max_pages_per_seq=8,
-    )
+    cache = make_cache(arch)
     out1 = greedy_generate(arch, params, cache, 0, [4, 9, 2], 5)
     cache.release(0)
-    out2 = greedy_generate(arch, params, make_cache_moe(arch), 0,
+    out2 = greedy_generate(arch, params, make_cache(arch), 0,
                            [4, 9, 2], 5)
     assert out1 == out2
     assert all(0 <= t < 32 for t in out1)
-
-
-def make_cache_moe(arch):
-    return PagedKVCache(
-        n_layers=arch.n_layers, n_heads=arch.n_heads,
-        head_dim=arch.d_model // arch.n_heads, page_size=PAGE,
-        n_pages=16, max_seqs=2, max_pages_per_seq=8,
-    )

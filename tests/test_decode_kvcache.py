@@ -63,8 +63,8 @@ def test_freelist_alloc_zero():
 
 def test_cache_reserve_release_lifecycle():
     cache = PagedKVCache(
-        n_layers=1, n_heads=1, head_dim=4, page_size=4, n_pages=8,
-        max_seqs=2, max_pages_per_seq=4,
+        n_layers=1, page_size=4, n_pages=8, max_seqs=2, max_pages_per_seq=4,
+        k_page=(4, 1, 4), v_page=(4, 1, 4),
     )
     # worst-case reservation: 6 positions over page_size 4 -> 2 pages
     pages = cache.reserve(0, 6)
@@ -86,8 +86,8 @@ def test_cache_reserve_release_lifecycle():
 
 def test_cache_reserve_exhaustion_and_slot_bound():
     cache = PagedKVCache(
-        n_layers=1, n_heads=1, head_dim=4, page_size=4, n_pages=4,
-        max_seqs=2, max_pages_per_seq=4,
+        n_layers=1, page_size=4, n_pages=4, max_seqs=2, max_pages_per_seq=4,
+        k_page=(4, 1, 4), v_page=(4, 1, 4),
     )
     with pytest.raises(KVExhausted, match="at most"):
         cache.reserve(0, 17)  # 5 pages > max_pages_per_seq
@@ -100,8 +100,8 @@ def test_cache_reserve_exhaustion_and_slot_bound():
 
 def test_cache_release_all():
     cache = PagedKVCache(
-        n_layers=1, n_heads=1, head_dim=4, page_size=2, n_pages=6,
-        max_seqs=3, max_pages_per_seq=2,
+        n_layers=1, page_size=2, n_pages=6, max_seqs=3, max_pages_per_seq=2,
+        k_page=(2, 1, 4), v_page=(2, 1, 4),
     )
     cache.reserve(0, 3)
     cache.reserve(2, 4)
@@ -113,8 +113,8 @@ def test_cache_release_all():
 
 def test_cache_pool_shapes_fixed():
     cache = PagedKVCache(
-        n_layers=3, n_heads=2, head_dim=8, page_size=4, n_pages=5,
-        max_seqs=2, max_pages_per_seq=4,
+        n_layers=3, page_size=4, n_pages=5, max_seqs=2, max_pages_per_seq=4,
+        k_page=(4, 2, 8), v_page=(4, 2, 8),
     )
     # scratch page rides at index n_pages: pool holds n_pages + 1
     assert cache.k_pool.shape == (3, 6, 4, 2, 8)

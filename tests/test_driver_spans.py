@@ -331,7 +331,7 @@ def test_every_pallas_call_in_ops_passes_a_name():
                 # a module-level constant beside its kernel
                 assert isinstance(name[0], ast.Name) and name[0].id in constants, f"{path}:{node.lineno}"
                 names.add(constants[name[0].id])
-    assert calls == 13 and len(names) == 13
-    assert {"moe_gmm", "moe_tgmm"} <= names
+    assert calls == 15 and len(names) == 15
+    assert {"moe_gmm", "moe_tgmm", "mla_decode", "mla_cache_write"} <= names
     # the roofline readers find the flash kernels by these two stems
     assert {n for n in names if "flash" in n} == {"flash_fwd", "flash_bwd", "flash_bwd_2d"}

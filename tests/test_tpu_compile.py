@@ -260,3 +260,71 @@ def test_trinity_mini_cut_train_step_fits_one_v5e(topo, one_chip,
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert 11e9 < live < 14e9, f"{live / 1e9:.2f} GB"
+
+
+def _mistral_small_4_decode(one_chip, monkeypatch):
+    """The served model at its published widths, its engine's pools and the
+    shapes of a decode step, all as shapes on the described chip."""
+    from theanompi_tpu.models.mistral4 import MistralSmall4_EP8
+    from theanompi_tpu.ops import pallas_attention as pa
+    from theanompi_tpu.ops import pallas_mla as pm
+    from theanompi_tpu.ops import pallas_moe as pg
+
+    for module in (pa, pm, pg):
+        _mosaic(monkeypatch, module)
+    model, page, pages, S = MistralSmall4_EP8(), 128, 2176, 32
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    spec = model.cache_spec(page)
+    pools = [jax.ShapeDtypeStruct((6, pages + 1, *spec[k]), jnp.bfloat16, sharding=one_chip)
+             for k in ("k_page", "v_page")]
+    slots = {d: jax.ShapeDtypeStruct((S,), d, sharding=one_chip) for d in (jnp.int32, jnp.bool_, jnp.float32)}
+    tables = jax.ShapeDtypeStruct((S, 69), jnp.int32, sharding=one_chip)
+    return model, page, params, pools, slots, tables
+
+
+def test_mistral_small_4_decode_step_updates_its_donated_pools_in_place(
+        one_chip, no_persistent_cache, monkeypatch):
+    """32 slots over a 1.07 GB latent pool, 6 layers at the published
+    widths: six ``mla_decode`` calls, one ``mla_cache_write``, eighteen
+    grouped products; the donated pools alias the outputs and NO copy of a
+    pool is made (a scatter of single positions made the compiler re-lay a
+    whole pool out twice a step; ``[page, 64]`` rotated pages a copy a layer)."""
+    model, page, params, pools, slots, tables = _mistral_small_4_decode(one_chip, monkeypatch)
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, *pools, tables, slots[jnp.int32], slots[jnp.int32], slots[jnp.bool_],
+        slots[jnp.float32]).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 6 + 1 + 18
+    assert "mla_decode" in text and "mla_cache_write" in text and "moe_gmm" in text
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    assert pool_bytes == 2177 * 128 * 3840 and mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.1e9  # 19 MB; a copy of a pool would be 0.2 or 0.9 GB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.slow
+def test_mistral_small_4_prefill_of_8192_positions_fits_beside_its_weights(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The expanded prefill of one 8,192 bucket: flash attention at 32 heads
+    of 128, the grouped products over 36,864 buffer rows, whole pages
+    written to the donated pools; 0.77 GB of temporaries beside 5.75 GB of
+    weights and the pools."""
+    model, page, params, pools, slots, _ = _mistral_small_4_decode(one_chip, monkeypatch)
+    tokens = jax.ShapeDtypeStruct((8192,), jnp.int32, sharding=one_chip)
+    pages = jax.ShapeDtypeStruct((8192 // page,), jnp.int32, sharding=one_chip)
+
+    def prefill(p, t, pg, k, v):
+        return model.decode_prefill(p, t, pg, k, v, page_size=page)
+
+    mem = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, tokens, pages, *pools).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 2177 * 128 * 3840 and mem.temp_size_in_bytes < 1.5e9

@@ -29,6 +29,8 @@ MODEL_REGISTRY = {
     "moe_lm": ("theanompi_tpu.models.lm", "MoELMModel"),
     "afmoe_lm": ("theanompi_tpu.models.afmoe", "AfmoeLM"),
     "trinity_mini_ep8": ("theanompi_tpu.models.afmoe", "TrinityMini_EP8"),
+    "mistral4_lm": ("theanompi_tpu.models.mistral4", "Mistral4LM"),
+    "mistral_small_4_ep8": ("theanompi_tpu.models.mistral4", "MistralSmall4_EP8"),
 }
 
 

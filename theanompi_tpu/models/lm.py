@@ -135,6 +135,15 @@ class TransformerLMModel(Model):
     # the functional arch so the MoE subclass inherits them unchanged
     # (its arch binds the dense top-1 Switch FFN).
 
+    def cache_spec(self, page_size: int) -> dict:
+        """What ``DecodeEngine`` builds the paged pools from: a page of
+        either pool holds ``[page_size, n_heads, head_dim]`` fp32 K or V
+        rows; the programs do not take the pools donated."""
+        a = self.arch
+        page = (page_size, a.n_heads, a.d_model // a.n_heads)
+        return {"kind": "kv", "k_page": page, "v_page": page, "dtype": jnp.float32,
+                "donate": False}
+
     def decode_prefill(self, params, tokens, pages, k_pool, v_pool, *,
                        page_size: int):
         """Cache one padded prompt's K/V pages; see

@@ -134,14 +134,23 @@ class RoutedStats(NamedTuple):
     load_max_over_mean: jax.Array  # largest over mean rows of a held expert
 
 
-def route_topk(h, router_w, bias, k: int, scale: float):
-    """Sigmoid scores over all experts in fp32 (``highest``: a score's
-    rounding decides which expert a pair lands on), the ``k`` best by
-    ``score + bias`` (the bias selects only), weights the chosen scores
-    normalised to ``scale``. -> (idx [T, k] int32, w [T, k] fp32)."""
-    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(s + lax.stop_gradient(bias), k)
+def route_topk(h, router_w, bias, k: int, scale: float, scoring: str = "sigmoid"):
+    """Scores over all experts in fp32 (``highest``: a score's rounding
+    decides which expert a pair lands on), the ``k`` best chosen, weights
+    the chosen scores normalised to ``scale``. ``scoring="sigmoid"``: a
+    sigmoid a logit, chosen by ``score + bias`` (the bias selects only);
+    ``"softmax"``: a softmax over all the logits, no bias (``bias`` is
+    ``None``). -> (idx [T, k] int32, w [T, k] fp32)."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(s + lax.stop_gradient(bias), k)
+    elif scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = lax.top_k(s, k)
+    else:
+        raise ValueError(f"route_topk: scoring {scoring!r} (sigmoid|softmax)")
     sel = jnp.take_along_axis(s, idx, axis=-1)
     return idx, sel / (jnp.sum(sel, axis=-1, keepdims=True) + 1e-20) * scale
 

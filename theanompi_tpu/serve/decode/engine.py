@@ -184,14 +184,21 @@ class DecodeEngine:
                 f"{self.max_context} positions at page_size "
                 f"{self.page_size})"
             )
+        # the cache by kind: the MODEL says what a page of each pool holds,
+        # in which dtype, and whether its programs take the pools donated
+        # (they then write each pool once, after every read: no copy)
+        spec = model.cache_spec(self.page_size)
+        self.cache_kind = str(spec["kind"])
+        self._donate = bool(spec["donate"])
         self._cache = PagedKVCache(
             n_layers=arch.n_layers,
-            n_heads=arch.n_heads,
-            head_dim=arch.d_model // arch.n_heads,
             page_size=self.page_size,
             n_pages=int(kv_pages),
             max_seqs=int(max_seqs),
             max_pages_per_seq=max_pages_per_seq,
+            k_page=spec["k_page"],
+            v_page=spec["v_page"],
+            dtype=spec["dtype"],
         )
         self._sched = DecodeScheduler(
             self._cache, prefill_buckets=buckets, mode=mode
@@ -228,8 +235,10 @@ class DecodeEngine:
                 temp, key, page_size=self.page_size,
             )
 
-        self._prefill = jax.jit(_counted_prefill)
-        self._decode = jax.jit(_counted_decode)
+        self._prefill = jax.jit(
+            _counted_prefill, donate_argnums=(3, 4) if self._donate else ())
+        self._decode = jax.jit(
+            _counted_decode, donate_argnums=(1, 2) if self._donate else ())
 
         from theanompi_tpu.parallel.recipe import ShardingRecipe
 
@@ -241,8 +250,7 @@ class DecodeEngine:
         # a pool left on the process-default device would change
         # placement (and retrace every program) the first time a step
         # returns it from the params' device
-        self._cache.k_pool, self._cache.v_pool = self.sharding.place_replicated(
-            (self._cache.k_pool, self._cache.v_pool))
+        self._place_pools()
 
         self._served: Optional[ServedParams] = None
         self._swap_lock = threading.Lock()
@@ -288,6 +296,14 @@ class DecodeEngine:
         self._g_step = self.registry.gauge(
             "tmpi_decode_params_step", help="checkpoint step currently served"
         )
+        self.registry.gauge(
+            "tmpi_decode_kv_pool_bytes",
+            help="bytes of the two cache pools (kind=kv|latent)",
+        ).set(float(self._cache.pool_bytes), kind=self.cache_kind)
+        self.registry.gauge(
+            "tmpi_decode_kv_bytes_per_position",
+            help="cache bytes one position takes over all layers",
+        ).set(float(self._cache.bytes_per_position), kind=self.cache_kind)
         self._c_requests = self.registry.counter(
             "tmpi_decode_requests_total",
             help="generations by outcome "
@@ -389,6 +405,11 @@ class DecodeEngine:
         })
 
     # -- lifecycle ----------------------------------------------------------
+    def _place_pools(self) -> None:
+        c = self._cache
+        c.k_pool, c.v_pool = self.sharding.place_replicated(
+            (c.k_pool, c.v_pool))
+
     def warmup(self) -> int:
         """AOT-compile every program before the first request: one
         prefill per bucket (pages all-scratch — the warmup K/V land on
@@ -407,6 +428,8 @@ class DecodeEngine:
             pages = jnp.full((b // self.page_size,), c.scratch, jnp.int32)
             out = self._prefill(served.params, toks, pages, c.k_pool, c.v_pool)
             jax.block_until_ready(out)  # compile now, discard scratch writes
+            if self._donate:  # the pools went into the call: these are they
+                c.k_pool, c.v_pool = out
         S = c.max_seqs
         nxt, _lg, _k, _v = self._decode(
             served.params, c.k_pool, c.v_pool,
@@ -415,6 +438,8 @@ class DecodeEngine:
             jnp.zeros((S,), jnp.float32), np.int32(0),
         )
         np.asarray(nxt)
+        if self._donate:
+            c.k_pool, c.v_pool = _k, _v
         return self.compile_count
 
     @property
@@ -710,7 +735,15 @@ class DecodeEngine:
         """Failure path for a poisoned iteration: reject every
         generation the loop owns, RELEASING their KV pages so the
         free-list stays conserved (the chaos oracle checks) and the
-        engine can keep serving if the error was input-local."""
+        engine can keep serving if the error was input-local — with
+        new pools where the failed program had taken them donated."""
+        c = self._cache
+        if self._donate and (c.k_pool.is_deleted() or c.v_pool.is_deleted()):
+            # the program that raised had taken the pools donated: they
+            # are gone. Every sequence that had rows in them is failed
+            # below, so fresh zeros are a sound state to serve on
+            c.reset_pools()
+            self._place_pools()
         failed = 0
         for slot in list(self._sched.running):
             seq = self._sched.remove(slot, "evicted")
@@ -750,6 +783,9 @@ class DecodeEngine:
             "tmpi_decode_kv_pages_free": float(self._cache.pages_free),
             "tmpi_decode_kv_pages_out_total": float(fl.pages_out_total),
             "tmpi_decode_kv_pages_in_total": float(fl.pages_in_total),
+            "tmpi_decode_kv_pool_bytes": float(self._cache.pool_bytes),
+            "tmpi_decode_kv_bytes_per_position": float(
+                self._cache.bytes_per_position),
             "tmpi_decode_iterations_total": float(self._iterations),
             "tmpi_decode_tokens_total": float(self._tokens_total),
             "tmpi_decode_served_total": self._c_requests.value(status="served"),
@@ -780,7 +816,8 @@ class DecodeEngine:
         tools/check_obs_schema.py). Replica members stamp
         ``replica_id``."""
         rec = {"kind": "decode", "t": time.time(),
-               "params_step": self.params_step, "metrics": self.stats()}
+               "params_step": self.params_step, "cache_kind": self.cache_kind,
+               "metrics": self.stats()}
         if self.replica_id is not None:
             rec["replica_id"] = self.replica_id
         return rec

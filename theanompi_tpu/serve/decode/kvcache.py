@@ -2,9 +2,15 @@
 
 ONE preallocated device pool per pool-kind (K and V), shaped
 
-    [n_layers, n_pages + 1, page_size, n_heads, head_dim]
+    [n_layers, n_pages + 1, *page]
 
-so the compiled decode/prefill programs see a FIXED shape forever: pages
+where ``page`` is what the MODEL says one page of that pool holds
+(``Model.cache_spec``): ``[page_size, n_heads, head_dim]`` twice for
+per-head keys and values, ``[page_size, kv_lora]`` and ``[qk_rope,
+page_size]`` for a latent cache (one normed latent row and one rotated key
+row a position, shared by all heads). The free-list, the page tables, the
+scratch page and the conservation counters know nothing of the kind, so
+the compiled decode/prefill programs see a FIXED shape forever: pages
 are handed out and returned by a host-side free-list, and the programs
 receive gather/scatter *indices* (per-sequence page tables) instead of
 resized buffers. Index ``n_pages`` is the SCRATCH page — never owned by
@@ -123,14 +129,16 @@ class PagedKVCache:
         self,
         *,
         n_layers: int,
-        n_heads: int,
-        head_dim: int,
         page_size: int,
         n_pages: int,
         max_seqs: int,
         max_pages_per_seq: int,
+        k_page: Sequence[int],
+        v_page: Sequence[int],
         dtype=None,
     ):
+        """``k_page`` / ``v_page``: the shape of one page of each pool
+        (``[page_size, n_heads, head_dim]`` twice for per-head K and V)."""
         import jax.numpy as jnp  # deferred: FreeList stays importable sans jax
 
         if page_size <= 0:
@@ -145,15 +153,35 @@ class PagedKVCache:
         self.scratch = self.n_pages  # the sacrificial page index
         self.max_seqs = int(max_seqs)
         self.max_pages_per_seq = int(max_pages_per_seq)
-        shape = (n_layers, self.n_pages + 1, self.page_size, n_heads, head_dim)
-        dt = dtype if dtype is not None else jnp.float32
-        self.k_pool = jnp.zeros(shape, dt)
-        self.v_pool = jnp.zeros(shape, dt)
+        lead = (int(n_layers), self.n_pages + 1)
+        self._shapes = (lead + tuple(k_page), lead + tuple(v_page))
+        self._dtype = jnp.dtype(dtype if dtype is not None else jnp.float32)
+        self.reset_pools()
         self.free_list = FreeList(self.n_pages)
         self.page_tables = np.full(
             (self.max_seqs, self.max_pages_per_seq), self.scratch, np.int32
         )
         self._slot_pages: dict = {}
+
+    def reset_pools(self) -> None:
+        """Allocate both pools anew, zeroed (construction; and the
+        engine's recovery when a program that took the pools DONATED
+        raised and left them deleted — it fails every sequence that had
+        rows in them, so zeros are a sound state)."""
+        import jax.numpy as jnp
+
+        self.k_pool, self.v_pool = (jnp.zeros(s, self._dtype) for s in self._shapes)
+
+    @property
+    def pool_bytes(self) -> int:
+        """Bytes of the two pools together."""
+        return sum(int(np.prod(s)) for s in self._shapes) * self._dtype.itemsize
+
+    @property
+    def bytes_per_position(self) -> int:
+        """Cache bytes one position takes over all layers: a page of each
+        pool holds ``page_size`` positions, whatever its kind."""
+        return self.pool_bytes // ((self.n_pages + 1) * self.page_size)
 
     @property
     def max_context(self) -> int:

@@ -398,6 +398,9 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
         "params_step": ((int,), True),
         "metrics": ((dict,), True),
         "replica_id": ((int,), False),
+        # what the paged pools hold (Model.cache_spec): "kv" per-head
+        # keys and values, "latent" one latent and one rotated row
+        "cache_kind": ((str,), False),
     },
     # replica-group router (serve/router.py): one record per routing
     # event in <obs_dir>/router.jsonl. `event` says which: "health"

@@ -745,6 +745,9 @@ def load_checkpoint(
             raise ValueError(
                 f"checkpoint leaf {key!r} has shape {arr.shape}, expected {want_shape}"
             )
+        if arr.dtype.kind == "V" and arr.dtype.itemsize == np.dtype(want_dtype).itemsize:
+            # an .npz keeps a bfloat16 leaf as raw 2-byte records: the same bits
+            arr = arr.view(want_dtype)
         new_leaves.append(arr.astype(want_dtype))
     return jax.tree_util.tree_unflatten(treedef, new_leaves), rng
 
