@@ -24,8 +24,23 @@ def tiny_lm(**kw):
     return arch, params
 
 
+def tiny_moe():
+    """The MoE LM through the same two paged functions (its ``ffn=`` hook).
+    A capacity no token can exceed: the training forward then drops nothing
+    and is the oracle of the dense top-1 decode FFN."""
+    from theanompi_tpu.models.moe import MoETransformerLM
+
+    arch = MoETransformerLM(vocab=32, d_model=32, n_heads=2, n_layers=2,
+                            d_ff=64, max_len=64, n_experts=4,
+                            capacity_factor=4.0, attn="ring")
+    return arch, arch.init(jax.random.PRNGKey(1))
+
+
+ARCHS = {"dense": tiny_lm, "moe": tiny_moe}
+
+
 def make_cache(arch, n_pages=16, max_seqs=2, max_pages_per_seq=8):
-    page = (PAGE, arch.n_heads, arch.d_model // arch.n_heads)
+    page = (PAGE, arch.d_model)  # n_heads * head_dim: lane-dense rows
     return PagedKVCache(
         n_layers=arch.n_layers, page_size=PAGE, k_page=page, v_page=page,
         n_pages=n_pages, max_seqs=max_seqs,
@@ -51,9 +66,10 @@ def run_prefill(arch, params, cache, slot, prompt, bucket=None):
     )
 
 
-def decode_once(arch, params, cache, slots):
+def decode_once(arch, params, cache, slots, stale=None):
     """One decode iteration; ``slots`` maps slot -> (seq_len, last_tok,
-    temperature). Returns the [S] next-token array."""
+    temperature), ``stale`` an INACTIVE slot -> the seq_len it is left
+    with. Returns the [S] next-token array."""
     S = cache.max_seqs
     seq_lens = np.zeros((S,), np.int32)
     last = np.zeros((S,), np.int32)
@@ -61,6 +77,8 @@ def decode_once(arch, params, cache, slots):
     temp = np.zeros((S,), np.float32)
     for s, (sl, lt, tp) in slots.items():
         seq_lens[s], last[s], active[s], temp[s] = sl, lt, True, tp
+    for s, sl in (stale or {}).items():
+        seq_lens[s] = sl
     nxt, _logits, cache.k_pool, cache.v_pool = arch.decode_step(
         params, cache.k_pool, cache.v_pool,
         jnp.asarray(cache.page_tables), jnp.asarray(seq_lens),
@@ -87,12 +105,19 @@ def oracle_next(arch, params, ctx):
     logits = arch.forward(
         params, jnp.asarray(np.asarray(ctx, np.int32))[None]
     )
+    if isinstance(logits, tuple):  # the MoE forward: (logits, aux, dropped)
+        assert float(logits[2]) == 0.0  # nothing dropped: a sound oracle
+        logits = logits[0]
     return int(jnp.argmax(logits[0, -1].astype(jnp.float32)))
 
 
 @pytest.mark.parametrize("prompt_len", [1, 2, 5, 9])
-def test_incremental_greedy_matches_full_forward(prompt_len):
-    arch, params = tiny_lm()
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_incremental_greedy_matches_full_forward(kind, prompt_len):
+    """``prompt_len`` 1: nothing is prefilled and the first step's softmax
+    sees the step's own row only; 5 and 9: the first decoded row lands on
+    a page's first offset, and the 8 steps cross every offset of a page."""
+    arch, params = ARCHS[kind]()
     cache = make_cache(arch)
     rng = np.random.RandomState(prompt_len)
     prompt = [int(t) for t in rng.randint(0, arch.vocab, size=prompt_len)]
@@ -108,6 +133,70 @@ def test_incremental_greedy_matches_full_forward(prompt_len):
         ctx.append(tok)
     cache.release(0)
     assert cache.free_list.conserved()
+
+
+def noise_pools(cache, seed=0):
+    """Both pools filled with noise: an untouched byte is then told from a
+    rewritten one, and a read past the mask would show in the tokens."""
+    kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    cache.k_pool = jax.random.normal(kk, cache.k_pool.shape, cache.k_pool.dtype)
+    cache.v_pool = jax.random.normal(kv, cache.v_pool.shape, cache.v_pool.dtype)
+
+
+# (architecture, slot 0's prompt length, is slot 0 active?). The step writes
+# position prompt_len - 1: offset (prompt_len - 1) % PAGE of its page.
+WRITE_CASES = {
+    "page_first_offset": ("dense", 2 * PAGE + 1, True),
+    "page_last_offset": ("dense", 2 * PAGE, True),
+    "no_cached_row": ("dense", 1, True),
+    "inactive_slot": ("dense", PAGE + 2, False),
+    "moe_first_offset": ("moe", PAGE + 1, True),
+    "moe_last_offset": ("moe", PAGE, True),
+}
+
+
+@pytest.mark.parametrize("case", WRITE_CASES)
+def test_decode_step_writes_one_row_a_layer_and_nothing_else(case):
+    """One step over two slots: slot 1 always decodes, slot 0 by the case.
+    Every byte of both pools outside the active slots' (page, offset) rows
+    and the scratch page is bit-identical before and after; an inactive
+    slot's row goes to the scratch page though its table still names real
+    pages; the active slots' tokens are the full forward's, now and one
+    step later (the row just written is read back from the pool)."""
+    kind, prompt_len, slot0_active = WRITE_CASES[case]
+    arch, params = ARCHS[kind]()
+    cache = make_cache(arch)
+    noise_pools(cache)
+    rng = np.random.RandomState(prompt_len)
+    prompts = {0: [int(t) for t in rng.randint(0, arch.vocab, size=prompt_len)],
+               1: [int(t) for t in rng.randint(0, arch.vocab, size=3)]}
+    for s, pr in prompts.items():
+        cache.reserve(s, len(pr) + 4)
+        run_prefill(arch, params, cache, s, pr)
+    state = {s: (len(pr) - 1, pr[-1], 0.0) for s, pr in prompts.items()}
+    stale = {}
+    if not slot0_active:  # table and length still name a real page's row
+        stale = {0: state.pop(0)[0]}
+    ctx = {s: list(prompts[s]) for s in state}
+    for step in range(2):
+        before = np.asarray(cache.k_pool), np.asarray(cache.v_pool)
+        nxt = decode_once(arch, params, cache, state, stale)
+        touched = np.zeros(before[0].shape[:3], bool)
+        touched[:, cache.scratch] = True
+        for s, (sl, _lt, _tp) in state.items():
+            touched[:, cache.page_tables[s, sl // PAGE], sl % PAGE] = True
+        for was, pool in zip(before, (cache.k_pool, cache.v_pool)):
+            now = np.asarray(pool)
+            assert now.shape == was.shape and now.dtype == was.dtype
+            assert np.array_equal(now[~touched], was[~touched])
+            for s, (sl, _lt, _tp) in state.items():  # and the rows ARE new
+                row = (slice(None), cache.page_tables[s, sl // PAGE], sl % PAGE)
+                assert not np.array_equal(now[row], was[row])
+        for s in state:
+            want = oracle_next(arch, params, ctx[s])
+            assert int(nxt[s]) == want, f"step {step} slot {s}"
+            ctx[s].append(want)
+            state[s] = (state[s][0] + 1, want, 0.0)
 
 
 def test_prefill_pad_bucket_identity():

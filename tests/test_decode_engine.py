@@ -10,7 +10,7 @@ import jax
 import numpy as np
 import pytest
 
-from theanompi_tpu.models.lm import LMRecipe, TransformerLMModel
+from theanompi_tpu.models.lm import LMRecipe, MoELMModel, TransformerLMModel
 from theanompi_tpu.serve.decode import DecodeEngine, DecodeResult
 from theanompi_tpu.serve.engine import (
     DeadlineExceeded,
@@ -81,6 +81,55 @@ def test_submit_drain_lifecycle():
     # drained: new submissions are refused
     with pytest.raises(EngineDraining):
         eng.submit(prompt(1))
+
+
+def tiny_moe_model():
+    """The MoE LM serves through the dense LM's paged functions and cache
+    spec. A capacity no token exceeds: the full forward drops nothing."""
+    return MoELMModel(LMRecipe(
+        input_shape=(64,), num_classes=32, d_model=32, n_heads=2,
+        n_layers=2, d_ff=64, attn="ring", dataset="lm_synthetic",
+        n_experts=4, capacity_factor=4.0,
+    ))
+
+
+@pytest.mark.parametrize("prompt_len", [
+    1,  # nothing to prefill: the first step's softmax sees its own row only
+    5,  # 4 cached rows: the first decoded row lands on a page's FIRST offset
+    8,  # 7 cached rows: ... on a page's LAST offset
+])
+@pytest.mark.parametrize("make_model", [tiny_model, tiny_moe_model],
+                         ids=["dense", "moe"])
+def test_served_greedy_tokens_are_the_full_forwards(make_model, prompt_len):
+    """Prefill then decode through the engine's lane-dense pools
+    (``[L, kv_pages + 1, page_size, H * hd]``, not donated) against the
+    full-context forward, token by token, beside a second running slot."""
+    from test_decode_correctness import oracle_next
+
+    model = make_model()
+    eng = make_engine(model, max_new_tokens=6)
+    params, _ = set_tiny_params(eng)
+    spec = model.cache_spec(eng.page_size)
+    assert spec["kind"] == "kv" and spec["donate"] is False
+    assert spec["k_page"] == spec["v_page"] == (4, 32)
+    assert eng._cache.k_pool.shape == eng._cache.v_pool.shape == (2, 33, 4, 32)
+    assert eng.warmup() == len(eng.buckets) + 1
+    rng = np.random.RandomState(prompt_len)
+    toks = rng.randint(0, 32, size=prompt_len).astype(np.int32)
+    eng.start()
+    try:
+        other = eng.submit(prompt(9, 3, 6), max_new_tokens=6)
+        got = eng.submit(toks, max_new_tokens=6).result(60).tokens
+        other.result(60)
+    finally:
+        assert eng.drain(timeout=60)
+    assert eng.compile_count == len(eng.buckets) + 1
+    assert eng._cache.free_list.conserved()
+    ctx = [int(t) for t in toks]
+    for i, tok in enumerate(got):
+        want = oracle_next(model.arch, params, ctx)
+        assert tok == want, f"token {i}: served {tok}, full forward {want}"
+        ctx.append(tok)
 
 
 def test_compile_count_bounded_under_mixed_stream():

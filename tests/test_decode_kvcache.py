@@ -64,7 +64,7 @@ def test_freelist_alloc_zero():
 def test_cache_reserve_release_lifecycle():
     cache = PagedKVCache(
         n_layers=1, page_size=4, n_pages=8, max_seqs=2, max_pages_per_seq=4,
-        k_page=(4, 1, 4), v_page=(4, 1, 4),
+        k_page=(4, 4), v_page=(4, 4),
     )
     # worst-case reservation: 6 positions over page_size 4 -> 2 pages
     pages = cache.reserve(0, 6)
@@ -87,7 +87,7 @@ def test_cache_reserve_release_lifecycle():
 def test_cache_reserve_exhaustion_and_slot_bound():
     cache = PagedKVCache(
         n_layers=1, page_size=4, n_pages=4, max_seqs=2, max_pages_per_seq=4,
-        k_page=(4, 1, 4), v_page=(4, 1, 4),
+        k_page=(4, 4), v_page=(4, 4),
     )
     with pytest.raises(KVExhausted, match="at most"):
         cache.reserve(0, 17)  # 5 pages > max_pages_per_seq
@@ -101,7 +101,7 @@ def test_cache_reserve_exhaustion_and_slot_bound():
 def test_cache_release_all():
     cache = PagedKVCache(
         n_layers=1, page_size=2, n_pages=6, max_seqs=3, max_pages_per_seq=2,
-        k_page=(2, 1, 4), v_page=(2, 1, 4),
+        k_page=(2, 4), v_page=(2, 4),
     )
     cache.reserve(0, 3)
     cache.reserve(2, 4)
@@ -114,11 +114,11 @@ def test_cache_release_all():
 def test_cache_pool_shapes_fixed():
     cache = PagedKVCache(
         n_layers=3, page_size=4, n_pages=5, max_seqs=2, max_pages_per_seq=4,
-        k_page=(4, 2, 8), v_page=(4, 2, 8),
+        k_page=(4, 16), v_page=(4, 16),
     )
     # scratch page rides at index n_pages: pool holds n_pages + 1
-    assert cache.k_pool.shape == (3, 6, 4, 2, 8)
-    assert cache.v_pool.shape == (3, 6, 4, 2, 8)
+    assert cache.k_pool.shape == (3, 6, 4, 16)
+    assert cache.v_pool.shape == (3, 6, 4, 16)
     assert cache.scratch == 5
     assert cache.max_context == 16
     assert cache.page_tables.dtype == np.int32

@@ -329,7 +329,7 @@ def test_the_dense_lm_keeps_its_pools_its_programs_and_their_count():
     eng = _engine(model)
     eng.set_params(*model.init(jax.random.PRNGKey(0)), 0)
     k0 = eng._cache.k_pool
-    assert k0.shape == eng._cache.v_pool.shape == (2, 49, PAGE, 2, 16) and k0.dtype == jnp.float32
+    assert k0.shape == eng._cache.v_pool.shape == (2, 49, PAGE, 32) and k0.dtype == jnp.float32  # lane-dense rows: H * hd
     assert eng.cache_kind == "kv" and eng.warmup() == 3
     assert not k0.is_deleted() and eng._cache.k_pool is k0  # not donated, warmup's writes dropped
     assert eng.stats()["tmpi_decode_kv_bytes_per_position"] == 2 * 2 * 32 * 4

@@ -14,6 +14,7 @@ live and would take their CPU branch here, so each test steers the name
 its kernel module imported."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -328,3 +329,84 @@ def test_mistral_small_4_prefill_of_8192_positions_fits_beside_its_weights(
     mem = jax.jit(prefill, donate_argnums=(3, 4)).lower(
         params, tokens, pages, *pools).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= 2177 * 128 * 3840 and mem.temp_size_in_bytes < 1.5e9
+
+
+def _lm136m_decode(one_chip):
+    """The cell ``lm136m-decode-closed`` as shapes on the described chip: the
+    136M model, its engine's two fp32 pools of 1,536 pages + scratch as the
+    model's ``cache_spec`` shapes them, 24 slots of 64 pages."""
+    from theanompi_tpu.models.lm import TransformerLM_136M
+
+    model, page, pages, S, M = TransformerLM_136M(), 16, 1536, 24, 64
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    spec = model.cache_spec(page)
+    assert spec["kind"] == "kv" and not spec["donate"]
+    pools = [on_chip((model.arch.n_layers, pages + 1, *spec[k]), spec["dtype"])
+             for k in ("k_page", "v_page")]
+    assert all(p.shape == (12, 1537, 16, 768) and p.dtype == jnp.float32 for p in pools)
+    return model, page, params, pools, on_chip, (S, M)
+
+
+def _assert_no_relayout_of_a_pool(compiled, pools):
+    """What PR 34 took out must stay out: minor dimensions ``[12, 64]`` fill
+    no ``(8, 128)`` tile and cost 4 whole-pool copies, 48 layer-sized copies
+    and 7.27 GB of temporaries a decode step (4 and 1.85 GB a prefill),
+    donated or not. Left: the ONE copy a pool that an undonated argument
+    forces."""
+    pool, layer = tuple(pools[0].shape), tuple(pools[0].shape[1:])
+    whole, layers, other = 0, [], []
+    for shape, op in re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(", compiled.as_text()):
+        dims = tuple(int(d) for d in shape.split(","))
+        if dims in (layer, (1, *layer)):
+            layers.append((op, dims))
+        elif op == "copy" and dims == pool:
+            whole += 1
+        elif op == "copy" and math.prod(dims) >= math.prod(layer):
+            other.append(dims)
+    assert not layers, f"results shaped like a layer of a pool: {layers}"
+    assert not other, f"copies as large as a layer of a pool: {other}"
+    assert whole <= len(pools), f"{whole} whole-pool copies"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1e9, f"{mem.temp_size_in_bytes / 1e9:.2f} GB of temporaries"
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 6e9
+    assert compiled.as_text().count(" scatter(") == len(pools)  # ONE write a pool
+
+
+def test_lm136m_decode_step_copies_each_undonated_pool_once_and_no_layer_of_it(
+        one_chip, no_persistent_cache):
+    """24 slots x 64 pages over two 0.906 GB pools: 24 gathers by page out
+    of the pools as they stood, both attention products over whole
+    768-lane rows (a reshape of the gathered rows to ``[12, 64]`` re-lays
+    them into padded tiles: 0.42 GB of temporaries where 0.01 GB stand), one
+    scatter a pool after the last layer."""
+    model, page, params, pools, on_chip, (S, M) = _lm136m_decode(one_chip)
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step).lower(
+        params, *pools, on_chip((S, M), jnp.int32), on_chip((S,), jnp.int32),
+        on_chip((S,), jnp.int32), on_chip((S,), jnp.bool_), on_chip((S,), jnp.float32)).compile()
+    _assert_no_relayout_of_a_pool(compiled, pools)
+    assert "f32[24,1024,12,64]" not in compiled.as_text()  # a slot's gathered rows, by heads
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_lm136m_prefill_writes_each_pool_once(one_chip, no_persistent_cache):
+    """One prompt of a 512 bucket: the forward, then all layers' pages to
+    each pool in one write; 0.03 GB of temporaries."""
+    model, page, params, pools, on_chip, _ = _lm136m_decode(one_chip)
+    bucket = 512
+
+    def prefill(p, t, pg, k, v):
+        return model.decode_prefill(p, t, pg, k, v, page_size=page)
+
+    compiled = jax.jit(prefill).lower(
+        params, on_chip((bucket,), jnp.int32), on_chip((bucket // page,), jnp.int32), *pools).compile()
+    _assert_no_relayout_of_a_pool(compiled, pools)

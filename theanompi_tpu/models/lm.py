@@ -137,10 +137,16 @@ class TransformerLMModel(Model):
 
     def cache_spec(self, page_size: int) -> dict:
         """What ``DecodeEngine`` builds the paged pools from: a page of
-        either pool holds ``[page_size, n_heads, head_dim]`` fp32 K or V
-        rows; the programs do not take the pools donated."""
+        either pool holds ``[page_size, n_heads * head_dim]`` fp32 K or V
+        rows, so the pools are ``[L, n_pages + 1, page_size, H * hd]``. A
+        position's heads lie side by side in one row (768 lanes at the
+        136M widths) because a TPU array lives in ``(8, 128)`` tiles over
+        its two minor dimensions: ``[n_heads, head_dim] = [12, 64]`` fills
+        none and made the compiler re-lay whole pools
+        (``transformer.paged_decode_step``). The programs do not take the
+        pools donated."""
         a = self.arch
-        page = (page_size, a.n_heads, a.d_model // a.n_heads)
+        page = (page_size, a.d_model)  # n_heads * head_dim
         return {"kind": "kv", "k_page": page, "v_page": page, "dtype": jnp.float32,
                 "donate": False}
 

@@ -424,6 +424,16 @@ class TransformerLM(NamedTuple):
 #     writes the current position's K/V, samples the next token.
 # Both take an ``ffn(blk, hin) -> delta`` hook so the MoE LM
 # (models/moe.py) reuses the attention/cache plumbing unchanged.
+#
+# The pools are ``[L, n_pages + 1, page_size, H * hd]``: a position's
+# heads side by side in ONE row (768 lanes at 12 heads of 64). A TPU
+# array lives in (8, 128) tiles over its two minor dimensions; minor
+# ``[H, hd] = [12, 64]`` fills no tile, so the compiler kept a second,
+# padded copy of a pool, re-laid it on the way in and out of every
+# program and copied a layer of it around every write (52 pool-sized
+# copies a decode step, PERF.md section 6, PR 34). ``[page_size, H * hd]``
+# is whole tiles, and each program touches a pool twice and no more:
+# reads by page, ONE write after the last layer.
 
 
 def dense_ffn(blk, hin):
@@ -440,29 +450,31 @@ def paged_prefill(arch, params, tokens, pages, k_pool, v_pool,
     bucket length, a multiple of ``page_size``), ``pages
     [T_b/page_size] int32`` routes each page-worth of positions to its
     physical page (the scratch index for the padding tail), and the
-    pools are ``[L, n_pages+1, page_size, H, hd]``. Runs the full
-    causal forward minus the vocabulary head, so every position below
-    the true prompt length produces K/V bit-identical to the training
-    forward — causality means the padding tail cannot contaminate them,
-    and its garbage K/V land on read-masked offsets or the scratch
-    page. Returns ``(k_pool, v_pool)`` updated.
+    pools are ``[L, n_pages + 1, page_size, H * hd]`` (lane-dense rows:
+    the ``(8, 128)`` tile, see above). Runs the full causal forward
+    minus the vocabulary head, so every position below the true prompt
+    length produces K/V bit-identical to the training forward —
+    causality means the padding tail cannot contaminate them, and its
+    garbage K/V land on read-masked offsets or the scratch page. All
+    layers' pages go to each pool in ONE write. Returns ``(k_pool,
+    v_pool)`` updated.
     """
     T = tokens.shape[0]
     x = (params["tok_emb"][tokens] + params["pos_emb"][:T]).astype(arch.dtype)
     x = x[None]  # [1, T, d]
-    for li, blk in enumerate(params["blocks"]):
+    ks, vs = [], []
+    for blk in params["blocks"]:
         blk = cast_block_params(blk, arch.dtype)
         hin = _rms(x, blk["ln1"])
         qkv = jnp.einsum("btd,dchk->btchk", hin, blk["qkv"])
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [1, T, H, hd]
-        kp = k[0].reshape(-1, page_size, k.shape[2], k.shape[3])
-        vp = v[0].reshape(-1, page_size, v.shape[2], v.shape[3])
-        k_pool = k_pool.at[li, pages].set(kp.astype(k_pool.dtype))
-        v_pool = v_pool.at[li, pages].set(vp.astype(v_pool.dtype))
+        ks.append(k.reshape(T // page_size, page_size, -1))
+        vs.append(v.reshape(T // page_size, page_size, -1))
         att = full_attention_reference(q, k, v, causal=True)
         x = x + jnp.einsum("bthk,hkd->btd", att, blk["proj"])
         x = x + ffn(blk, _rms(x, blk["ln2"]))
-    return k_pool, v_pool
+    return (k_pool.at[:, pages].set(jnp.stack(ks).astype(k_pool.dtype)),
+            v_pool.at[:, pages].set(jnp.stack(vs).astype(v_pool.dtype)))
 
 
 def paged_decode_step(arch, params, k_pool, v_pool, page_tables, seq_lens,
@@ -470,59 +482,86 @@ def paged_decode_step(arch, params, k_pool, v_pool, page_tables, seq_lens,
                       page_size: int, ffn=dense_ffn):
     """One continuous-batching decode iteration over ALL batch slots.
 
-    Per slot ``s``: embed ``last_tokens[s]`` at position ``seq_lens[s]``,
-    write its K/V at (page ``page_tables[s, pos//page_size]``, offset
-    ``pos % page_size``) — inactive slots write to the scratch page —
-    then attend over cached positions ``0..seq_lens[s]`` inclusive
-    (gathered through the slot's page table, fp32 softmax, same
-    ``1/sqrt(hd)`` scale as :func:`full_attention_reference`), and
-    sample: greedy argmax where ``temperature[s] == 0``, else
-    categorical on ``logits/temperature`` under ``key``. All shapes are
-    static in ``(S, M)`` so ONE compiled program serves every iteration.
+    Per slot ``s``: embed ``last_tokens[s]`` at position ``seq_lens[s]``
+    and attend over positions ``0..seq_lens[s]`` inclusive: the cached
+    rows below ``seq_lens[s]``, gathered through the slot's page table
+    from the pool AS IT STOOD (viewed ``[L * (n_pages + 1), page_size,
+    H * hd]`` and indexed ``page_tables + li * (n_pages + 1)``: no layer
+    is cut out of it), and the step's own K/V row, an operand whose score
+    joins the cached ones under one fp32 softmax (same ``1/sqrt(hd)``
+    scale as :func:`full_attention_reference`). The new rows of ALL
+    layers go to (page ``page_tables[s, pos//page_size]``, offset
+    ``pos % page_size``) in ONE scatter a pool after the last layer —
+    inactive slots' to the scratch page. Sample: greedy argmax where
+    ``temperature[s] == 0``, else categorical on ``logits/temperature``
+    under ``key``. All shapes are static in ``(S, M)`` so ONE compiled
+    program serves every iteration.
+
+    The pools are ``[L, n_pages + 1, page_size, H * hd]`` (lane-dense
+    rows: the ``(8, 128)`` tile, see above) and the gathered rows are
+    never reshaped to ``[H, hd]``, which would re-lay them into padded
+    tiles layer by layer (7.6 ms of a 22.7 ms step on a v5e, PERF.md
+    section 6, PR 34): both attention products run over whole rows, head
+    ``h``'s query zero outside its own ``hd`` lanes and its output taken
+    from them.
 
     Returns ``(next_tokens [S] int32, logits [S, V] fp32, k_pool,
     v_pool)``.
     """
     S, M = page_tables.shape
-    scratch = k_pool.shape[1] - 1
+    L, n_phys = k_pool.shape[:2]  # n_pages + 1: the last is the scratch page
+    H, D = arch.n_heads, k_pool.shape[-1]
+    hd = D // H
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    sc = 1.0 / math.sqrt(hd)
     pos = jnp.clip(seq_lens, 0, params["pos_emb"].shape[0] - 1)
     x = (params["tok_emb"][last_tokens] + params["pos_emb"][pos]).astype(
         arch.dtype
     )
-    pidx = jnp.clip(seq_lens // page_size, 0, M - 1)
-    write_page = jnp.where(
-        active, page_tables[jnp.arange(S), pidx], scratch
-    )
-    write_off = seq_lens % page_size
+    k_pages = k_pool.reshape(L * n_phys, page_size, D)
+    v_pages = v_pool.reshape(L * n_phys, page_size, D)
+    cached = (jnp.arange(M * page_size)[None, :] < seq_lens[:, None])[:, None, :]
+    head_lanes = jnp.arange(D)[None, :] // hd == jnp.arange(H)[:, None]  # [H, D]
+    ks, vs = [], []
     for li, blk in enumerate(params["blocks"]):
         blk = cast_block_params(blk, arch.dtype)
         hin = _rms(x, blk["ln1"])
         qkv = jnp.einsum("sd,dchk->schk", hin, blk["qkv"])
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [S, H, hd]
-        k_pool = k_pool.at[li, write_page, write_off].set(
-            k.astype(k_pool.dtype)
+        # the row as the pool will hold it, so that this step and the next
+        # read the same K and V of this position
+        k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
+        ks.append(k.reshape(S, D))
+        vs.append(v.reshape(S, D))
+        k_ctx = k_pages[page_tables + li * n_phys].reshape(S, M * page_size, D)
+        v_ctx = v_pages[page_tables + li * n_phys].reshape(S, M * page_size, D)
+        # every cached row was a compute-dtype value when it was written, so
+        # the cast back is exact: products of such values, summed in fp32
+        q_heads = jnp.where(head_lanes, q.reshape(S, 1, D), 0)  # [S, H, D]
+        s_ = jnp.einsum("shD,stD->sht", q_heads, k_ctx.astype(q.dtype),
+                        precision=hi, preferred_element_type=f32) * sc
+        s_own = jnp.einsum("shd,shd->sh", q.astype(f32), k.astype(f32)) * sc
+        p = jax.nn.softmax(
+            jnp.concatenate(
+                [jnp.where(cached, s_, -1e30), s_own[..., None]], axis=-1
+            ),
+            axis=-1,
         )
-        v_pool = v_pool.at[li, write_page, write_off].set(
-            v.astype(v_pool.dtype)
-        )
-        k_ctx = k_pool[li][page_tables].reshape(
-            S, M * page_size, k.shape[1], k.shape[2]
-        )
-        v_ctx = v_pool[li][page_tables].reshape(
-            S, M * page_size, v.shape[1], v.shape[2]
-        )
-        sc = 1.0 / math.sqrt(q.shape[-1])
-        s_ = jnp.einsum(
-            "shd,sthd->sht", q.astype(jnp.float32), k_ctx.astype(jnp.float32)
-        ) * sc
-        valid = jnp.arange(M * page_size)[None, :] <= seq_lens[:, None]
-        s_ = jnp.where(valid[:, None, :], s_, -1e30)
-        p = jax.nn.softmax(s_, axis=-1)
-        att = jnp.einsum(
-            "sht,sthd->shd", p, v_ctx.astype(jnp.float32)
-        ).astype(x.dtype)
+        o = jnp.einsum("sht,stD->shD", p[..., :-1], v_ctx.astype(f32),
+                       precision=hi)  # head h's output lies in head h's lanes
+        att = jnp.where(head_lanes, o, 0).sum(axis=1).reshape(S, H, hd)
+        att = (att + p[..., -1:] * v.astype(f32)).astype(x.dtype)
         x = x + jnp.einsum("shk,hkd->sd", att, blk["proj"])
         x = x + ffn(blk, _rms(x, blk["ln2"]))
+    write_page = jnp.where(
+        active,
+        page_tables[jnp.arange(S), jnp.clip(seq_lens // page_size, 0, M - 1)],
+        n_phys - 1,
+    )
+    rows = write_page + jnp.arange(L)[:, None] * n_phys  # [L, S] pages of the view
+    write_off = seq_lens % page_size
+    k_pool = k_pages.at[rows, write_off].set(jnp.stack(ks)).reshape(k_pool.shape)
+    v_pool = v_pages.at[rows, write_off].set(jnp.stack(vs)).reshape(v_pool.shape)
     logits = (x @ params["head"].astype(arch.dtype)).astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     safe_t = jnp.maximum(temperature, 1e-6)[:, None]

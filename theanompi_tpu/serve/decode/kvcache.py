@@ -5,8 +5,9 @@ ONE preallocated device pool per pool-kind (K and V), shaped
     [n_layers, n_pages + 1, *page]
 
 where ``page`` is what the MODEL says one page of that pool holds
-(``Model.cache_spec``): ``[page_size, n_heads, head_dim]`` twice for
-per-head keys and values, ``[page_size, kv_lora]`` and ``[qk_rope,
+(``Model.cache_spec``): ``[page_size, n_heads * head_dim]`` twice for
+per-head keys and values (a position's heads side by side in one
+lane-dense row), ``[page_size, kv_lora]`` and ``[qk_rope,
 page_size]`` for a latent cache (one normed latent row and one rotated key
 row a position, shared by all heads). The free-list, the page tables, the
 scratch page and the conservation counters know nothing of the kind, so
@@ -138,7 +139,7 @@ class PagedKVCache:
         dtype=None,
     ):
         """``k_page`` / ``v_page``: the shape of one page of each pool
-        (``[page_size, n_heads, head_dim]`` twice for per-head K and V)."""
+        (``[page_size, n_heads * head_dim]`` twice for per-head K and V)."""
         import jax.numpy as jnp  # deferred: FreeList stays importable sans jax
 
         if page_size <= 0:
