@@ -9,7 +9,9 @@ the very pools it passed in. ``mistral-small-4-decode``'s programs have given
 those away, so that fault stops the engine and never reaches the comparison
 (``checks/readings_decode.py`` records ``stopped``). Here the same fault is
 planted so that it survives donation: a copy of each pool is taken before the
-call and handed back after it.
+call and handed back after it. ``--workload minicpm-sala-decode-doc16k`` (PR
+35) runs the same faults over that cell's three kinds of state: pages,
+compressed keys and recurrent state are all "the cache" here.
 
 - ``none``: the program as it is (the rows' distribution to hold the faults'
   against, from the same process).
@@ -49,7 +51,8 @@ def plant(probe, fault):
     import jax.numpy as jnp
 
     decode, prefill = probe._orig["_decode"], probe._orig["_prefill"]
-    copies = jax.jit(lambda k, v: (jnp.copy(k), jnp.copy(v)))
+    # the second pool may be a pytree (V pages beside arrays held a slot: PR 35)
+    copies = jax.jit(lambda k, v: jax.tree_util.tree_map(jnp.copy, (k, v)))
     cache = probe.engine._cache
     jax.block_until_ready(copies(cache.k_pool, cache.v_pool))  # compiled before the window
 
@@ -58,9 +61,9 @@ def plant(probe, fault):
         nxt, logits, _, _ = decode(params, k_pool, v_pool, *rest)
         return (nxt, logits, *kept)
 
-    def prefill_unchanged(params, tokens, pages, k_pool, v_pool):
+    def prefill_unchanged(params, tokens, pages, k_pool, v_pool, *where):
         kept = copies(k_pool, v_pool)
-        prefill(params, tokens, pages, k_pool, v_pool)
+        prefill(params, tokens, pages, k_pool, v_pool, *where)
         return kept
 
     if fault == "state_unchanged":

@@ -331,7 +331,8 @@ def test_every_pallas_call_in_ops_passes_a_name():
                 # a module-level constant beside its kernel
                 assert isinstance(name[0], ast.Name) and name[0].id in constants, f"{path}:{node.lineno}"
                 names.add(constants[name[0].id])
-    assert calls == 15 and len(names) == 15
+    assert calls == 19 and len(names) == 19
     assert {"moe_gmm", "moe_tgmm", "mla_decode", "mla_cache_write"} <= names
+    assert {"sparse_decode", "sparse_prefill", "sparse_cache_write", "lightning_step"} <= names  # PR 35
     # the roofline readers find the flash kernels by these two stems
     assert {n for n in names if "flash" in n} == {"flash_fwd", "flash_bwd", "flash_bwd_2d"}

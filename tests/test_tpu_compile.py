@@ -410,3 +410,86 @@ def test_lm136m_prefill_writes_each_pool_once(one_chip, no_persistent_cache):
     compiled = jax.jit(prefill).lower(
         params, on_chip((bucket,), jnp.int32), on_chip((bucket // page,), jnp.int32), *pools).compile()
     _assert_no_relayout_of_a_pool(compiled, pools)
+
+
+def _minicpm_sala_decode(one_chip, monkeypatch):
+    """The cell ``minicpm-sala-decode-doc16k`` as shapes on the described chip:
+    the stage of 8 layers at the published widths and its engine's three kinds
+    of state as ``PagedKVCache`` shapes them (16 slots of 265 pages of 64)."""
+    from theanompi_tpu.models.minicpm_sala import MiniCPM_SALA_Stage8
+    from theanompi_tpu.ops import pallas_lightning as plg
+    from theanompi_tpu.ops import pallas_sparse as ps
+
+    for module in (plg, ps):
+        _mosaic(monkeypatch, module)
+    model, page, pages, S, M = MiniCPM_SALA_Stage8(), 64, 4240, 16, 265
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    spec = model.cache_spec(page)
+    k_pool = on_chip((spec["paged_layers"], pages + 1, *spec["k_page"]), spec["dtype"])
+    held = {"v": on_chip(k_pool.shape, spec["dtype"])}
+    for name, a in spec["slots"].items():
+        rows = () if a.get("positions_per_row") is None else (-(-M * page // a["positions_per_row"]),)
+        held[name] = on_chip((a["layers"], S, *rows, *a["row"]), a["dtype"])
+    assert k_pool.shape == (2, 4241, 64, 256) and held["compressed"].shape == (2, 16, 1060, 256)
+    assert held["state"].shape == (6, 16, 32, 128, 128) and held["state"].dtype == jnp.float32
+    return model, page, params, k_pool, held, on_chip, (S, M)
+
+
+def _held_bytes(k_pool, held):
+    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize for a in (k_pool, *held.values()))
+
+
+def test_minicpm_sala_decode_step_walks_chosen_pages_and_updates_every_pool_in_place(
+        one_chip, no_persistent_cache, monkeypatch):
+    """16 slots over 0.56 GB of K and V pages (2 sparse layers), 17 MB of
+    compressed keys and 0.2 GB of float32 state (6 lightning layers): two
+    ``sparse_decode`` calls over at most 98 chosen pages a (slot, K/V head),
+    six ``lightning_step`` calls, one ``sparse_cache_write``; everything
+    donated aliases its output, NO pool is copied and no slot's whole context
+    is gathered (``[16, 16960, 256]`` would be 139 MB a pool a layer)."""
+    model, page, params, k_pool, held, on_chip, (S, M) = _minicpm_sala_decode(one_chip, monkeypatch)
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_pool, held, on_chip((S, M), jnp.int32), on_chip((S,), jnp.int32), on_chip((S,), jnp.int32),
+        on_chip((S,), jnp.bool_), on_chip((S,), jnp.float32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 2 + 6 + 1
+    assert all(name in text for name in ("sparse_decode", "lightning_step", "sparse_cache_write"))
+    assert _held_bytes(k_pool, held) == 774_569_984 and mem.alias_size_in_bytes >= 774_569_984
+    assert mem.temp_size_in_bytes < 0.1e9  # 23 MB; a copy of the K pages would be 0.28 GB, of the state 0.2
+    assert not re.search(rf"\[{S},{M * page},\d+\]", text) and not re.search(rf"\[{S},{M},{page},\d+\]", text)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_minicpm_sala_prefill_of_16384_positions_holds_no_square_of_the_prompt(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One bucket of 16,384 rows for its slot at a traced real length: two
+    ``sparse_prefill`` flash passes under a per-row block mask, six chunked
+    scans, whole pages and the slot's rows written to the donated pools;
+    1.7 GB of temporaries beside 5.64 GB of weights. Nothing is shaped
+    ``[.., T, T]``: shown on a bucket of 8,192, because at 16,384 the
+    SwiGLU's ``[T, 16384]`` is a square by accident."""
+    model, page, params, k_pool, held, on_chip, _ = _minicpm_sala_decode(one_chip, monkeypatch)
+
+    def prefill(p, t, pg, k, v, slot, n_real):
+        return model.decode_prefill(p, t, pg, k, v, slot, n_real, page_size=page)
+
+    def compiled_at(T):
+        return jax.jit(prefill, donate_argnums=(3, 4)).lower(
+            params, on_chip((T,), jnp.int32), on_chip((T // page,), jnp.int32), k_pool, held,
+            on_chip((), jnp.int32), on_chip((), jnp.int32)).compile()
+
+    compiled = compiled_at(16384)
+    mem = compiled.memory_analysis()
+    assert _kernels(compiled) == 2 and "sparse_prefill" in compiled.as_text()
+    assert mem.alias_size_in_bytes >= 774_569_984 and mem.temp_size_in_bytes < 2.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+    assert "8192,8192" not in compiled_at(8192).as_text()

@@ -31,6 +31,8 @@ MODEL_REGISTRY = {
     "trinity_mini_ep8": ("theanompi_tpu.models.afmoe", "TrinityMini_EP8"),
     "mistral4_lm": ("theanompi_tpu.models.mistral4", "Mistral4LM"),
     "mistral_small_4_ep8": ("theanompi_tpu.models.mistral4", "MistralSmall4_EP8"),
+    "minicpm_sala_lm": ("theanompi_tpu.models.minicpm_sala", "MiniCPMSALA"),
+    "minicpm_sala_stage8": ("theanompi_tpu.models.minicpm_sala", "MiniCPM_SALA_Stage8"),
 }
 
 

@@ -190,8 +190,12 @@ class DecodeEngine:
         spec = model.cache_spec(self.page_size)
         self.cache_kind = str(spec["kind"])
         self._donate = bool(spec["donate"])
+        # a model that holds state a SLOT (beside or instead of pages in some
+        # layers) also tells its prefill which slot it fills and how many of
+        # the bucket's positions are real
+        self._slot_state = bool(spec.get("slots"))
         self._cache = PagedKVCache(
-            n_layers=arch.n_layers,
+            n_layers=spec.get("paged_layers", arch.n_layers),
             page_size=self.page_size,
             n_pages=int(kv_pages),
             max_seqs=int(max_seqs),
@@ -199,6 +203,8 @@ class DecodeEngine:
             k_page=spec["k_page"],
             v_page=spec["v_page"],
             dtype=spec["dtype"],
+            kind=self.cache_kind,
+            slots=spec.get("slots"),
         )
         self._sched = DecodeScheduler(
             self._cache, prefill_buckets=buckets, mode=mode
@@ -216,10 +222,12 @@ class DecodeEngine:
         self._trace_count = 0
         seed_const = self._seed
 
-        def _counted_prefill(params, tokens, pages, k_pool, v_pool):
+        def _counted_prefill(params, tokens, pages, k_pool, v_pool, *where):
             self._trace_count += 1  # trace-time only, never per call
+            # ``where``: (slot, real length) as traced scalars for a model
+            # with state a slot, else nothing: one program a bucket
             return model.decode_prefill(
-                params, tokens, pages, k_pool, v_pool,
+                params, tokens, pages, k_pool, v_pool, *where,
                 page_size=self.page_size,
             )
 
@@ -296,14 +304,28 @@ class DecodeEngine:
         self._g_step = self.registry.gauge(
             "tmpi_decode_params_step", help="checkpoint step currently served"
         )
-        self.registry.gauge(
+        g_pool = self.registry.gauge(
             "tmpi_decode_kv_pool_bytes",
-            help="bytes of the two cache pools (kind=kv|latent)",
-        ).set(float(self._cache.pool_bytes), kind=self.cache_kind)
-        self.registry.gauge(
+            help="bytes the cache holds, by kind: the two paged pools "
+                 "(kind=kv|latent), arrays held a slot (kind=compressed|state)",
+        )
+        for kind, n in self._cache.pool_bytes_by_kind.items():
+            g_pool.set(float(n), kind=kind)
+        g_position = self.registry.gauge(
             "tmpi_decode_kv_bytes_per_position",
-            help="cache bytes one position takes over all layers",
-        ).set(float(self._cache.bytes_per_position), kind=self.cache_kind)
+            help="cache bytes one more position takes over all layers, by kind",
+        )
+        for kind, n in self._cache.bytes_per_position_by_kind.items():
+            g_position.set(float(n), kind=kind)
+        # a model whose attention sees a chosen part of the context says
+        # which share (host side, from the running lengths)
+        self._visible_share = getattr(model, "visible_share", None)
+        if self._visible_share is not None:
+            self._g_visible = self.registry.gauge(
+                "tmpi_decode_visible_context_share",
+                help="share of their context the sparse layers' queries of "
+                     "the last iteration saw",
+            )
         self._c_requests = self.registry.counter(
             "tmpi_decode_requests_total",
             help="generations by outcome "
@@ -426,7 +448,8 @@ class DecodeEngine:
         for b in self.buckets:
             toks = jnp.zeros((b,), jnp.int32)
             pages = jnp.full((b // self.page_size,), c.scratch, jnp.int32)
-            out = self._prefill(served.params, toks, pages, c.k_pool, c.v_pool)
+            where = (np.int32(0), np.int32(0)) if self._slot_state else ()
+            out = self._prefill(served.params, toks, pages, c.k_pool, c.v_pool, *where)
             jax.block_until_ready(out)  # compile now, discard scratch writes
             if self._donate:  # the pools went into the call: these are they
                 c.k_pool, c.v_pool = out
@@ -663,14 +686,18 @@ class DecodeEngine:
             if pf is None:
                 continue  # 1-token prompt: the decode step handles it
             bucket, toks, pages = pf
+            where = ((np.int32(seq.slot), np.int32(seq.n_cache))
+                     if self._slot_state else ())
             c.k_pool, c.v_pool = self._prefill(
                 served.params, jnp.asarray(toks), jnp.asarray(pages),
-                c.k_pool, c.v_pool,
+                c.k_pool, c.v_pool, *where,
             )
             self._c_prefills.inc(bucket=bucket)
         if not self._sched.running:
             return
         tables, seq_lens, last, active, temp = self._sched.step_arrays()
+        if self._visible_share is not None:
+            self._g_visible.set(self._visible_share(seq_lens[active]))
         nxt, _logits, c.k_pool, c.v_pool = self._decode(
             served.params, c.k_pool, c.v_pool,
             jnp.asarray(tables), jnp.asarray(seq_lens), jnp.asarray(last),
@@ -738,7 +765,7 @@ class DecodeEngine:
         engine can keep serving if the error was input-local — with
         new pools where the failed program had taken them donated."""
         c = self._cache
-        if self._donate and (c.k_pool.is_deleted() or c.v_pool.is_deleted()):
+        if self._donate and c.pools_deleted():
             # the program that raised had taken the pools donated: they
             # are gone. Every sequence that had rows in them is failed
             # below, so fresh zeros are a sound state to serve on
@@ -799,6 +826,11 @@ class DecodeEngine:
             "tmpi_decode_reload_failures_total":
                 self._c_reloads.value(status="failed"),
         }
+        for kind, n in self._cache.pool_bytes_by_kind.items():
+            if kind != self.cache_kind:  # held a slot, beside the pages
+                out[f"tmpi_decode_{kind}_bytes"] = float(n)
+        if self._visible_share is not None:
+            out["tmpi_decode_visible_context_share"] = self._g_visible.value()
         tps = self.tokens_per_sec()
         if tps is not None:
             out["tmpi_decode_tokens_per_sec"] = tps

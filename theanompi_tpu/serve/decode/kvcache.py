@@ -14,7 +14,14 @@ scratch page and the conservation counters know nothing of the kind, so
 the compiled decode/prefill programs see a FIXED shape forever: pages
 are handed out and returned by a host-side free-list, and the programs
 receive gather/scatter *indices* (per-sequence page tables) instead of
-resized buffers. Index ``n_pages`` is the SCRATCH page — never owned by
+resized buffers. A model whose layers do not all keep pages says over how
+many layers the paged pools run, and which arrays it holds a SLOT and not a
+page (``slots``: a recurrent state, a row every few positions): those are
+``[layers, max_seqs, ...]``, allocated, reset, placed and counted here
+beside the pages (``pool_bytes_by_kind``), and ride in the second pool,
+then a pytree ``{"v": the V pages, name: array}``; a slot's arrays belong
+to whoever holds the slot and the programs overwrite them as a sequence
+starts. Index ``n_pages`` is the SCRATCH page — never owned by
 any sequence; inactive batch slots and the padding tail of a prefill
 scatter are routed there, so every write in the jitted step is
 unconditional (no dynamic shapes, no host-side branching) and the
@@ -33,7 +40,7 @@ can assert pages_out == pages_in after drain.
 
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -137,9 +144,16 @@ class PagedKVCache:
         k_page: Sequence[int],
         v_page: Sequence[int],
         dtype=None,
+        kind: str = "kv",
+        slots: Optional[dict] = None,
     ):
         """``k_page`` / ``v_page``: the shape of one page of each pool
-        (``[page_size, n_heads * head_dim]`` twice for per-head K and V)."""
+        (``[page_size, n_heads * head_dim]`` twice for per-head K and V),
+        over ``n_layers`` layers: those that keep pages. ``kind`` names what
+        the pages hold. ``slots``: ``{name: {"layers", "row", "dtype"[,
+        "positions_per_row"]}}``, arrays held a slot: ``[layers, max_seqs,
+        *row]``, or with ``positions_per_row`` one row every so many positions
+        of the longest context, ``[layers, max_seqs, rows, *row]``."""
         import jax.numpy as jnp  # deferred: FreeList stays importable sans jax
 
         if page_size <= 0:
@@ -157,6 +171,14 @@ class PagedKVCache:
         lead = (int(n_layers), self.n_pages + 1)
         self._shapes = (lead + tuple(k_page), lead + tuple(v_page))
         self._dtype = jnp.dtype(dtype if dtype is not None else jnp.float32)
+        self.kind = str(kind)
+        # name -> (shape, dtype, positions a row or None)
+        self._slots = {}
+        for name, a in (slots or {}).items():
+            per = a.get("positions_per_row")
+            rows = () if per is None else (pages_needed(self.max_context, per),)
+            self._slots[name] = ((int(a["layers"]), self.max_seqs, *rows, *a["row"]),
+                                 jnp.dtype(a["dtype"]), per)
         self.reset_pools()
         self.free_list = FreeList(self.n_pages)
         self.page_tables = np.full(
@@ -172,17 +194,47 @@ class PagedKVCache:
         import jax.numpy as jnp
 
         self.k_pool, self.v_pool = (jnp.zeros(s, self._dtype) for s in self._shapes)
+        if self._slots:
+            self.v_pool = {"v": self.v_pool, **{
+                name: jnp.zeros(shape, dtype) for name, (shape, dtype, _) in self._slots.items()}}
+
+    def pools_deleted(self) -> bool:
+        """Whether a program that took the pools donated has left any of
+        their arrays deleted."""
+        import jax
+
+        return any(a.is_deleted() for a in jax.tree_util.tree_leaves((self.k_pool, self.v_pool)))
+
+    @property
+    def pool_bytes_by_kind(self) -> dict:
+        """``{kind: bytes}``: the two paged pools under the kind the model
+        gave them, every array held a slot under its name."""
+        out = {self.kind: sum(int(np.prod(s)) for s in self._shapes) * self._dtype.itemsize}
+        for name, (shape, dtype, _) in self._slots.items():
+            out[name] = int(np.prod(shape)) * dtype.itemsize
+        return out
 
     @property
     def pool_bytes(self) -> int:
-        """Bytes of the two pools together."""
-        return sum(int(np.prod(s)) for s in self._shapes) * self._dtype.itemsize
+        """Bytes of everything held here together."""
+        return sum(self.pool_bytes_by_kind.values())
+
+    @property
+    def bytes_per_position_by_kind(self) -> dict:
+        """``{kind: bytes}`` one more position takes over all layers: a page
+        of each pool holds ``page_size`` positions, whatever its kind; an
+        array held a slot counts where it grows with the context (a row
+        every few positions), a recurrent state does not."""
+        by_kind = self.pool_bytes_by_kind
+        out = {self.kind: by_kind[self.kind] // ((self.n_pages + 1) * self.page_size)}
+        for name, (shape, _, per) in self._slots.items():
+            if per is not None:
+                out[name] = by_kind[name] // (self.max_seqs * shape[2] * per)
+        return out
 
     @property
     def bytes_per_position(self) -> int:
-        """Cache bytes one position takes over all layers: a page of each
-        pool holds ``page_size`` positions, whatever its kind."""
-        return self.pool_bytes // ((self.n_pages + 1) * self.page_size)
+        return sum(self.bytes_per_position_by_kind.values())
 
     @property
     def max_context(self) -> int:
