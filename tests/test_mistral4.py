@@ -144,18 +144,38 @@ def test_absorbed_equals_expanded_on_one_layer():
             assert jnp.allclose(c_new[0], c_kv[0, n], atol=1e-6) and jnp.allclose(r_new[0], k_pe[0, n], atol=1e-6)
 
 
-@pytest.mark.parametrize("lens", [[0, 1, 8, 37, 88], [88, 0, 0, 16, 3]])
-def test_mla_decode_in_interpret_mode_equals_its_jnp_twin(lens):
-    S, H, R, Dr, L, P, M = len(lens), 4, 32, 16, 2, 40, 12
+_TINY, _CELL = (4, 32, 16, PAGE, 12), (32, 256, 64, 128, 11)  # H, R, Dr, page, M
+
+
+@pytest.mark.parametrize("lens,dims,most,pps", [  # at most `most` pages a step -> `pps` pages a step
+    ([0, 1, 8, 37, 88], _TINY, 6, 6),
+    ([88, 0, 0, 16, 3], _TINY, 6, 6),
+    ([7, 8, 9, 0, 15, 16, 17], _TINY, 6, 6),  # at, under and over a multiple of the page
+    ([47, 48, 49, 0, 95, 40, 41], _TINY, 6, 6),  # and of a step's 6 pages; up to 48 the last step is dead
+    ([0, 0, 0], _TINY, 6, 6),
+    ([37, 0, 88, 95], _TINY, 5, 4),  # a table of 12 in 3 steps of 4
+    ([37, 0, 88, 95], _TINY, None, 12),  # the module's own step: the whole table
+    ([0, 127, 128, 129, 511, 512, 513, 1300], _CELL, 4, 4),  # the served cell's widths, 11 pages in 3 steps of 4
+], ids=["mixed", "zeros-between", "page-edges", "step-edges", "all-empty", "ragged-steps", "one-step",
+        "cell-widths"])
+def test_mla_decode_in_interpret_mode_equals_its_jnp_twin(lens, dims, most, pps, monkeypatch):
+    from theanompi_tpu.ops import pallas_mla as pm
+
+    H, R, Dr, page, M = dims
+    if most:
+        monkeypatch.setattr(pm, "_STEP_BYTES", most * page * (R + Dr) * 2)
+    assert pm._pages_a_step(M, page * (R + Dr) * 2) == pps
+    S, L = len(lens), 2
+    P = sum(pages_needed(n + 1, page) for n in lens)
     k = jax.random.split(jax.random.PRNGKey(0), 6)
     dt = jnp.bfloat16
     ql, qr = jax.random.normal(k[0], (S, H, R)).astype(dt), jax.random.normal(k[1], (S, H, Dr)).astype(dt)
     cn, rn = jax.random.normal(k[2], (S, R)).astype(dt), jax.random.normal(k[3], (S, Dr)).astype(dt)
-    cp = jax.random.normal(k[4], (L, P + 1, PAGE, R)).astype(dt)
-    rp = jax.random.normal(k[5], (L, P + 1, Dr, PAGE)).astype(dt)
+    cp = jax.random.normal(k[4], (L, P + 1, page, R)).astype(dt)
+    rp = jax.random.normal(k[5], (L, P + 1, Dr, page)).astype(dt)
     tables, perm, at = np.full((S, M), P, np.int32), np.random.default_rng(0).permutation(P), 0
     for s, n in enumerate(lens):
-        npg = pages_needed(n + 1, PAGE)  # the cached positions and the step's own
+        npg = pages_needed(n + 1, page)  # the cached positions and the step's own
         tables[s, :npg] = perm[at:at + npg]
         at += npg
     lens_a = jnp.asarray(lens, jnp.int32)
@@ -167,11 +187,11 @@ def test_mla_decode_in_interpret_mode_equals_its_jnp_twin(lens):
     # a slot of no cached position attends to its own row alone
     assert jnp.array_equal(a[lens.index(0)], jnp.broadcast_to(cn[lens.index(0)].astype(jnp.float32), (H, R)))
     # the write of every layer's own rows: bit for bit the scatter it replaces, nothing else touched
-    wpage = jnp.asarray([tables[s, n // PAGE] for s, n in enumerate(lens)], jnp.int32)
+    wpage = jnp.asarray([tables[s, n // page] for s, n in enumerate(lens)], jnp.int32)
     c_rows, r_rows = jnp.stack([cn, -cn]), jnp.stack([rn, -rn])
     c_out, r_out = mla_cache_write(cp, rp, c_rows, r_rows, wpage, lens_a)
-    assert jnp.array_equal(c_out, cp.at[:, wpage, lens_a % PAGE].set(c_rows))
-    assert jnp.array_equal(r_out, rp.at[:, wpage, :, lens_a % PAGE].set(jnp.swapaxes(r_rows, 0, 1)))
+    assert jnp.array_equal(c_out, cp.at[:, wpage, lens_a % page].set(c_rows))
+    assert jnp.array_equal(r_out, rp.at[:, wpage, :, lens_a % page].set(jnp.swapaxes(r_rows, 0, 1)))
 
 
 def test_yarn_frequencies_and_the_softmax_scale_are_the_published_numbers():

@@ -19,11 +19,22 @@ positions are the minor dimension, so nothing is padded to the 128 lanes;
 with ``[page, Dr]`` pages the TPU's compiler keeps the pool in this order
 anyway and copies it whole to the padded one before every kernel call)
 
-walking the slot's page table (scalar prefetch) and reading ONLY the pages
-its real length covers: past the last live page the block index repeats
-(no copy) and the body does nothing, so a slot of no cached position (an
-inactive one) costs its grid steps alone. The step's own position is not
-in the pools yet: its rows ``c_new`` / ``r_new`` come as operands and join
+reading ONLY the pages its real length covers. The grid is the slots. The
+pools stay in HBM (``memory_space=pl.ANY``: one operand each, no block, no
+copy of a pool) and the kernel brings a slot's pages itself: the slot's
+table is cut into the fewest equal steps of at most ``_STEP_BYTES`` of rows
+(the served cell's 69-page table: 3 steps of 23 pages), and a step's live
+pages are copied, a page each of ``c`` and ``r``, into one half of a
+two-halved VMEM buffer while the step before is computed from the other
+half; a slot's last step starts the first step of the next slot, so the
+copies do not stop at a slot's end. A step is ONE softmax pass over all its
+positions: the scores ``q_lat c^T + q_rope r`` as one ``[H, pages * page]``
+tile (two products), one mask (a page past the length is not copied: its
+place holds zeros or an earlier step's rows and is masked, not branched
+on), one max / exp / sum, one rescale of the accumulator and one second
+product; ``m``, ``l`` and the accumulator are the loop's values. A slot of
+no cached position (an inactive one) runs no step. The step's own position
+is not in the pools yet: its rows ``c_new`` / ``r_new`` come as operands and join
 the softmax last. ``mla_cache_write`` then puts the new rows of ALL layers
 into the pools in one call after the last layer: each slot's current page
 (``write_page[s]``, offset ``lens[s] % page``; the scratch page for an
@@ -49,63 +60,101 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from theanompi_tpu.ops.pallas_util import interpret_mode as _interpret
 
 DECODE_NAME = "mla_decode"  # the kernels' names in a device trace
 WRITE_NAME = "mla_cache_write"
-_PAGES_A_STEP = 8  # pages a grid step reads (one block each): fewer, longer steps
+_STEP_BYTES = 2 << 20  # of cached rows a step brings at most (VMEM holds two such halves)
 _NEG = -1e30
 
 
-def _kernel(scale, page, pps, tables_ref, lens_ref, qlat_ref, qrope_ref, cnew_ref,
-            rnew_ref, *rest):
-    del tables_ref  # read by the index maps
-    c_refs, r_refs = rest[:pps], rest[pps:2 * pps]
-    o_ref, m_ref, l_ref, acc_ref = rest[2 * pps:]
-    j = pl.program_id(1)
-    n = lens_ref[pl.program_id(0)]
+def _kernel(scale, page, pps, layer, tables_ref, lens_ref, qlat_ref, qrope_ref, cnew_ref, rnew_ref,
+            cpool_ref, rpool_ref, o_ref, cbuf_ref, rbuf_ref, sem_ref, par_ref):
+    s, S = pl.program_id(0), pl.num_programs(0)
+    span = pps * page
+    n = lens_ref[s]
+    steps = (n + span - 1) // span
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def pages(slot, step, buf, act):
+        """``act`` on the copies, ``c`` and ``r``, of every live page of
+        ``slot``'s step into half ``buf`` (a copy is waited for as it was started)."""
+        for i in range(pps):
+            k = step * pps + i
+            pg = tables_ref[slot, jnp.minimum(k, tables_ref.shape[1] - 1)]
 
+            @pl.when(k * page < lens_ref[slot])
+            def _(i=i, pg=pg):
+                act(pltpu.make_async_copy(cpool_ref.at[layer, pg],
+                                          cbuf_ref.at[buf, pl.ds(i * page, page)], sem_ref.at[buf, 0]))
+                act(pltpu.make_async_copy(rpool_ref.at[layer, pg],
+                                          rbuf_ref.at[buf, :, pl.ds(i * page, page)], sem_ref.at[buf, 1]))
+
+    def start(slot, step, buf):
+        pages(slot, step, buf, lambda copy: copy.start())
+
+    def wait(slot, step, buf):
+        pages(slot, step, buf, lambda copy: copy.wait())
+
+    @pl.when(s == 0)
+    def _first():
+        cbuf_ref[...] = jnp.zeros_like(cbuf_ref)  # a dead page's rows are masked, so must be finite:
+        rbuf_ref[...] = jnp.zeros_like(rbuf_ref)  # zeros now, an earlier step's rows later
+        par_ref[0] = 0  # the half that holds the slot's first step
+
+    par = par_ref[0]
+
+    @pl.when(jnp.logical_or(s == 0, lens_ref[jnp.maximum(s - 1, 0)] == 0))
+    def _own_first_step():  # else the slot before started it from its last step
+        start(s, 0, par)
+
+    nxt = jnp.minimum(s + 1, S - 1)
+    has_next = s + 1 < S
     qlat, qrope = qlat_ref[...], qrope_ref[...]  # [H, R], [H, Dr]
-    for i in range(pps):
-        start = (j * pps + i) * page
+    H, R = qlat.shape
 
-        @pl.when(start < n)
-        def _page(i=i, start=start):
-            c, r = c_refs[i][...], r_refs[i][...]  # [page, R], [Dr, page]
-            s = (lax.dot_general(qlat, c, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-                 + lax.dot_general(qrope, r, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)) * scale
-            seen = start + lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
-            s = jnp.where(seen, s, _NEG)
-            m = m_ref[...]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-            corr = jnp.exp(m - m_new)
-            l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-                p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+    def step(t, carry):
+        m, l, acc = carry
+        buf = (par + t) % 2
+        last = t + 1 == steps
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _own_position_and_out():
-        cn = cnew_ref[...].astype(jnp.float32)  # [1, R]
-        rn = rnew_ref[...].astype(jnp.float32)
-        s = (jnp.sum(qlat.astype(jnp.float32) * cn, axis=-1, keepdims=True)
-             + jnp.sum(qrope.astype(jnp.float32) * rn, axis=-1, keepdims=True)) * scale
-        m = m_ref[...]
-        m_new = jnp.maximum(m, s)
-        corr, p = jnp.exp(m - m_new), jnp.exp(s - m_new)
-        l = l_ref[...] * corr + p
-        o_ref[...] = ((acc_ref[...] * corr + p * cn) / l).astype(o_ref.dtype)
+        @pl.when(jnp.logical_or(jnp.logical_not(last), has_next))
+        def _next_step():  # this slot's, or the first of the next slot
+            start(jnp.where(last, nxt, s), jnp.where(last, 0, t + 1), 1 - buf)
+
+        wait(s, t, buf)
+        c, r = cbuf_ref[buf], rbuf_ref[buf]  # [span, R], [Dr, span]
+        sc = (lax.dot_general(qlat, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+              + lax.dot_general(qrope, r, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)) * scale  # [H, span]
+        sc = jnp.where(t * span + lax.broadcasted_iota(jnp.int32, sc.shape, 1) < n, sc, _NEG)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + lax.dot_general(p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                                           preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m, l, acc = lax.fori_loop(0, steps, step, (jnp.full((H, 1), _NEG, jnp.float32),
+                                               jnp.zeros((H, 1), jnp.float32),
+                                               jnp.zeros((H, R), jnp.float32)))
+
+    par_ref[0] = (par + steps) % 2
+    cn = cnew_ref[...].astype(jnp.float32)  # [1, R]
+    rn = rnew_ref[...].astype(jnp.float32)
+    sc = (jnp.sum(qlat.astype(jnp.float32) * cn, axis=-1, keepdims=True)
+          + jnp.sum(qrope.astype(jnp.float32) * rn, axis=-1, keepdims=True)) * scale
+    m_new = jnp.maximum(m, sc)
+    corr, p = jnp.exp(m - m_new), jnp.exp(sc - m_new)
+    o_ref[...] = ((acc * corr + p * cn) / (l * corr + p)).astype(o_ref.dtype)
+
+
+def _pages_a_step(M: int, page_bytes: int) -> int:
+    """The table's ``M`` pages in the fewest equal steps of at most ``_STEP_BYTES``."""
+    steps = -(-M // max(1, min(M, _STEP_BYTES // page_bytes)))
+    return -(-M // steps)
 
 
 def mla_decode(
@@ -123,43 +172,35 @@ def mla_decode(
 ) -> jax.Array:
     """``o_lat [S, H, R]``: every slot's heads attending over the slot's
     ``lens[s]`` cached positions of ``layer`` and over its own new row."""
-    from jax.experimental.pallas import tpu as pltpu
-
     S, H, R = q_lat.shape
     Dr = q_rope.shape[-1]
     M, page = tables.shape[1], c_pool.shape[2]
-    pps = min(_PAGES_A_STEP, M)
-    layer = int(layer)
-
-    def page_at(i):
-        def at(s, j, tables, lens):
-            last = jnp.maximum((lens[s] + page - 1) // page - 1, 0)
-            return (layer, tables[s, jnp.minimum(jnp.minimum(j * pps + i, last), M - 1)], 0, 0)
-        return at
-
-    slot = lambda s, j, tables, lens: (s, 0, 0)  # noqa: E731
+    pps = _pages_a_step(M, page * (R + Dr) * c_pool.dtype.itemsize)
+    slot = lambda s, tables, lens: (s, 0, 0)  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_kernel, float(scale), page, pps),
+        functools.partial(_kernel, float(scale), page, pps, int(layer)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(S, -(-M // pps)),
+            grid=(S,),
             in_specs=[
                 pl.BlockSpec((None, H, R), slot),
                 pl.BlockSpec((None, H, Dr), slot),
                 pl.BlockSpec((None, 1, R), slot),
                 pl.BlockSpec((None, 1, Dr), slot),
-                *[pl.BlockSpec((None, None, page, R), page_at(i)) for i in range(pps)],
-                *[pl.BlockSpec((None, None, Dr, page), page_at(i)) for i in range(pps)],
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, H, R), slot),
-            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32), pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, R), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((2, pps * page, R), c_pool.dtype),
+                            pltpu.VMEM((2, Dr, pps * page), r_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)), pltpu.SMEM((1,), jnp.int32)],
         ),
         out_shape=jax.ShapeDtypeStruct((S, H, R), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         name=DECODE_NAME,
         interpret=_interpret(),
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), q_lat, q_rope,
-      c_new[:, None, :], r_new[:, None, :], *[c_pool] * pps, *[r_pool] * pps)
+      c_new[:, None, :], r_new[:, None, :], c_pool, r_pool)
 
 
 def _write_kernel(page, wpage_ref, lens_ref, cnew_ref, rcol_ref, cw_ref, rw_ref, co_ref, ro_ref):
@@ -184,8 +225,6 @@ def mla_cache_write(
     outputs: in place when the caller's pools are donated). A slot's page is
     read, the row (a column of the transposed rotated page) put in, and the
     page written back: 2 pages a slot a layer, no scatter."""
-    from jax.experimental.pallas import tpu as pltpu
-
     L, S, R = c_rows.shape
     Dr, page = r_rows.shape[-1], c_pool.shape[2]
     row = lambda l, s, wpage, lens: (l, s, 0, 0)  # noqa: E731
