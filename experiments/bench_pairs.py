@@ -12,7 +12,9 @@ A run spec is ``side:cell:seed:trace`` with side ``P`` or ``C``; a fifth
 field ``spans`` runs the cell through ``experiments/bench_spans.py`` of that
 checkout (span means, ``keys_ready_share``, ``dispatch_ahead_share``), and
 ``spans1`` the same at ``--dispatch-depth 1`` (a control on one commit; only
-for a checkout whose wrapper knows the option: PR 31 on). Each run is the
+for a checkout whose wrapper knows the option: PR 31 on); ``loop`` runs it
+through ``experiments/bench_loop_spans.py`` (the serving loop's six span
+metrics laid over the manifest: PR 37). Each run is the
 benchmark's own command in a process of its own, from its checkout's root; this process never
 touches JAX, so the chip is the child's. Per run: the whole output under
 ``--out`` (a traced run: also its ``breakdown`` and its ``*.xplane.pb``) and,
@@ -29,11 +31,14 @@ import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TELL = ("[bench] between two runs", "[spans]", "[bench] longest step brackets")
+TELL = ("[bench] between two runs", "[spans]", "[bench] longest step brackets", "[bench] loop spans",
+        "[bench] after a decode program", "[bench] the window's longest iterations", "[bench] from the decode program",
+        "[bench] first tokens in the window")
 # a run spec's fifth field -> the script, and its own options, the cell runs through
 VIA = {"": ["benchmark/run.py"],
        "spans": ["experiments/bench_spans.py"],
-       "spans1": ["experiments/bench_spans.py", "--dispatch-depth", "1"]}
+       "spans1": ["experiments/bench_spans.py", "--dispatch-depth", "1"],
+       "loop": ["experiments/bench_loop_spans.py"]}
 
 
 def one(spec, roots, out, seconds, tiny):

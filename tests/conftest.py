@@ -59,3 +59,19 @@ def mesh8(devices):
 @pytest.fixture
 def rng():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture(autouse=True)
+def no_profiler_session_outlives_its_test():
+    """A process has ONE profiler session. An anomaly or stall dump arms a
+    post-mortem capture of 2 s on a thread nobody waits for
+    (``obs/health.py arm_profiler_capture``); left running, it refuses the
+    ``start_trace`` of whatever test the worker runs next (ISSUE 37:
+    ``test_drift``'s dump made ``test_launch.py::test_profile_trace_capture``
+    fail under ``--dist loadfile``). The test that armed one waits it out."""
+    yield
+    import threading
+
+    for t in threading.enumerate():
+        if t.name.startswith("tmpi-postmortem-"):
+            t.join(30)

@@ -77,8 +77,10 @@ def arm_profiler_capture(trace_dir: str, capture_s: float = 2.0,
 
             os.makedirs(trace_dir, exist_ok=True)
             jax.profiler.start_trace(trace_dir)
-            time.sleep(capture_s)
-            jax.profiler.stop_trace()
+            try:
+                time.sleep(capture_s)
+            finally:
+                jax.profiler.stop_trace()
         except Exception as e:  # noqa: BLE001 — an armed Recorder
             # trace (already tracing) or a wedged runtime must not
             # surface as a crash from a diagnostics thread

@@ -16,7 +16,12 @@ twice under one kind), and the driver's phases inside a step's
 amortized window, in a step's order ``dispatch``, ``key_split`` (the
 NEXT unit's keys, split under the step just dispatched), ``drain`` and
 ``emit`` (depth 1: children of ``step``, so they stay out of the
-fractions).
+fractions). The serving loop (serve/decode/engine.py) keeps its own
+kinds in memory and writes them as ``span`` lines when it drains: an
+iteration's ``queue``, ``admit``, ``prefill``, ``upload``, ``dispatch``,
+``drain``, ``harvest`` (each with its ``iteration``) and a request's
+``queue_wait`` and ``first_token`` (each with its ``request`` and the
+``cause`` iteration).
 Schema: tools/check_obs_schema.py.
 
 Clock: ``t0`` is seconds of ``time.time_ns()``, the clock a profiler
@@ -51,7 +56,11 @@ from contextlib import contextmanager
 from typing import Optional
 
 SPAN_KINDS = ("data_wait", "h2d", "step", "grad_sync", "eval", "checkpoint",
-              "key_split", "dispatch", "drain", "emit")
+              "key_split", "dispatch", "drain", "emit",
+              # the serving loop's (serve/decode/engine.py LOOP_SPANS,
+              # REQUEST_SPANS; ``dispatch`` and ``drain`` are above)
+              "queue", "admit", "prefill", "upload", "harvest",
+              "queue_wait", "first_token")
 
 
 class SpanRecorder:

@@ -40,9 +40,28 @@ for autoregression:
    schedules hammer it harder).
 
 Telemetry is ``tmpi_decode_*``-prefixed (schema: ``kind=decode`` in
-tools/check_obs_schema.py): TTFT/TPOT histograms, tokens/sec,
+tools/check_obs_schema.py): TTFT/TPOT/queue-wait histograms, tokens/sec,
 kv page occupancy, batch occupancy, eviction/expiry counters, plus
 periodic ``decode`` JSONL records in ``<obs_dir>/decode.jsonl``.
+
+**The loop measured from inside** (ISSUE 37). The engine owns one
+:class:`~theanompi_tpu.utils.recorder.SpanStore` (the Recorder's own
+span class), found after the engine has gone by
+``span_store("decode")`` (a replica's: ``"decode/<replica_id>"``). The
+loop thread brackets seven spans an iteration, in loop order ``queue``
+(``_loop``'s body between two ``_iteration`` calls: the lock, the wait,
+the queue's hand-over), ``admit``, ``prefill`` (every call of the
+iteration; their count is kept beside it as ``prefill_calls``),
+``upload`` (the decode step's host arrays and their H2D), ``dispatch``
+(the decode call), ``drain`` (the ONE blocking D2H) and ``harvest``,
+each under the iteration's number (``tmpi_decode_iterations_total`` as
+the iteration begins), on ``time.time_ns()``, the clock of a profiler
+trace; each is a ``TraceAnnotation``. A request gets two spans by its
+``seq_id``: ``queue_wait`` (submit to the admission that gave it a
+slot) and ``first_token`` (that admission to its first token's
+``t_done``), each with the ``cause`` iteration. In memory, always on,
+no lock; ``drain()`` writes what the store still holds to
+``<obs_dir>/spans_rank<r>.jsonl`` (tools/spans_to_trace.py).
 """
 
 from __future__ import annotations
@@ -58,6 +77,7 @@ import numpy as np
 
 from theanompi_tpu.serve.decode.kvcache import PagedKVCache, pages_needed
 from theanompi_tpu.serve.decode.scheduler import DecodeScheduler, DecodeSequence
+from theanompi_tpu.utils.recorder import SpanStore
 from theanompi_tpu.serve.engine import (
     LATENCY_BUCKETS,
     DeadlineExceeded,
@@ -73,6 +93,7 @@ __all__ = [
     "DecodeEngine",
     "DecodeResult",
     "DEFAULT_PREFILL_BUCKETS",
+    "LOOP_SPANS",
     "DeadlineExceeded",
     "EngineDead",
     "EngineDraining",
@@ -81,6 +102,12 @@ __all__ = [
 ]
 
 DEFAULT_PREFILL_BUCKETS = (16, 64)
+
+# the loop thread's spans of one iteration, in loop order (see the
+# module's docstring), and a request's two
+LOOP_SPANS = ("queue", "admit", "prefill", "upload", "dispatch", "drain",
+              "harvest")
+REQUEST_SPANS = ("queue_wait", "first_token")
 
 # TPOT (time-per-output-token) lives well below request latency — extend
 # the serve band downward into the sub-millisecond range
@@ -275,6 +302,10 @@ class DecodeEngine:
         self._sink_f = None
         self._sink_lock = threading.Lock()
         self._sink_retired = False
+        self._spans_written = False
+        self._spans = SpanStore(
+            "decode" if self.replica_id is None
+            else f"decode/{self.replica_id}")
 
         self.registry = registry or MetricsRegistry()
         self._h_ttft = self.registry.histogram(
@@ -286,6 +317,11 @@ class DecodeEngine:
             "tmpi_decode_tpot_seconds",
             help="per-output-token latency after the first token",
             buckets=TPOT_BUCKETS,
+        )
+        self._h_queue_wait = self.registry.histogram(
+            "tmpi_decode_queue_wait_seconds",
+            help="submit -> the admission that gave the request a slot",
+            buckets=LATENCY_BUCKETS,
         )
         self._g_queue = self.registry.gauge(
             "tmpi_decode_queue_depth",
@@ -341,13 +377,6 @@ class DecodeEngine:
         self._c_evicted = self.registry.counter(
             "tmpi_decode_evicted_total",
             help="running sequences evicted (deadline) — typed, not a drop",
-        )
-        self._c_preempted = self.registry.counter(
-            "tmpi_decode_preempted_total",
-            help="running sequences preempted for capacity (admission "
-                 "reserves worst-case pages, so this stays 0 — the "
-                 "counter exists so a future best-effort-admission mode "
-                 "cannot hide preemptions)",
         )
         self._c_reloads = self.registry.counter(
             "tmpi_decode_reloads_total",
@@ -512,7 +541,43 @@ class DecodeEngine:
                     self._sink_retired = True
                     self._sink_f.close()
                     self._sink_f = None
+        # the first drain that saw the loop thread end (one that timed out
+        # before it leaves this to the next): the rings stand still
+        if drained and self.obs_dir is not None and not self._spans_written:
+            self._spans_written = True
+            self._write_spans()
         return drained
+
+    def _write_spans(self) -> None:
+        """The spans the store still holds, as ``span`` lines of the
+        training side's schema (tools/check_obs_schema.py) in
+        ``<obs_dir>/spans_rank<r>.jsonl`` (``r``: the replica, else 0),
+        where tools/spans_to_trace.py looks: a loop span carries its
+        ``iteration`` (``prefill`` also its ``calls``), a request's span
+        its ``request`` and the ``cause`` iteration."""
+        rank = self.replica_id or 0
+        lines = []
+        calls = self._spans.counts.get("prefill_calls")
+        for name in LOOP_SPANS + REQUEST_SPANS:
+            ring = self._spans.span_rings.get(name)
+            if ring is None:
+                continue
+            key = "iteration" if name in LOOP_SPANS else "request"
+            numbers, t0s, durs = ring.held()
+            causes = ring.cause[numbers % ring.capacity]
+            for n, t0, dur, cause in zip(*(a.tolist() for a in (numbers, t0s, durs, causes))):
+                rec = {"kind": "span", "name": name, "rank": rank,
+                       "t0": t0 * 1e-9, "dur": dur * 1e-9, "depth": 0, key: n}
+                if cause >= 0:
+                    rec["cause"] = cause
+                if name == "prefill" and calls is not None:
+                    rec["calls"] = calls.get(n) or 0
+                lines.append(json.dumps(rec))
+        if lines:
+            os.makedirs(self.obs_dir, exist_ok=True)
+            path = os.path.join(self.obs_dir, f"spans_rank{rank}.jsonl")
+            with open(path, "a") as f:
+                f.write("\n".join(lines) + "\n")
 
     close = drain
 
@@ -593,6 +658,9 @@ class DecodeEngine:
             if deadline_ms else None
         )
         fut = ServeFuture()
+        # the span clock read beside the future's own monotonic stamp: the
+        # queue_wait span's t0 (its duration comes from the stamps)
+        t_submit_ns = self._spans.clock_ns()
         seq = DecodeSequence(
             prompt,
             max_new_tokens=n_new,
@@ -602,6 +670,7 @@ class DecodeEngine:
             future=fut,
             t_submit=fut.t_submit,
         )
+        seq.t_submit_ns = t_submit_ns
         with self._cond:
             if self._draining:
                 self._c_requests.inc(status="rejected")
@@ -641,17 +710,22 @@ class DecodeEngine:
 
     # -- decode loop --------------------------------------------------------
     def _loop(self) -> None:
+        spans = self._spans
         while True:
+            # an idle engine's waits land in this span and nowhere else
+            spans.enter("queue")
             with self._cond:
                 while (not self._q and not self._sched.has_work()
                        and not self._draining):
                     self._cond.wait(0.05)
                 if (self._draining and not self._q
                         and not self._sched.has_work()):
+                    spans.leave("queue")
                     return
                 while self._q:
                     self._sched.add(self._q.popleft())
                 self._g_queue.set(self._sched.n_waiting)
+            spans.leave("queue", self._iterations)
             try:
                 self._iteration()
             except BaseException as e:  # noqa: BLE001 — generations must
@@ -659,6 +733,7 @@ class DecodeEngine:
                 # owns (releasing its KV pages) and keep the thread
                 # alive. An abort poisons the iteration on purpose —
                 # those count as failed, not rejected
+                spans.abandon()
                 self._fail_all(e)
 
     def _iteration(self) -> None:
@@ -671,16 +746,23 @@ class DecodeEngine:
         err = self._abort_error
         if err is not None:  # the replica died under this iteration
             raise err
+        spans, it = self._spans, self._iterations
         now = time.monotonic()
         served = self._served  # ONE read: the swap point for hot reload
+        spans.enter("admit")
         admitted, expired = self._sched.admit(now)
         for seq in expired:
             seq.future._reject(DeadlineExceeded(
                 "deadline passed before a decode slot opened"
             ))
             self._c_requests.inc(status="expired")
+        for seq in admitted:
+            self._note_admitted(seq, it)
+        spans.leave("admit", it)
         t0 = time.monotonic()
         c = self._cache
+        calls = 0
+        spans.enter("prefill")
         for seq in admitted:
             pf = self._sched.prefill_args(seq)
             if pf is None:
@@ -693,29 +775,58 @@ class DecodeEngine:
                 c.k_pool, c.v_pool, *where,
             )
             self._c_prefills.inc(bucket=bucket)
+            calls += 1
+        spans.leave("prefill", it)
+        spans.count("prefill_calls", it, calls)
         if not self._sched.running:
             return
+        spans.enter("upload")
         tables, seq_lens, last, active, temp = self._sched.step_arrays()
         if self._visible_share is not None:
             self._g_visible.set(self._visible_share(seq_lens[active]))
-        nxt, _logits, c.k_pool, c.v_pool = self._decode(
-            served.params, c.k_pool, c.v_pool,
+        step_inputs = (
             jnp.asarray(tables), jnp.asarray(seq_lens), jnp.asarray(last),
-            jnp.asarray(active), jnp.asarray(temp),
-            np.int32(self._iterations),
+            jnp.asarray(active), jnp.asarray(temp), np.int32(it),
         )
+        spans.leave("upload", it)
+        spans.enter("dispatch")
+        nxt, _logits, c.k_pool, c.v_pool = self._decode(
+            served.params, c.k_pool, c.v_pool, *step_inputs)
+        spans.leave("dispatch", it)
+        spans.enter("drain")
+        # the uploads die here, under the running step, as the call's own
+        # temporaries did: their release lets other threads run, and left
+        # until the iteration's end it let a client's resubmission in
+        # ahead of the next hand-over (ISSUE 37, measured on the chip:
+        # ttft_p50_ms 29.9 -> 18.3 in lm136m-decode-closed; ROADMAP S9c)
+        del step_inputs
         next_np = np.asarray(nxt)  # the ONE host drain per iteration
+        spans.leave("drain", it)
         t_done = time.monotonic()
         err = self._abort_error
         if err is not None:  # abort landed mid-step: nothing resolves
             raise err        # after a death
         self._harvest(next_np, served.step, t_done, t0)
 
+    def _note_admitted(self, seq: DecodeSequence, it: int) -> None:
+        """A request got its slot in iteration ``it``: its ``queue_wait``
+        span (the scheduler's ``t_admit`` less the future's ``t_submit``,
+        both monotonic; ``t0`` from the span clock read at submission)
+        and the histogram, once a request."""
+        if seq.t_submit is None or seq.t_submit_ns is None:
+            return
+        wait = max(0.0, seq.t_admit - seq.t_submit)
+        self._spans.put("queue_wait", seq.seq_id, seq.t_submit_ns,
+                        int(wait * 1e9), cause=it)
+        self._h_queue_wait.observe(wait)
+
     def _harvest(self, next_np: np.ndarray, step: int, t_done: float,
                  t0: float) -> None:
         """Post-step bookkeeping: append tokens, resolve finished
         generations, evict deadline-passed ones (typed — never a
         silent drop), update telemetry."""
+        spans, it = self._spans, self._iterations
+        spans.enter("harvest")
         n_live = 0
         for slot, seq in list(self._sched.running.items()):
             tok = int(next_np[slot])
@@ -725,6 +836,11 @@ class DecodeEngine:
                 seq.t_first_token = t_done
                 if seq.t_submit is not None:
                     self._h_ttft.observe(t_done - seq.t_submit)
+                    if seq.t_submit_ns is not None:
+                        wait = int(max(0.0, seq.t_admit - seq.t_submit) * 1e9)
+                        spans.put("first_token", seq.seq_id,
+                                  seq.t_submit_ns + wait,
+                                  int((t_done - seq.t_admit) * 1e9), cause=it)
             if seq.done:
                 self._sched.remove(slot, "finished")
                 n = len(seq.generated)
@@ -757,6 +873,7 @@ class DecodeEngine:
         self._iterations += 1
         if self._iterations % self.record_every == 0:
             self._write_record(self.decode_record())
+        spans.leave("harvest", it)
 
     def _fail_all(self, e: BaseException) -> None:
         """Failure path for a poisoned iteration: reject every
@@ -804,7 +921,6 @@ class DecodeEngine:
         fl = self._cache.free_list
         out = {
             "tmpi_decode_queue_depth": float(self.queue_depth),
-            "tmpi_decode_running": float(self._sched.n_running),
             "tmpi_decode_batch_occupancy": self._sched.occupancy,
             "tmpi_decode_kv_pages_used": float(self._cache.pages_used),
             "tmpi_decode_kv_pages_free": float(self._cache.pages_free),
@@ -818,7 +934,6 @@ class DecodeEngine:
             "tmpi_decode_served_total": self._c_requests.value(status="served"),
             "tmpi_decode_expired_total": self._c_requests.value(status="expired"),
             "tmpi_decode_evicted_total": self._c_evicted.value(),
-            "tmpi_decode_preempted_total": self._c_preempted.value(),
             "tmpi_decode_rejected_total":
                 self._c_requests.value(status="rejected"),
             "tmpi_decode_failed_total": self._c_requests.value(status="failed"),
@@ -841,6 +956,18 @@ class DecodeEngine:
         tpot = self._h_tpot.quantile(0.5)
         if tpot is not None:
             out["tmpi_decode_tpot_ms"] = 1000.0 * tpot
+        wait = self._h_queue_wait.quantile(0.5)
+        if wait is not None:
+            out["tmpi_decode_queue_wait_p50_ms"] = 1000.0 * wait
+        # the loop's seven spans, mean over the last record_every
+        # iterations the rings hold (read here, off the iteration's path)
+        hi = self._iterations
+        for name in LOOP_SPANS:
+            ring = self._spans.span_rings.get(name)
+            dur = None if ring is None else ring.durations(
+                hi - self.record_every, hi)
+            if dur is not None and len(dur):
+                out[f"tmpi_decode_loop_{name}_ms"] = 1e-6 * float(dur.mean())
         return out
 
     def decode_record(self) -> dict:

@@ -46,6 +46,7 @@ class DecodeSequence:
     __slots__ = (
         "seq_id", "prompt", "max_new_tokens", "temperature", "deadline",
         "future", "t_submit", "slot", "generated", "t_first_token",
+        "t_submit_ns", "t_admit",
     )
 
     def __init__(self, prompt, *, max_new_tokens: int,
@@ -66,6 +67,11 @@ class DecodeSequence:
         self.slot: Optional[int] = None
         self.generated: List[int] = []
         self.t_first_token: Optional[float] = None
+        # the engine's request spans: the span clock (time.time_ns) read
+        # beside ``t_submit``, and the ``now`` of the admission pass that
+        # gave the request its slot (monotonic, as ``t_submit`` is)
+        self.t_submit_ns: Optional[int] = None
+        self.t_admit: Optional[float] = None
 
     @property
     def prompt_len(self) -> int:
@@ -178,6 +184,7 @@ class DecodeScheduler:
             self.waiting.popleft()
             self._free_slots.pop()
             seq.slot = slot
+            seq.t_admit = now
             self.running[slot] = seq
             self.admitted_total += 1
             admitted.append(seq)

@@ -64,6 +64,16 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
         # amortized step); spans outside the train loop carry none.
         # ``t0`` is seconds of time.time_ns(), the profiler's clock
         "step": ((int,), False),
+        # the serving loop's spans (serve/decode/engine.py, written by
+        # drain()): the ``iteration`` a loop span belongs to (queue,
+        # admit, prefill, upload, dispatch, drain, harvest; ``calls``:
+        # the prefill calls inside a ``prefill`` span); a request's
+        # queue_wait / first_token span carries its ``request`` number
+        # and ``cause``, the iteration that admitted or answered it
+        "iteration": ((int,), False),
+        "calls": ((int,), False),
+        "request": ((int,), False),
+        "cause": ((int,), False),
     },
     "span_summary": {
         "rank": ((int,), True),
@@ -510,6 +520,13 @@ SERVE_METRIC_PREFIX = "tmpi_serve_"
 # these-prefixed keys — enforced below, same deal as serve's):
 #   tmpi_decode_ttft_seconds    histogram  submit -> first token
 #   tmpi_decode_tpot_seconds    histogram  per-token decode interval
+#   tmpi_decode_queue_wait_seconds histogram  submit -> the admission
+#                                          that gave the request a slot
+#                                          (record: ..._queue_wait_p50_ms)
+#   tmpi_decode_loop_<span>_ms  record only: mean of the loop thread's
+#                                          queue|admit|prefill|upload|
+#                                          dispatch|drain|harvest span over
+#                                          the last record_every iterations
 #   tmpi_decode_queue_depth     gauge      prompts waiting for a slot
 #   tmpi_decode_batch_occupancy gauge      running seqs / max_seqs
 #   tmpi_decode_kv_pages_used   gauge      KV pool pages outstanding

@@ -183,9 +183,11 @@ def capture_model_step(model_name: str, batch: Optional[int], steps: int,
     out = runner(state, x, y, jax.random.PRNGKey(1))
     np.asarray(out[1]["loss"])  # compile + warm outside the window
     jax.profiler.start_trace(trace_dir)
-    out = runner(state, x, y, jax.random.PRNGKey(1))
-    np.asarray(out[1]["loss"])
-    jax.profiler.stop_trace()
+    try:
+        out = runner(state, x, y, jax.random.PRNGKey(1))
+        np.asarray(out[1]["loss"])
+    finally:  # the process has ONE profiler session: never leave it open
+        jax.profiler.stop_trace()
 
 
 def main(argv=None) -> int:

@@ -338,9 +338,11 @@ def run_profile(
         os.makedirs(trace_dir, exist_ok=True)
         k = min(4, steps)
         jax.profiler.start_trace(trace_dir)
-        for i in range(k):
-            state, rng, *_ = one_step(state, rng, want + i)
-        jax.profiler.stop_trace()
+        try:
+            for i in range(k):
+                state, rng, *_ = one_step(state, rng, want + i)
+        finally:  # the process has ONE profiler session: never leave it open
+            jax.profiler.stop_trace()
         from theanompi_tpu.tools.op_profile import op_table
 
         ops = join_op_table(op_table(trace_dir, steps=k), attr)

@@ -15,6 +15,12 @@ files into ONE trace viewable in ``chrome://tracing`` / Perfetto /
   utils/dispatch.py) on their OWN lane (thread 1, ``amortized``),
   flagged in ``args`` — attributed time is not a measured bracket and
   must not fake-nest under real ones;
+- a serving run's spans (serve/decode/engine.py writes them when it
+  drains, ``spans_rank<replica>.jsonl``): the loop's seven phases on
+  thread 0 with their ``iteration`` in ``args``; a request's
+  ``queue_wait`` and ``first_token`` as ASYNC events (``"ph": "b"`` /
+  ``"e"``, ``id`` = the request): requests overlap, so each gets a
+  track of its own, with the ``cause`` iteration in ``args``;
 - ``span_summary`` lines become per-process metadata (``args`` on a
   zero-duration instant event) so the per-kind fractions travel with
   the trace.
@@ -137,7 +143,16 @@ def convert(paths: list[str], align: bool = True) -> dict:
                 except ValueError:
                     continue
                 kind = row.get("kind")
-                if kind == "span":
+                if kind == "span" and "request" in row:
+                    ts = (row["t0"] + shift) * 1e6
+                    common = {"name": row["name"], "cat": "request",
+                              "id": row["request"], "pid": rank, "tid": 0}
+                    events.append({**common, "ph": "b", "ts": ts, "args": {
+                        k: row[k] for k in ("request", "cause") if k in row}})
+                    events.append({**common, "ph": "e",
+                                   "ts": ts + max(0.0, row["dur"] * 1e6)})
+                    seen_ranks.add(rank)
+                elif kind == "span":
                     amortized = bool(row.get("amortized", False))
                     events.append({
                         "name": row["name"],
@@ -148,8 +163,9 @@ def convert(paths: list[str], align: bool = True) -> dict:
                         "tid": 1 if amortized else 0,
                         "args": {"depth": row.get("depth", 0),
                                  "amortized": amortized,
-                                 **({"step": row["step"]}
-                                    if "step" in row else {})},
+                                 **{k: row[k] for k in
+                                    ("step", "iteration", "calls")
+                                    if k in row}},
                     })
                     seen_ranks.add(rank)
                 elif kind == "span_summary":
@@ -192,7 +208,7 @@ def main(argv: Optional[list] = None) -> int:
     trace = convert(files, align=not args.no_align)
     with open(args.out, "w") as f:
         json.dump(trace, f)
-    n_spans = sum(1 for e in trace["traceEvents"] if e["ph"] == "X")
+    n_spans = sum(1 for e in trace["traceEvents"] if e["ph"] in "Xb")
     print(f"wrote {args.out}: {n_spans} spans from {len(files)} "
           f"file{'s' if len(files) != 1 else ''}")
     return 0

@@ -17,7 +17,9 @@ is kept because it was good; additions over the reference:
   bracket ALSO opens/closes a trace span (wait -> ``data_wait``,
   comm -> ``grad_sync``, others by name) — the Recorder stays the
   single emission point, the obs files the machine-readable sinks;
-- ONE span store (ISSUE 26): a bracket closed with a step number keeps
+- ONE span store (ISSUE 26; its own class since ISSUE 37, so that the
+  serving loop brackets through the same code): :class:`SpanStore`, which
+  the Recorder is. A bracket closed with a step number keeps
   its start and duration in :class:`SpanRing`, in memory, always on.
   Stamps are integer nanoseconds of ``time.time_ns()``, the clock the
   profiler writes a ``*.xplane.pb`` in (an event's ``start_ns`` there
@@ -50,27 +52,33 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 SPAN_RING_STEPS = 65536
+# how long an armed capture waits for a profiler session another holds
+PROFILE_BUSY_WAIT_S = 10.0
 
 
 class SpanRing:
     """One bracket category's spans of the last ``capacity`` steps:
     start and duration in integer nanoseconds, addressed by step number
     (slot ``step % capacity``; an older step's slot is overwritten, so a
-    long run holds constant memory)."""
+    long run holds constant memory). ``cause``: the number of the span
+    that caused this one (a request's span names the loop iteration that
+    admitted or answered it), -1 where none."""
 
-    __slots__ = ("capacity", "steps", "t0_ns", "dur_ns")
+    __slots__ = ("capacity", "steps", "t0_ns", "dur_ns", "cause")
 
     def __init__(self, capacity: int = SPAN_RING_STEPS):
         self.capacity = int(capacity)
         self.steps = np.full(self.capacity, -1, np.int64)
         self.t0_ns = np.zeros(self.capacity, np.int64)
         self.dur_ns = np.zeros(self.capacity, np.int64)
+        self.cause = np.full(self.capacity, -1, np.int64)
 
-    def put(self, step: int, t0_ns: int, dur_ns: int) -> None:
+    def put(self, step: int, t0_ns: int, dur_ns: int, cause: int = -1) -> None:
         i = step % self.capacity
         self.steps[i] = step
         self.t0_ns[i] = t0_ns
         self.dur_ns[i] = dur_ns
+        self.cause[i] = cause
 
     def get(self, step: int) -> Optional[tuple[int, int]]:
         """``(t0_ns, dur_ns)`` of ``step``'s span, or None where the ring
@@ -81,13 +89,134 @@ class SpanRing:
         return int(self.t0_ns[i]), int(self.dur_ns[i])
 
     def held(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """``(steps, t0_ns, dur_ns)`` of every span held, by step."""
+        """``(steps, t0_ns, dur_ns)`` of every span held, by step (their
+        causes: ``cause[steps % capacity]``)."""
         idx = np.flatnonzero(self.steps >= 0)
         idx = idx[np.argsort(self.steps[idx], kind="stable")]
         return self.steps[idx], self.t0_ns[idx], self.dur_ns[idx]
 
+    def durations(self, lo: int, hi: int) -> np.ndarray:
+        """``dur_ns`` of the spans numbered ``lo`` to ``hi - 1`` that are
+        still held (a reader's window, without a pass over the ring)."""
+        numbers = np.arange(max(0, lo), hi)
+        at = numbers % self.capacity
+        return self.dur_ns[at][self.steps[at] == numbers]
 
-class Recorder:
+
+class CountRing:
+    """A whole number a step (how many prefill calls an iteration made),
+    kept beside the spans and addressed as :class:`SpanRing` is."""
+
+    __slots__ = ("capacity", "steps", "values")
+
+    def __init__(self, capacity: int = SPAN_RING_STEPS):
+        self.capacity = int(capacity)
+        self.steps = np.full(self.capacity, -1, np.int64)
+        self.values = np.zeros(self.capacity, np.int64)
+
+    def put(self, step: int, value: int) -> None:
+        i = step % self.capacity
+        self.steps[i] = step
+        self.values[i] = value
+
+    def get(self, step: int) -> Optional[int]:
+        i = step % self.capacity
+        return int(self.values[i]) if self.steps[i] == step else None
+
+
+class SpanStore:
+    """Brackets of one thread, kept by number: the one span
+    implementation of the package. ``enter(category)`` reads
+    ``clock_ns`` and opens a ``TraceAnnotation`` of the category's name;
+    ``leave(category, number)`` reads the clock again and keeps start and
+    duration in the category's :class:`SpanRing` under ``number`` (a
+    training step, a serving iteration). Rings are made on first use
+    and stay at ``SPAN_RING_STEPS``; no lock, no I/O, always on.
+    ``put`` keeps a span measured elsewhere (a request's, from stamps
+    it already carries), ``count`` a whole number beside a span's.
+
+    A store made with a ``name`` is found again by :func:`span_store`
+    after its owner has gone: a reader outside the program (the
+    benchmark's per-layer metrics) asks by name."""
+
+    clock_ns = staticmethod(time.time_ns)  # the profiler's clock
+
+    def __init__(self, name: Optional[str] = None):
+        # open brackets: category -> (t0_ns, trace annotation)
+        self._open: dict[str, tuple] = {}
+        self.span_rings: dict[str, SpanRing] = {}
+        self.counts: dict[str, CountRing] = {}
+        self.t_end_ns = 0  # the stamp that closed the newest bracket
+        if name is not None:
+            _STORES[name] = self
+
+    def enter(self, category: str) -> int:
+        # with no profiler session, entering one costs an atomic read
+        ann = TraceAnnotation(category)
+        ann.__enter__()
+        t0 = self.clock_ns()
+        self._open[category] = (t0, ann)
+        return t0
+
+    def leave(self, category: str,
+              number: Optional[int] = None) -> Optional[tuple[int, int]]:
+        """Close ``category``'s bracket -> ``(t0_ns, t1_ns)``, or None
+        where none is open."""
+        t1 = self.clock_ns()
+        opened = self._open.pop(category, None)
+        if opened is None:
+            return None
+        t0, ann = opened
+        ann.__exit__(None, None, None)
+        self.t_end_ns = t1
+        if number is not None:
+            # the wall clock may be set back
+            self.put(category, number, t0, max(0, t1 - t0))
+        return t0, t1
+
+    def abandon(self) -> None:
+        """Close every open bracket and keep none (an iteration that
+        raised between an ``enter`` and its ``leave``)."""
+        while self._open:
+            _, (_, ann) = self._open.popitem()
+            ann.__exit__(None, None, None)
+
+    def put(self, category: str, number: int, t0_ns: int, dur_ns: int,
+            cause: int = -1) -> None:
+        ring = self.span_rings.get(category)
+        if ring is None:
+            ring = self.span_rings[category] = SpanRing()
+        ring.put(number, t0_ns, dur_ns, cause)
+
+    def count(self, name: str, number: int, value: int) -> None:
+        """Keep the whole number ``value`` under ``number``."""
+        ring = self.counts.get(name)
+        if ring is None:
+            ring = self.counts[name] = CountRing()
+        ring.put(number, value)
+
+    def counted(self, name: str, number: int) -> Optional[int]:
+        ring = self.counts.get(name)
+        return None if ring is None else ring.get(number)
+
+    def span(self, category: str, step: int) -> Optional[tuple[int, int]]:
+        """``(t0_ns, dur_ns)`` of ``category``'s bracket of ``step``, on
+        the ``clock_ns`` clock; None where none is held."""
+        ring = self.span_rings.get(category)
+        return None if ring is None else ring.get(step)
+
+
+_STORES: dict[str, SpanStore] = {}
+
+
+def span_store(name: str) -> Optional[SpanStore]:
+    """The newest :class:`SpanStore` made under ``name`` in this process
+    (``"decode"``: the serving loop's; a router's replicas are
+    ``"decode/<replica_id>"``), or None."""
+    return _STORES.get(name)
+
+
+class Recorder(SpanStore):
     # bracket category -> obs span kind (obs/spans.py SPAN_KINDS); the
     # reference's 'comm' bracket is the gradient exchange, hence grad_sync
     SPAN_NAMES = {"wait": "data_wait", "comm": "grad_sync"}
@@ -96,7 +225,6 @@ class Recorder:
     # span_summary's top-level fractions disjoint (``wait`` stays beside
     # it at depth 0: the amortized step already excludes the waits)
     STEP_CHILDREN = frozenset({"key_split", "dispatch", "drain", "emit"})
-    clock_ns = staticmethod(time.time_ns)  # the profiler's clock
 
     def __init__(
         self,
@@ -114,10 +242,8 @@ class Recorder:
         self.run_name = run_name
         self.registry = registry  # obs.MetricsRegistry or None
         self.spans = spans  # obs.SpanRecorder or None
-        # open brackets: category -> (t0_ns, trace annotation, sink token)
-        self._open: dict[str, tuple] = {}
-        self.span_rings: dict[str, SpanRing] = {}
-        self.t_end_ns = 0  # the stamp that closed the newest bracket
+        SpanStore.__init__(self)
+        self._tokens: dict[str, dict] = {}  # open brackets' sink tokens
         self.timings: dict[str, list[float]] = defaultdict(list)
         self.history: dict[str, list] = defaultdict(list)
         self.epoch_start: Optional[float] = None
@@ -180,7 +306,25 @@ class Recorder:
                 import jax
 
                 os.makedirs(p["dir"], exist_ok=True)
-                jax.profiler.start_trace(p["dir"])
+                try:
+                    jax.profiler.start_trace(p["dir"])
+                except RuntimeError as e:
+                    # the process has ONE profiler session and another
+                    # capture holds it (a post-mortem trace the watchdog
+                    # or the flight recorder armed: obs/health.py, 2 s):
+                    # stay armed and try again at the next step, for
+                    # PROFILE_BUSY_WAIT_S; any other failure, or a
+                    # session held longer, is the operator's to see
+                    now = time.monotonic()
+                    since = p.setdefault("busy_since", now)
+                    if ("already been started" not in str(e)
+                            or now - since > PROFILE_BUSY_WAIT_S):
+                        p["state"] = "done"
+                        raise
+                    if since == now:
+                        print(f"[rank {self.rank}] profile capture waits: "
+                              f"{e}", flush=True)
+                    return
                 p["state"] = "tracing"
                 p["started_at"] = step
         elif p["state"] == "tracing" and step >= p["started_at"] + p["n"]:
@@ -190,8 +334,10 @@ class Recorder:
         p = self._prof
         import jax
 
-        jax.profiler.stop_trace()
-        p["state"] = "done"
+        try:
+            jax.profiler.stop_trace()
+        finally:  # whatever stop_trace raised, this capture is over
+            p["state"] = "done"
         print(
             f"[rank {self.rank}] wrote XLA trace to {p['dir']}"
             + (f" ({reason})" if reason else "")
@@ -201,17 +347,12 @@ class Recorder:
 
     # -- timing brackets (reference API) ------------------------------------
     def start(self, category: str = "calc") -> None:
-        # with no profiler session, entering one costs an atomic read
-        ann = TraceAnnotation(category)
-        ann.__enter__()
-        t0 = self.clock_ns()
-        token = None
+        t0 = self.enter(category)
         if self.spans is not None:
-            token = self.spans.begin(
+            self._tokens[category] = self.spans.begin(
                 self.SPAN_NAMES.get(category, category), t0_ns=t0,
                 under=1 if category in self.STEP_CHILDREN else 0,
             )
-        self._open[category] = (t0, ann, token)
 
     def end(self, category: str = "calc", sync=None,
             step: Optional[int] = None) -> float:
@@ -230,9 +371,8 @@ class Recorder:
                 sync.block_until_ready()
             except AttributeError:
                 pass
-        t1 = self.clock_ns()
-        opened = self._open.pop(category, None)
-        if opened is None:
+        closed = self.leave(category, step)
+        if closed is None:
             import warnings
 
             warnings.warn(
@@ -241,17 +381,10 @@ class Recorder:
                 RuntimeWarning, stacklevel=2,
             )
             return 0.0
-        t0, ann, token = opened
-        ann.__exit__(None, None, None)
-        self.t_end_ns = t1
-        dur_ns = max(0, t1 - t0)  # the wall clock may be set back
-        dt = dur_ns * 1e-9
+        t0, t1 = closed
+        dt = max(0, t1 - t0) * 1e-9
         self.timings[category].append(dt)
-        if step is not None:
-            ring = self.span_rings.get(category)
-            if ring is None:
-                ring = self.span_rings[category] = SpanRing()
-            ring.put(step, t0, dur_ns)
+        token = self._tokens.pop(category, None)
         if token is not None and self.spans is not None:
             self.spans.finish(token, t1_ns=t1, step=step)
         if self.registry is not None:
@@ -261,12 +394,6 @@ class Recorder:
                 help=f"Recorder '{category}' bracket wall time",
             ).observe(dt)
         return dt
-
-    def span(self, category: str, step: int) -> Optional[tuple[int, int]]:
-        """``(t0_ns, dur_ns)`` of ``category``'s bracket of ``step``, on
-        the ``clock_ns`` clock; None where none is held."""
-        ring = self.span_rings.get(category)
-        return None if ring is None else ring.get(step)
 
     def note_time(self, category: str, dt: float,
                   step: Optional[int] = None) -> float:
