@@ -418,3 +418,154 @@ def test_http_frontend_single_decode_engine():
         httpd.shutdown()
         httpd.server_close()
         eng.drain(timeout=30)
+
+
+# -- ISSUE 38: one packed operand for the decode step ----------------------
+def _mistral4_model():
+    from theanompi_tpu.models.mistral4 import Mistral4LM
+
+    return Mistral4LM()
+
+
+def _minicpm_sala_model():
+    from theanompi_tpu.models.minicpm_sala import MiniCPMSALA
+
+    return MiniCPMSALA()
+
+
+# the cache kinds the suite's tiny engines cover: per-head K and V (not donated), the latent kind and pages
+# beside state held a slot (both donated)
+KINDS = {"kv": tiny_model, "latent": _mistral4_model, "pages_and_state": _minicpm_sala_model}
+
+
+def _kind_engine(kind, **kw):
+    eng = DecodeEngine(KINDS[kind](), prefill_buckets=(8, 16), kv_pages=48, page_size=8, max_seqs=4,
+                       max_new_tokens=8, seed=11, **kw)
+    params, state = eng.model.init(jax.random.PRNGKey(3))
+    assert eng.set_params(params, state, 1)
+    return eng
+
+
+def _the_five_arrays(sched):
+    """``step_arrays`` as it stood before ISSUE 38: five host arrays."""
+    S = sched.cache.max_seqs
+    seq_lens, last = np.zeros((S,), np.int32), np.zeros((S,), np.int32)
+    active, temp = np.zeros((S,), bool), np.zeros((S,), np.float32)
+    for slot, seq in sched.running.items():
+        seq_lens[slot], last[slot], active[slot], temp[slot] = seq.pos, seq.last_token, True, seq.temperature
+    return sched.cache.page_tables.copy(), seq_lens, last, active, temp
+
+
+def test_the_decode_program_hands_decode_step_what_step_arrays_packed():
+    """The packed buffer cut apart INSIDE the program: ``decode_step`` gets, bit
+    for bit and dtype for dtype, the five arrays the scheduler made before and
+    the key folded from the counter: a batch with an inactive slot and a freed
+    one, temperatures that are not 0, ``it`` > 0."""
+    from theanompi_tpu.serve.decode.scheduler import DecodeSequence
+
+    eng = _kind_engine("kv")
+    seen = lambda params, k_pool, v_pool, *given, page_size: (given[:5], given[5], k_pool, v_pool)  # noqa: E731
+    eng.model.decode_step = seen  # before the one trace: the program hands out what the model was given
+    seqs = [DecodeSequence(prompt(*range(1, n + 1)), max_new_tokens=5, temperature=t)
+            for n, t in ((3, 0.0), (9, 0.7), (1, 1.3))]
+    for seq in seqs:
+        eng._sched.add(seq)
+    assert len(eng._sched.admit(0.0)[0]) == 3
+    seqs[1].generated += [4, 17]
+    eng._sched.remove(seqs[0].slot, "finished")  # a freed slot's row is zeros again
+    want = _the_five_arrays(eng._sched)
+    assert want[3].tolist().count(True) == 2 and set(want[4].tolist()) == {0.0, np.float32(0.7), np.float32(1.3)}
+    c = eng._cache
+    for it in (7, 2 ** 31 - 1):
+        packed = eng._sched.step_arrays(it)
+        assert packed.dtype == np.int32 and packed.shape == (4 * (c.max_pages_per_seq + 4) + 1,)
+        assert packed is eng._sched.step_arrays(it)  # kept and written in place
+        got, key, _, _ = eng._decode(eng._served.params, c.k_pool, c.v_pool, packed)
+        for g, w in zip(got, want):
+            g = np.asarray(g)
+            assert g.dtype == w.dtype and g.shape == w.shape and g.tobytes() == w.tobytes()
+        assert np.array_equal(np.asarray(key), np.asarray(jax.random.fold_in(jax.random.PRNGKey(11), np.int32(it))))
+    assert eng.compile_count == 1
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_short_generation_is_token_for_token_the_five_operand_programs(kind):
+    """The whole loop over the packed operand against the same loop over the
+    program as it stood (five operands and the counter, made on the host from
+    the same buffer): the same tokens, sampled ones among them, in every cache
+    kind. Every request is queued before the start, so both loops admit all
+    of them in iteration 0 and the counter (the sampling key) runs alike."""
+    import jax.numpy as jnp
+
+    tokens = {}
+    for form in ("packed", "five"):
+        eng = _kind_engine(kind)
+        if form == "five":
+            model = eng.model
+
+            def five(params, k_pool, v_pool, tables, seq_lens, last, active, temp, it):
+                key = jax.random.fold_in(jax.random.PRNGKey(11), it)
+                return model.decode_step(params, k_pool, v_pool, tables, seq_lens, last, active, temp, key,
+                                         page_size=8)
+
+            program = jax.jit(five, donate_argnums=(1, 2) if eng._donate else ())
+
+            def decode(params, k_pool, v_pool, packed):
+                tables, seq_lens, last, active, temp, it = eng._sched.split_step(packed)
+                return program(params, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(seq_lens),
+                               jnp.asarray(last), jnp.asarray(active != 0), jnp.asarray(temp.view(np.float32)),
+                               np.int32(it))
+
+            eng._decode = decode
+        assert eng.warmup() == len(eng.buckets) + (form == "packed")
+        futs = [eng.submit(prompt(*range(2, 2 + n)), max_new_tokens=m, temperature=t)
+                for n, m, t in ((5, 8, 0.0), (1, 3, 0.9), (12, 6, 1.4))]  # three of four slots: one inactive
+        eng.start()
+        try:
+            tokens[form] = [f.result(120).tokens.tolist() for f in futs]
+        finally:
+            assert eng.drain(timeout=60)
+        assert eng._iterations == 8 and eng._cache.free_list.conserved()
+    assert [len(t) for t in tokens["packed"]] == [8, 3, 6]
+    assert tokens["packed"] == tokens["five"]
+
+
+def test_the_program_count_holds_through_warm_up_and_twenty_mixed_iterations():
+    eng = make_engine(max_new_tokens=6)
+    set_tiny_params(eng)
+    assert eng.warmup() == len(eng.buckets) + 1
+    rng = np.random.RandomState(1)
+    eng.start()
+    try:
+        while eng._iterations < 20:  # prompts over both buckets and of one token, greedy and sampled, in waves
+            futs = [eng.submit(rng.randint(0, 32, size=int(rng.randint(1, 10))).astype(np.int32),
+                               max_new_tokens=int(rng.randint(1, 7)), temperature=float(rng.choice([0.0, 0.8])))
+                    for _ in range(6)]
+            for f in futs:
+                f.result(60)
+    finally:
+        assert eng.drain(timeout=60)
+    assert eng._iterations >= 20 and eng.compile_count == len(eng.buckets) + 1
+
+
+@pytest.mark.parametrize("case,want", [("empty", True), ("someone_waits", False), ("no_slot", False),
+                                       ("no_page", False), ("static_batch_runs", False),
+                                       ("static_batch_emptied", True)])
+def test_the_scheduler_says_whether_a_submission_made_now_would_be_admitted(case, want):
+    from theanompi_tpu.serve.decode.kvcache import PagedKVCache
+    from theanompi_tpu.serve.decode.scheduler import DecodeScheduler, DecodeSequence
+
+    cache = PagedKVCache(n_layers=1, page_size=4, n_pages=4, max_seqs=2, max_pages_per_seq=2,
+                         k_page=(4, 8), v_page=(4, 8), dtype=np.float32)
+    sched = DecodeScheduler(cache, prefill_buckets=(4,), mode="static" if case.startswith("static") else "continuous")
+    for _ in range({"no_slot": 2, "no_page": 2, "static_batch_runs": 1, "static_batch_emptied": 1}.get(case, 0)):
+        sched.add(DecodeSequence(prompt(1, 2, 3), max_new_tokens=5 if case == "no_page" else 1))
+    sched.admit(0.0)
+    if case == "no_page":  # both slots' worst case took the four pages; one slot is free again
+        assert cache.pages_free == 0
+        sched._free_slots.append(sched.running.popitem()[0])
+    if case == "static_batch_emptied":
+        sched.remove(next(iter(sched.running)), "finished")
+    if case == "someone_waits":
+        sched.add(DecodeSequence(prompt(1), max_new_tokens=1))
+    assert sched.can_admit() is want

@@ -267,12 +267,113 @@ def test_one_host_drain_an_iteration_on_the_new_source():
         source = f.read()
     assert check_decode_source(source) == []
     body = source[source.index("def _iteration"):source.index("def _note_admitted")]
-    # the uploads are jnp.asarray, hoisted out of the decode call's expression
     assert len(re.findall(r"(?<![\w.])np\.asarray\(", body)) == 1
+    # ONE host buffer goes into the decode call as it is: no upload of the step's operands on this thread, neither
+    # in the ``upload`` bracket nor inside the call's expression (the two left are a prefill's, a prompt's own)
+    upload = body[body.index('spans.enter("upload")'):body.index('spans.leave("upload", it)')]
     call = body[body.index("self._decode("):body.index('spans.leave("dispatch", it)')]
-    assert "asarray" not in call
+    assert "asarray" not in upload + call and "device_put" not in upload + call
+    assert "step_arrays(it)" in upload and body.count("jnp.asarray(") == 2
     for name in LOOP_SPANS[1:-1]:
         assert f'spans.enter("{name}")' in body and f'spans.leave("{name}", it)' in body
+    # the ``drain`` bracket holds the one blocking read alone (a ``copy_to_host_async()`` ahead of it was measured
+    # on the chip and taken out again, PERF.md section 6, PR 38; the rule stays clean with one present)
+    drain = body[body.index('spans.enter("drain")'):body.index('spans.leave("drain", it)')].rstrip().splitlines()
+    assert len(drain) == 2 and "np.asarray(nxt)" in drain[1]
+    queued = source.replace(drain[1], drain[1].replace("next_np = np.asarray(nxt)", "nxt.copy_to_host_async()") + "\n" + drain[1])
+    assert "copy_to_host_async" in queued and check_decode_source(queued) == []
+    # the hand-over lies in ``_loop``, inside the ``queue`` bracket and after ``_iteration`` has returned
+    loop = source[source.index("def _loop"):source.index("def _hand_over")]
+    assert loop.index('spans.enter("queue")') < loop.index("self._hand_over(") < loop.index('spans.leave("queue"')
+    assert "_hand_over" not in source[source.index("def _harvest"):source.index("def _fail_all")]
+
+
+class HandOver:
+    """One long request keeps the loop turning while short ones resolve beside
+    it; ``resubmit`` times a client thread sends the next short request when
+    the last one resolved, as the benchmark's closed loop does, ``think``
+    seconds after it heard (several iterations of this engine: without the
+    hand-over's wait the request would find the loop that many further on)."""
+
+    def __init__(self, monkeypatch, bound, resubmit, long=30, think=0.05):
+        from theanompi_tpu.serve.decode import engine as engine_module
+
+        monkeypatch.setattr(engine_module, "HANDOVER_WAIT_S", bound)
+        eng = make_engine(max_new_tokens=long, kv_pages=64)
+        set_tiny_params(eng)
+        eng.warmup()
+        self.seq_ids, self.waits = [], []
+        add, hand_over = eng._sched.add, eng._hand_over
+        eng._sched.add = lambda seq: (self.seq_ids.append(seq.seq_id), add(seq))[1]
+        eng._hand_over = lambda resolved: (self.waits.append(resolved), hand_over(resolved))[1]
+        self.shorts = []
+
+        def client():
+            fut = eng.submit(prompt(5, 6), max_new_tokens=3)
+            for _ in range(resubmit):
+                self.shorts.append(fut.result(60))
+                time.sleep(think)
+                fut = eng.submit(prompt(5, 6), max_new_tokens=3)
+            self.shorts.append(fut.result(60))
+
+        self.long = eng.submit(prompt(1, 2, 3), max_new_tokens=long)
+        eng.start()
+        thread = threading.Thread(target=client)
+        thread.start()
+        try:
+            thread.join(120)
+            assert not thread.is_alive()
+            self.long_tokens = self.long.result(60).tokens
+        finally:
+            assert eng.drain(timeout=60)
+        self.eng, self.stats, self.store = eng, eng.stats(), eng._spans
+
+
+def test_a_resubmission_on_resolution_is_admitted_in_the_very_next_iteration(monkeypatch):
+    # a bound no loaded machine's thread wake-up comes near: the waits end by the submission
+    run = HandOver(monkeypatch, bound=2.0, resubmit=3, long=40)
+    assert len(run.shorts) == 4 and len(run.long_tokens) == 40 and len(run.seq_ids) == 5
+    wait_ring, first_ring = (run.store.span_rings[name] for name in REQUEST_SPANS)
+    short_ids = sorted(run.seq_ids)[1:]  # in the order of their submission
+    for before, after in zip(short_ids, short_ids[1:]):
+        answered = int(first_ring.cause[before % SPAN_RING_STEPS])  # the iteration of its first token of three
+        admitted = int(wait_ring.cause[after % SPAN_RING_STEPS])
+        assert admitted == answered + 3, (before, after)  # resolved in ``answered + 2``: not an iteration lost
+    assert run.stats["tmpi_decode_handover_submitted_total"] == 3.0
+    # the last short request and the long one resolve with nobody to answer: the drain cuts those waits short
+    assert run.stats["tmpi_decode_handover_timed_out_total"] <= 2.0
+    assert validate_record(run.eng.decode_record()) == []
+    assert 'tmpi_decode_handover_total{outcome="submitted"} 3' in run.eng.registry.to_prometheus()
+
+
+def test_with_no_resubmission_the_loop_goes_on_after_the_bound(monkeypatch):
+    run = HandOver(monkeypatch, bound=0.02, resubmit=0)
+    assert len(run.shorts) == 1 and len(run.long_tokens) == 30  # the long request ran on after the short one went
+    assert run.stats["tmpi_decode_handover_submitted_total"] == 0.0
+    assert 1.0 <= run.stats["tmpi_decode_handover_timed_out_total"] <= 2.0
+    # the wait lies inside the ``queue`` bracket of the iteration after the one that resolved
+    resolved_in = int(run.store.span_rings["first_token"].cause[max(run.seq_ids) % SPAN_RING_STEPS]) + 2
+    assert run.store.span("queue", resolved_in + 1)[1] >= 0.02e9
+    assert run.store.span("queue", resolved_in)[1] < 0.02e9
+
+
+def test_with_nothing_resolved_the_loop_never_waits():
+    eng = make_engine(max_new_tokens=30, kv_pages=64)
+    set_tiny_params(eng)
+    eng.warmup()
+    waits, hand_over = [], eng._hand_over
+    eng._hand_over = lambda resolved: (waits.append((eng._iterations, resolved)), hand_over(resolved))[1]
+    fut = eng.submit(prompt(1, 2, 3))
+    eng.start()
+    try:
+        assert len(fut.result(60).tokens) == 30
+    finally:
+        assert eng.drain(timeout=60)
+    # 29 iterations resolved nothing and went straight on; the one hand-over follows the last (and waits, unless
+    # this thread's drain got in first)
+    assert waits == [(30, 1)]
+    stats = eng.stats()
+    assert stats["tmpi_decode_handover_submitted_total"] == 0.0 and stats["tmpi_decode_handover_timed_out_total"] <= 1.0
 
 
 def test_the_ring_keeps_the_cause_and_reads_a_window():

@@ -77,10 +77,7 @@ def _serve(eng, params, prompts, n_new):
                                               c.v_pool, np.int32(seq.slot), np.int32(seq.n_cache))
     logits, tokens = [], []
     for it in range(n_new):
-        tables, seq_lens, last, active, temp = eng._sched.step_arrays()
-        nxt, lg, c.k_pool, c.v_pool = eng._decode(
-            params, c.k_pool, c.v_pool, jnp.asarray(tables), jnp.asarray(seq_lens), jnp.asarray(last),
-            jnp.asarray(active), jnp.asarray(temp), np.int32(it))
+        nxt, lg, c.k_pool, c.v_pool = eng._decode(params, c.k_pool, c.v_pool, eng._sched.step_arrays(it))
         nxt, lg = np.asarray(nxt), np.asarray(lg)
         logits.append(np.stack([lg[s.slot] for s in seqs]))
         tokens.append(np.asarray([nxt[s.slot] for s in seqs]))
@@ -358,11 +355,8 @@ def test_the_decode_program_names_its_parts(scope):
     model = _model(jnp.bfloat16)
     eng = _engine(model)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0])
-    c, S = eng._cache, 4
-    text = eng._decode.lower(
-        params, c.k_pool, c.v_pool, jnp.asarray(c.page_tables), jnp.zeros((S,), jnp.int32),
-        jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool), jnp.zeros((S,), jnp.float32),
-        np.int32(0)).as_text(debug_info=True)
+    c = eng._cache
+    text = eng._decode.lower(params, c.k_pool, c.v_pool, eng._sched.step_arrays(0)).as_text(debug_info=True)
     assert scope in text
 
 

@@ -11,9 +11,11 @@ for autoregression:
 
 1. **Fixed shapes, bounded programs.** The KV pool is ONE preallocated
    device array per layer (``serve/decode/kvcache.py``); page tables
-   and per-slot operand vectors have fixed ``[max_seqs]`` shapes, so
-   the single-token decode step compiles exactly ONCE no matter how
-   sequences come and go. Prompt prefill pads into a small set of
+   and per-slot operand vectors have fixed ``[max_seqs]`` shapes and
+   ride in ONE int32 host buffer (``scheduler.py step_arrays``, cut
+   apart inside the program), so the single-token decode step compiles
+   exactly ONCE no matter how sequences come and go, and takes one
+   transfer an iteration. Prompt prefill pads into a small set of
    length buckets (page-size multiples), one compiled program each,
    AOT-warmed in :meth:`warmup`. Total programs:
    ``len(prefill_buckets) + 1`` — proven by the trace counter
@@ -28,7 +30,15 @@ for autoregression:
    LAST token rides the decode step, so every emitted token exits
    through the one decode program and each iteration has exactly ONE
    host drain point (the ``np.asarray`` on the next-token vector —
-   ``tools/check_hot_loop.py`` HOT004 guards this).
+   ``tools/check_hot_loop.py`` HOT004 guards this; a
+   ``copy_to_host_async()`` at dispatch was measured and left out: the
+   1.3-1.7 ms from the program's end to the loop's wake are the
+   runtime's, PERF.md section 6, PR 38). After an iteration
+   that resolved a request the loop waits a bounded moment for the
+   resolved clients' next submissions BEFORE it reads its queue
+   (``_hand_over``; ``tmpi_decode_handover_total`` counts how the
+   waits ended): a client that resubmits on resolution gets the slot
+   its predecessor freed in the very next iteration.
 
 3. **Swap params between iterations.** Hot reload publishes a new
    :class:`~theanompi_tpu.serve.engine.ServedParams` by atomic
@@ -49,11 +59,13 @@ periodic ``decode`` JSONL records in ``<obs_dir>/decode.jsonl``.
 span class), found after the engine has gone by
 ``span_store("decode")`` (a replica's: ``"decode/<replica_id>"``). The
 loop thread brackets seven spans an iteration, in loop order ``queue``
-(``_loop``'s body between two ``_iteration`` calls: the lock, the wait,
-the queue's hand-over), ``admit``, ``prefill`` (every call of the
+(``_loop``'s body between two ``_iteration`` calls: the lock, the
+hand-over's wait after a resolution, an idle engine's wait, the queue
+read into the scheduler), ``admit``, ``prefill`` (every call of the
 iteration; their count is kept beside it as ``prefill_calls``),
-``upload`` (the decode step's host arrays and their H2D), ``dispatch``
-(the decode call), ``drain`` (the ONE blocking D2H) and ``harvest``,
+``upload`` (the decode step's ONE host buffer filled), ``dispatch``
+(the decode call, which carries the buffer to the device), ``drain``
+(the ONE blocking D2H) and ``harvest``,
 each under the iteration's number (``tmpi_decode_iterations_total`` as
 the iteration begins), on ``time.time_ns()``, the clock of a profiler
 trace; each is a ``TraceAnnotation``. A request gets two spans by its
@@ -108,6 +120,15 @@ DEFAULT_PREFILL_BUCKETS = (16, 64)
 LOOP_SPANS = ("queue", "admit", "prefill", "upload", "dispatch", "drain",
               "harvest")
 REQUEST_SPANS = ("queue_wait", "first_token")
+
+# the hand-over's bound: how long the loop thread, after an iteration that
+# resolved a request, waits for the resolved clients' next submissions
+# before it reads its queue. A client needs 0.15-0.4 ms of interpreter
+# from the resolution to its ``submit`` (PERF.md section 6, PR 37: a
+# request that got in ahead of the read waited 0.163-0.171 ms, one that
+# did not a whole iteration, 10-14 ms); a deployment whose clients answer
+# over a network pays this on the iterations that resolve something
+HANDOVER_WAIT_S = 0.0005
 
 # TPOT (time-per-output-token) lives well below request latency — extend
 # the serve band downward into the sub-millisecond range
@@ -245,6 +266,7 @@ class DecodeEngine:
         # the "len(prefill_buckets) + 1 programs" bound under any
         # request mix (tests/test_decode_engine.py)
         import jax
+        import jax.numpy as jnp
 
         self._trace_count = 0
         seed_const = self._seed
@@ -258,16 +280,20 @@ class DecodeEngine:
                 page_size=self.page_size,
             )
 
-        def _counted_decode(params, k_pool, v_pool, tables, seq_lens,
-                            last, active, temp, it):
+        def _counted_decode(params, k_pool, v_pool, packed):
             self._trace_count += 1  # trace-time only, never per call
+            # ONE operand for the step's host arrays (the scheduler's
+            # ``step_arrays``), cut apart here: a few static slices
+            tables, seq_lens, last, active, temp, it = \
+                self._sched.split_step(packed)
             # the sampling key is derived INSIDE the program from the
             # traced iteration counter — deterministic replay, no
             # per-iteration retrace, no host-side key threading
             key = jax.random.fold_in(jax.random.PRNGKey(seed_const), it)
             return model.decode_step(
-                params, k_pool, v_pool, tables, seq_lens, last, active,
-                temp, key, page_size=self.page_size,
+                params, k_pool, v_pool, tables, seq_lens, last, active != 0,
+                jax.lax.bitcast_convert_type(temp, jnp.float32), key,
+                page_size=self.page_size,
             )
 
         self._prefill = jax.jit(
@@ -382,6 +408,11 @@ class DecodeEngine:
             "tmpi_decode_reloads_total",
             help="checkpoint hot-reloads applied (serve/reload.py)",
         )
+        self._c_handover = self.registry.counter(
+            "tmpi_decode_handover_total",
+            help="waits for a resolved client's next submission before the "
+                 "queue was read, by how they ended (outcome=submitted|timed_out)",
+        )
 
     # -- params (surface shared with ServeEngine; router/reloader use it) ---
     @property
@@ -482,13 +513,8 @@ class DecodeEngine:
             jax.block_until_ready(out)  # compile now, discard scratch writes
             if self._donate:  # the pools went into the call: these are they
                 c.k_pool, c.v_pool = out
-        S = c.max_seqs
         nxt, _lg, _k, _v = self._decode(
-            served.params, c.k_pool, c.v_pool,
-            jnp.asarray(c.page_tables), jnp.zeros((S,), jnp.int32),
-            jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool),
-            jnp.zeros((S,), jnp.float32), np.int32(0),
-        )
+            served.params, c.k_pool, c.v_pool, self._sched.step_arrays(0))
         np.asarray(nxt)
         if self._donate:
             c.k_pool, c.v_pool = _k, _v
@@ -710,24 +736,30 @@ class DecodeEngine:
 
     # -- decode loop --------------------------------------------------------
     def _loop(self) -> None:
-        spans = self._spans
+        spans, sched = self._spans, self._sched
+        resolved = 0  # sequences the iteration that just ended took out
         while True:
-            # an idle engine's waits land in this span and nowhere else
+            # an idle engine's waits land in this span and nowhere else,
+            # and the hand-over's
             spans.enter("queue")
             with self._cond:
-                while (not self._q and not self._sched.has_work()
+                if resolved:
+                    self._hand_over(resolved)
+                while (not self._q and not sched.has_work()
                        and not self._draining):
                     self._cond.wait(0.05)
                 if (self._draining and not self._q
-                        and not self._sched.has_work()):
+                        and not sched.has_work()):
                     spans.leave("queue")
                     return
                 while self._q:
-                    self._sched.add(self._q.popleft())
-                self._g_queue.set(self._sched.n_waiting)
+                    sched.add(self._q.popleft())
+                self._g_queue.set(sched.n_waiting)
             spans.leave("queue", self._iterations)
+            before = sched.removed_total
             try:
                 self._iteration()
+                resolved = sched.removed_total - before
             except BaseException as e:  # noqa: BLE001 — generations must
                 # never hang on an engine bug: fail everything this loop
                 # owns (releasing its KV pages) and keep the thread
@@ -735,12 +767,42 @@ class DecodeEngine:
                 # those count as failed, not rejected
                 spans.abandon()
                 self._fail_all(e)
+                resolved = 0
+
+    def _hand_over(self, resolved: int) -> None:
+        """The hand-over before the queue is read (``self._cond`` held):
+        the iteration that just returned resolved ``resolved`` requests,
+        and their clients hear of it only now (a future's waiter, or
+        whoever wraps the harvest, runs after ``future._resolve``). A
+        client that submits again on a resolution needs a few tenths of
+        a millisecond of interpreter, which this thread does not let go
+        of on its own before it has emptied its queue: the resubmission
+        then waits a whole iteration for the slot its predecessor freed.
+        So where a submission made now would be admitted at once, wait
+        for as many as were resolved, ``HANDOVER_WAIT_S`` at most;
+        ``submit()`` notifies. Nothing resolved, a queue that already
+        holds a request, a draining engine or no room: no wait."""
+        if self._q or self._draining or not self._sched.can_admit():
+            return
+        deadline = time.monotonic() + HANDOVER_WAIT_S
+        while len(self._q) < resolved and not self._draining:
+            left = deadline - time.monotonic()
+            if left <= 0:
+                break
+            self._cond.wait(left)
+        self._c_handover.inc(
+            outcome="submitted" if self._q else "timed_out")
 
     def _iteration(self) -> None:
-        """One continuous-batching iteration: admit, prefill admitted
-        prompts, run the single decode step, harvest tokens. Exactly
-        ONE host drain point (the np.asarray on the next-token vector)
-        — tools/check_hot_loop.py HOT004 walks this function."""
+        """One continuous-batching iteration, in this order: admit,
+        prefill the admitted prompts, fill the step's ONE host buffer
+        (``upload``), run the single decode step on it (``dispatch``:
+        the call takes the host array as it is, one transfer), block on
+        the next-token vector (``drain``), harvest tokens. Exactly ONE host drain point (the
+        np.asarray on the next-token vector) — tools/check_hot_loop.py
+        HOT004 walks this function. The hand-over to clients that
+        resubmit on a resolution comes after this has returned, in
+        ``_loop``."""
         import jax.numpy as jnp
 
         err = self._abort_error
@@ -781,25 +843,18 @@ class DecodeEngine:
         if not self._sched.running:
             return
         spans.enter("upload")
-        tables, seq_lens, last, active, temp = self._sched.step_arrays()
+        # ONE host buffer for the step's operands, handed to the call as it
+        # is: the jitted call's own argument path makes the one transfer
+        packed = self._sched.step_arrays(it)
         if self._visible_share is not None:
-            self._g_visible.set(self._visible_share(seq_lens[active]))
-        step_inputs = (
-            jnp.asarray(tables), jnp.asarray(seq_lens), jnp.asarray(last),
-            jnp.asarray(active), jnp.asarray(temp), np.int32(it),
-        )
+            _, seq_lens, _, active, _, _ = self._sched.split_step(packed)
+            self._g_visible.set(self._visible_share(seq_lens[active != 0]))
         spans.leave("upload", it)
         spans.enter("dispatch")
         nxt, _logits, c.k_pool, c.v_pool = self._decode(
-            served.params, c.k_pool, c.v_pool, *step_inputs)
+            served.params, c.k_pool, c.v_pool, packed)
         spans.leave("dispatch", it)
         spans.enter("drain")
-        # the uploads die here, under the running step, as the call's own
-        # temporaries did: their release lets other threads run, and left
-        # until the iteration's end it let a client's resubmission in
-        # ahead of the next hand-over (ISSUE 37, measured on the chip:
-        # ttft_p50_ms 29.9 -> 18.3 in lm136m-decode-closed; ROADMAP S9c)
-        del step_inputs
         next_np = np.asarray(nxt)  # the ONE host drain per iteration
         spans.leave("drain", it)
         t_done = time.monotonic()
@@ -940,6 +995,10 @@ class DecodeEngine:
             "tmpi_decode_reloads_total": self._c_reloads.value(),
             "tmpi_decode_reload_failures_total":
                 self._c_reloads.value(status="failed"),
+            "tmpi_decode_handover_submitted_total":
+                self._c_handover.value(outcome="submitted"),
+            "tmpi_decode_handover_timed_out_total":
+                self._c_handover.value(outcome="timed_out"),
         }
         for kind, n in self._cache.pool_bytes_by_kind.items():
             if kind != self.cache_kind:  # held a slot, beside the pages

@@ -125,6 +125,9 @@ class DecodeScheduler:
         self.waiting: Deque[DecodeSequence] = deque()
         self.running: Dict[int, DecodeSequence] = {}
         self._free_slots = list(range(cache.max_seqs - 1, -1, -1))
+        # the decode step's operands, packed (``split_step`` names the parts)
+        self._step = np.zeros(
+            (cache.max_seqs * (cache.max_pages_per_seq + 4) + 1,), np.int32)
         self.admitted_total = 0
         self.finished_total = 0
         self.evicted_total = 0
@@ -148,6 +151,11 @@ class DecodeScheduler:
 
     def has_work(self) -> bool:
         return bool(self.waiting or self.running)
+
+    @property
+    def removed_total(self) -> int:
+        """Sequences taken out of the running set so far, either way."""
+        return self.finished_total + self.evicted_total
 
     @property
     def occupancy(self) -> float:
@@ -238,17 +246,40 @@ class DecodeScheduler:
         pages[:npg] = self.cache.page_tables[seq.slot, :npg]
         return bucket, toks, pages
 
-    def step_arrays(self):
-        """Fixed-shape operands for the decode program: ``(page_tables,
-        seq_lens, last_tokens, active, temperature)``."""
-        S = self.cache.max_seqs
-        seq_lens = np.zeros((S,), np.int32)
-        last = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        temp = np.zeros((S,), np.float32)
+    def can_admit(self) -> bool:
+        """Whether a request submitted NOW would get a slot at the next
+        admission pass: nobody waits ahead of it, a slot and pages are
+        free and, in ``mode="static"``, the batch has emptied."""
+        if self.waiting or not self._free_slots or not self.cache.pages_free:
+            return False
+        return self.mode != "static" or not self.running
+
+    def step_arrays(self, it: int) -> np.ndarray:
+        """The decode program's fixed-shape operands as ONE int32 buffer,
+        kept here and written in place (the loop is serial with the
+        device: the program that read the last iteration's has ended):
+        ``split_step`` names its parts."""
+        tables, seq_lens, last, active, temp, counter = self.split_step(
+            self._step)
+        tables[:] = self.cache.page_tables
+        self._step[tables.size:] = 0
+        temp = temp.view(np.float32)
         for slot, seq in self.running.items():
             seq_lens[slot] = seq.pos
             last[slot] = seq.last_token
-            active[slot] = True
+            active[slot] = 1
             temp[slot] = seq.temperature
-        return self.cache.page_tables, seq_lens, last, active, temp
+        counter[...] = it
+        return self._step
+
+    def split_step(self, packed):
+        """The packed buffer of one decode step cut into ``(page_tables
+        [S, P], seq_lens [S], last_tokens [S], active [S], temperature
+        [S], iteration [])``, all int32 as packed: ``active`` is 0 or 1
+        and the temperature its float32's bits. Static slices, so the
+        same cut serves the host's buffer (views, written through) and
+        the traced operand of the decode program."""
+        S, P = self.cache.max_seqs, self.cache.max_pages_per_seq
+        rows = [packed[S * P + k * S:S * P + (k + 1) * S] for k in range(4)]
+        return (packed[:S * P].reshape(S, P), *rows,
+                packed[S * (P + 4):].reshape(()))

@@ -542,6 +542,13 @@ SERVE_METRIC_PREFIX = "tmpi_serve_"
 #   tmpi_decode_tokens_total    counter    tokens sampled and returned
 #   tmpi_decode_prefills_total  counter    by bucket=N
 #   tmpi_decode_reloads_total   counter    hot-reloads applied
+#   tmpi_decode_handover_total  counter    by outcome=submitted|timed_out:
+#                                          the loop's bounded waits, after
+#                                          an iteration that resolved a
+#                                          request, for the client's next
+#                                          submission before the queue is
+#                                          read (record:
+#                                          ..._handover_<outcome>_total)
 DECODE_METRIC_PREFIX = "tmpi_decode_"
 
 # the router metric name family (serve/router.py; kind=router snapshot
