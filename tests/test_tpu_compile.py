@@ -493,3 +493,76 @@ def test_minicpm_sala_prefill_of_16384_positions_holds_no_square_of_the_prompt(
     assert mem.alias_size_in_bytes >= 774_569_984 and mem.temp_size_in_bytes < 2.5e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
     assert "8192,8192" not in compiled_at(8192).as_text()
+
+
+def _evabyte_decode(one_chip, monkeypatch):
+    """The cell ``evabyte-decode-bytes24k`` as shapes on the described chip:
+    the stage of 4 layers at the published widths and its engine's two kinds
+    of state as ``PagedKVCache`` shapes them (24 slots of 14 pages of one
+    window block, 128 compressed rows a page; a ring of 2,048 rows a slot)."""
+    from theanompi_tpu.models.evabyte import EvaByte_Stage4
+    from theanompi_tpu.ops import pallas_eva as pe
+
+    _mosaic(monkeypatch, pe)
+    model, page, pages, S, M = EvaByte_Stage4(), 2048, 336, 24, 14
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    spec = model.cache_spec(page)
+    k_pool = on_chip((model.arch.n_layers, pages + 1, *spec["k_page"]), spec["dtype"])
+    ring = spec["slots"]["ring"]
+    held = {"v": on_chip(k_pool.shape, spec["dtype"]),
+            "ring": on_chip((ring["layers"], S, *ring["row"]), ring["dtype"])}
+    assert k_pool.shape == (4, 337, 128, 4096) and held["ring"].shape == (4, 24, 2, 2048, 4096)
+    return model, page, params, k_pool, held, on_chip, (S, M)
+
+
+def test_evabyte_decode_step_reads_its_rows_and_updates_every_pool_in_place(
+        one_chip, no_persistent_cache, monkeypatch):
+    """24 slots over 2.83 GB of compressed pages and 3.22 GB of ring (4
+    layers): four ``eva_decode`` calls, one ``eva_cache_write``; everything
+    donated aliases its output, NO pool is copied and no slot's whole ring or
+    page list is gathered (``[24, 2048, 4096]`` would be 0.4 GB a layer)."""
+    model, page, params, k_pool, held, on_chip, (S, M) = _evabyte_decode(one_chip, monkeypatch)
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_pool, held, on_chip((S, M), jnp.int32), on_chip((S,), jnp.int32), on_chip((S,), jnp.int32),
+        on_chip((S,), jnp.bool_), on_chip((S,), jnp.float32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 4 + 1
+    assert "eva_decode" in text and "eva_cache_write" in text
+    assert _held_bytes(k_pool, held) == 6_048_186_368 and mem.alias_size_in_bytes >= 6_048_186_368
+    assert mem.temp_size_in_bytes < 0.1e9  # a copy of a layer's ring would be 0.8 GB, of the pages 0.7
+    assert not re.search(rf"\[{S},2048,4096\]", text) and not re.search(rf"\[{S},{M * 128},4096\]", text)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_evabyte_prefill_of_24576_positions_holds_no_square_of_the_prompt(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One bucket of 24,576 rows for its slot at a traced real length: three
+    ``eva_prefill`` flash passes (the last layer's attention feeds nothing a
+    prefill keeps, and the compiler drops it), whole pages of compressed rows and the
+    slot's ring written to the donated pools; 3.1 GB of temporaries beside
+    1.64 GB of weights and 6.05 GB of pools fit the chip, and nothing is shaped
+    ``[.., T, T]`` or ``[.., T, T / 16]``."""
+    model, page, params, k_pool, held, on_chip, _ = _evabyte_decode(one_chip, monkeypatch)
+
+    def prefill(p, t, pg, k, v, slot, n_real):
+        return model.decode_prefill(p, t, pg, k, v, slot, n_real, page_size=page)
+
+    T = 24576
+    compiled = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, on_chip((T,), jnp.int32), on_chip((T // page,), jnp.int32), k_pool, held,
+        on_chip((), jnp.int32), on_chip((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 3 and "eva_prefill" in text
+    # 3.09 GB: the SwiGLU's [24576, 11008] intermediates lead; a copy of a pool would add 2.8 or 3.2 GB
+    assert mem.alias_size_in_bytes >= 6_048_186_368 and mem.temp_size_in_bytes < 3.5e9
+    assert f"{T},{T}" not in text and f"{T},{T // 16}" not in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES

@@ -33,6 +33,8 @@ MODEL_REGISTRY = {
     "mistral_small_4_ep8": ("theanompi_tpu.models.mistral4", "MistralSmall4_EP8"),
     "minicpm_sala_lm": ("theanompi_tpu.models.minicpm_sala", "MiniCPMSALA"),
     "minicpm_sala_stage8": ("theanompi_tpu.models.minicpm_sala", "MiniCPM_SALA_Stage8"),
+    "evabyte_lm": ("theanompi_tpu.models.evabyte", "EvaByte"),
+    "evabyte_stage4": ("theanompi_tpu.models.evabyte", "EvaByte_Stage4"),
 }
 
 

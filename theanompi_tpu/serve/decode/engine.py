@@ -51,8 +51,10 @@ for autoregression:
 
 Telemetry is ``tmpi_decode_*``-prefixed (schema: ``kind=decode`` in
 tools/check_obs_schema.py): TTFT/TPOT/queue-wait histograms, tokens/sec,
-kv page occupancy, batch occupancy, eviction/expiry counters, plus
-periodic ``decode`` JSONL records in ``<obs_dir>/decode.jsonl``.
+kv page occupancy, batch occupancy, eviction/expiry counters, the rows a
+model's query reads by kind where the model counts them (``cache_rows``:
+EVA's window positions and compressed rows), plus periodic ``decode``
+JSONL records in ``<obs_dir>/decode.jsonl``.
 
 **The loop measured from inside** (ISSUE 37). The engine owns one
 :class:`~theanompi_tpu.utils.recorder.SpanStore` (the Recorder's own
@@ -388,6 +390,19 @@ class DecodeEngine:
                 help="share of their context the sparse layers' queries of "
                      "the last iteration saw",
             )
+        # a model whose query reads rows of several kinds says how many of
+        # each (host side, from the running lengths): a gauge each, the mean
+        # over the running slots of the last iteration, and the sum kept an
+        # iteration in the span store, where a reader finds it by number
+        self._cache_rows = getattr(model, "cache_rows", None)
+        self._g_rows = {}
+        if self._cache_rows is not None:
+            for name in self._cache_rows(np.zeros(0, np.int64)):
+                self._g_rows[name] = self.registry.gauge(
+                    f"tmpi_decode_{name}",
+                    help=f"{name.replace('_', ' ')} a query of the last "
+                         "iteration read, mean over the running slots",
+                )
         self._c_requests = self.registry.counter(
             "tmpi_decode_requests_total",
             help="generations by outcome "
@@ -846,9 +861,9 @@ class DecodeEngine:
         # ONE host buffer for the step's operands, handed to the call as it
         # is: the jitted call's own argument path makes the one transfer
         packed = self._sched.step_arrays(it)
-        if self._visible_share is not None:
+        if self._visible_share is not None or self._g_rows:
             _, seq_lens, _, active, _, _ = self._sched.split_step(packed)
-            self._g_visible.set(self._visible_share(seq_lens[active != 0]))
+            self._note_context(seq_lens[active != 0], it)
         spans.leave("upload", it)
         spans.enter("dispatch")
         nxt, _logits, c.k_pool, c.v_pool = self._decode(
@@ -862,6 +877,16 @@ class DecodeEngine:
         if err is not None:  # abort landed mid-step: nothing resolves
             raise err        # after a death
         self._harvest(next_np, served.step, t_done, t0)
+
+    def _note_context(self, running: np.ndarray, it: int) -> None:
+        """The running slots' lengths (host side) as the model reads them:
+        the visible share's gauge, and a gauge a kind of row with that
+        kind's sum kept under iteration ``it``."""
+        if self._visible_share is not None:
+            self._g_visible.set(self._visible_share(running))
+        for name, rows in (self._cache_rows(running) if self._g_rows else {}).items():
+            self._g_rows[name].set(float(np.mean(rows)))
+            self._spans.count(name, it, int(np.sum(rows)))
 
     def _note_admitted(self, seq: DecodeSequence, it: int) -> None:
         """A request got its slot in iteration ``it``: its ``queue_wait``
@@ -1005,6 +1030,8 @@ class DecodeEngine:
                 out[f"tmpi_decode_{kind}_bytes"] = float(n)
         if self._visible_share is not None:
             out["tmpi_decode_visible_context_share"] = self._g_visible.value()
+        for name, g in self._g_rows.items():
+            out[f"tmpi_decode_{name}"] = g.value()
         tps = self.tokens_per_sec()
         if tps is not None:
             out["tmpi_decode_tokens_per_sec"] = tps

@@ -409,8 +409,9 @@ SCHEMAS: dict[str, dict[str, tuple[tuple, bool]]] = {
         "metrics": ((dict,), True),
         "replica_id": ((int,), False),
         # what the paged pools hold (Model.cache_spec): "kv" per-head
-        # keys and values, "latent" one latent and one rotated row (what
-        # a model holds a slot beside them is in the metrics, by kind)
+        # keys and values, "latent" one latent and one rotated row,
+        # "compressed" EVA's compressed rows (what a model holds a slot
+        # beside them is in the metrics, by kind)
         "cache_kind": ((str,), False),
     },
     # replica-group router (serve/router.py): one record per routing
@@ -531,12 +532,19 @@ SERVE_METRIC_PREFIX = "tmpi_serve_"
 #   tmpi_decode_batch_occupancy gauge      running seqs / max_seqs
 #   tmpi_decode_kv_pages_used   gauge      KV pool pages outstanding
 #   tmpi_decode_kv_pages_free   gauge      KV pool pages in free list
-#   tmpi_decode_kv_pool_bytes   gauge      by kind=kv|latent (the paged
-#                                          pools) |compressed|state (held
-#                                          a slot)
+#   tmpi_decode_kv_pool_bytes   gauge      by kind=kv|latent|compressed
+#                                          (the paged pools; EVA's pages
+#                                          hold compressed rows) |compressed|
+#                                          state|ring (held a slot)
 #   tmpi_decode_kv_bytes_per_position gauge  by kind, one more position
+#                                          (a ring or a state: none)
 #   tmpi_decode_visible_context_share gauge  share of their context the
-#                                          sparse layers' queries saw
+#                                          sparse or EVA layers' queries saw
+#   tmpi_decode_eva_window_positions gauge  EVA: exact keys a query read
+#                                          (mean over running slots; the
+#                                          sum an iteration in the span
+#                                          store's counts, by that name)
+#   tmpi_decode_eva_compressed_rows gauge  EVA: compressed rows it read
 #   tmpi_decode_requests_total  counter    by status=served|expired|
 #                                          evicted|rejected|failed
 #   tmpi_decode_tokens_total    counter    tokens sampled and returned
