@@ -14,7 +14,9 @@ checkout (span means, ``keys_ready_share``, ``dispatch_ahead_share``), and
 ``spans1`` the same at ``--dispatch-depth 1`` (a control on one commit; only
 for a checkout whose wrapper knows the option: PR 31 on); ``loop`` runs it
 through ``experiments/bench_loop_spans.py`` (the serving loop's six span
-metrics laid over the manifest: PR 37). Each run is the
+metrics laid over the manifest: PR 37); ``chunk`` through
+``experiments/bench_readers.py`` with ``lightning_chunk_roofline.decode`` laid
+over it (PR 40; lay this tree's ``benchmark/`` over the parent first). Each run is the
 benchmark's own command in a process of its own, from its checkout's root; this process never
 touches JAX, so the chip is the child's. Per run: the whole output under
 ``--out`` (a traced run: also its ``breakdown`` and its ``*.xplane.pb``) and,
@@ -38,7 +40,8 @@ TELL = ("[bench] between two runs", "[spans]", "[bench] longest step brackets", 
 VIA = {"": ["benchmark/run.py"],
        "spans": ["experiments/bench_spans.py"],
        "spans1": ["experiments/bench_spans.py", "--dispatch-depth", "1"],
-       "loop": ["experiments/bench_loop_spans.py"]}
+       "loop": ["experiments/bench_loop_spans.py"],
+       "chunk": ["experiments/bench_readers.py", "--readers", "lightning_chunk_roofline.decode"]}
 
 
 def one(spec, roots, out, seconds, tiny):

@@ -3,8 +3,9 @@ lightning linear attention) at a small size on the CPU, against the
 benchmark's plain reference (``benchmark/reference/minicpm-sala-decode.py``) on
 seeded random weights: the full forward, prefill then decode through the
 ENGINE's two programs across the context where selection starts, the chunked
-scan against the recurrence, each kernel against its ``jnp`` twin, the three
-kinds of state in one cache manager, and the other models' programs unchanged."""
+scan (kernel and twin) against the recurrence, each kernel against its ``jnp``
+twin, the three kinds of state in one cache manager, and the other models'
+programs unchanged."""
 
 import json
 import os
@@ -18,7 +19,7 @@ from benchmark_checks import names_of  # noqa: F401 - sets the benchmark's impor
 from harness import manifest
 from theanompi_tpu.models import get_model
 from theanompi_tpu.models.minicpm_sala import (
-    LIGHTNING, SPARSE, MiniCPM_SALA_Stage8, MiniCPMSALA, decay_rates)
+    LIGHTNING, SPARSE, MiniCPM_SALA_Stage8, MiniCPMSALA, _rotate, _turn, decay_rates)
 from theanompi_tpu.ops import pallas_lightning as pl_lightning
 from theanompi_tpu.ops import pallas_sparse as pl_sparse
 from theanompi_tpu.serve.decode.engine import DecodeEngine
@@ -147,20 +148,41 @@ def test_the_programs_with_the_kernels_equal_the_programs_with_their_twins():
         return pl_sparse.sparse_prefill_reference(q, k, v, mask, scale=scale, block=block)
 
     with jax.default_matmul_precision("highest"):
-        a = model._prompt(params, tokens, 96)[0]
-        b = model._prompt(params, tokens, 96, attend=twin)[0]
+        a, _, states = model._prompt(params, tokens, 77)
+        b, _, twin_states = model._prompt(params, tokens, 77, attend=twin, scan=pl_lightning.lightning_chunk_reference)
     assert np.abs(np.asarray(a - b)).max() < 1e-5 * np.abs(np.asarray(b)).max()
+    for s, t in zip(states, twin_states):  # the states at the real length
+        assert np.abs(np.asarray(s - t)).max() < 1e-5 * np.abs(np.asarray(t)).max()
 
 
+@pytest.mark.parametrize("scan", ["lightning_chunk", "lightning_chunk_reference"])
 @pytest.mark.parametrize("n_real", [96, 77, 33, 16, 1])
-def test_the_chunked_scan_is_the_recurrence_at_a_real_length_that_is_no_multiple_of_the_chunk(n_real):
+def test_the_chunked_scan_is_the_recurrence_at_a_real_length_that_is_no_multiple_of_the_chunk(n_real, scan):
     T, H, D = 96, 4, 16
     q, k, v = (jax.random.normal(key, (T, H, D)) for key in jax.random.split(jax.random.PRNGKey(2), 3))
+    still = (jnp.ones((T, D // 2)), jnp.zeros((T, D // 2)))  # a rotation by nought: q and k as they are
     with jax.default_matmul_precision("highest"):
-        o, last = pl_lightning.lightning_chunked(q, k, v, decay_rates(H), jnp.int32(n_real), 16)
+        o, last = getattr(pl_lightning, scan)(q, k, v, decay_rates(H), jnp.int32(n_real), 16, still)
         o_ref, last_ref = pl_lightning.lightning_reference(q, k, v, decay_rates(H), n_real)
     assert np.abs(np.asarray(o - o_ref))[:n_real].max() < 1e-4  # the padded rows are never read
     assert np.abs(np.asarray(last - last_ref)).max() < 1e-4  # the state at the REAL length
+
+
+@pytest.mark.parametrize("scan", ["lightning_chunk", "lightning_chunk_reference"])
+def test_the_scan_rotates_q_and_k_as_the_decode_step_does(scan):
+    """A scan takes q and k unrotated in fp32 and rotates and rounds them
+    itself: as ``_rotate`` then the compute dtype do, outside."""
+    T, H, D, n_real = 64, 4, 16, 45
+    q, k, v = (jax.random.normal(key, (T, H, D)) for key in jax.random.split(jax.random.PRNGKey(6), 3))
+    pos, v = jnp.arange(T), v.astype(jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        o, last = getattr(pl_lightning, scan)(q, k, v, decay_rates(H), jnp.int32(n_real), 16,
+                                              turn=_turn(pos, D, 10000.0))
+        o_ref, last_ref = pl_lightning.lightning_reference(
+            *(_rotate(x, pos, 10000.0).astype(jnp.bfloat16) for x in (q, k)), v, decay_rates(H), n_real)
+    o, o_ref = o[:n_real], o_ref[:n_real]
+    assert np.abs(np.asarray(o - o_ref)).max() < 1e-5 * np.abs(np.asarray(o_ref)).max()
+    assert np.abs(np.asarray(last - last_ref)).max() < 1e-5 * np.abs(np.asarray(last_ref)).max()
 
 
 def test_the_references_chunks_are_its_recurrence_one_position_at_a_time():
@@ -370,6 +392,23 @@ def test_the_prefill_program_names_its_parts_and_holds_no_square_of_the_prompt(s
                                  c.k_pool, c.v_pool, np.int32(0), np.int32(0))
     assert scope in lowered.as_text(debug_info=True)
     assert f"x{T}x{T}x" not in lowered.as_text() and f"<{T}x{T}x" not in lowered.as_text()
+
+
+def test_the_prefill_program_holds_no_state_a_chunk():
+    """The scan is one kernel a lightning layer: no ``[N, H, D, D]`` float32
+    (a state a chunk and head) is made outside it, where the ``jnp`` form
+    makes two a layer."""
+    model = _model(jnp.bfloat16)
+    r, T = model.recipe, 96
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0])
+    square = f"{T // r.chunk}x{r.n_heads}x{r.head_dim}x{r.head_dim}xf32"
+
+    def text(scan):
+        return jax.jit(lambda p, t: model._prompt(p, t, 77, scan=scan)).lower(
+            params, jnp.zeros((T,), jnp.int32)).as_text()
+
+    assert square not in text(pl_lightning.lightning_chunk)
+    assert square in text(pl_lightning.lightning_chunk_reference)  # the test sees what it looks for
 
 
 def _other_engine(name):

@@ -472,11 +472,12 @@ def test_minicpm_sala_decode_step_walks_chosen_pages_and_updates_every_pool_in_p
 def test_minicpm_sala_prefill_of_16384_positions_holds_no_square_of_the_prompt(
         one_chip, no_persistent_cache, monkeypatch):
     """One bucket of 16,384 rows for its slot at a traced real length: two
-    ``sparse_prefill`` flash passes under a per-row block mask, six chunked
-    scans, whole pages and the slot's rows written to the donated pools;
-    1.7 GB of temporaries beside 5.64 GB of weights. Nothing is shaped
-    ``[.., T, T]``: shown on a bucket of 8,192, because at 16,384 the
-    SwiGLU's ``[T, 16384]`` is a square by accident."""
+    ``sparse_prefill`` flash passes under a per-row block mask, six
+    ``lightning_chunk`` scans, whole pages and the slot's rows written to the
+    donated pools; 1.58 GB of temporaries beside 5.64 GB of weights (1.72
+    with the ``jnp`` scans, whose ``[128, 32, 128, 128]`` fp32 arrays are
+    gone, as are the rotation's halves). Nothing is shaped ``[.., T, T]``: shown on a bucket of 8,192,
+    because at 16,384 the SwiGLU's ``[T, 16384]`` is a square by accident."""
     model, page, params, k_pool, held, on_chip, _ = _minicpm_sala_decode(one_chip, monkeypatch)
 
     def prefill(p, t, pg, k, v, slot, n_real):
@@ -489,10 +490,35 @@ def test_minicpm_sala_prefill_of_16384_positions_holds_no_square_of_the_prompt(
 
     compiled = compiled_at(16384)
     mem = compiled.memory_analysis()
-    assert _kernels(compiled) == 2 and "sparse_prefill" in compiled.as_text()
-    assert mem.alias_size_in_bytes >= 774_569_984 and mem.temp_size_in_bytes < 2.5e9
+    assert _kernels(compiled) == 2 + 6
+    assert all(name in compiled.as_text() for name in ("sparse_prefill", "lightning_chunk"))
+    assert mem.alias_size_in_bytes >= 774_569_984 and mem.temp_size_in_bytes < 1.65e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
     assert "8192,8192" not in compiled_at(8192).as_text()
+
+
+def test_lightning_chunk_at_the_minicpm_sala_width(one_chip, no_persistent_cache, monkeypatch):
+    """The prompt's scan alone at the cell's width (16,384 rows, 32 heads of
+    128, chunks of 128), as the model calls it (q and k unrotated in fp32 and
+    rotated inside by a lane roll, v in bfloat16): Mosaic's tile and VMEM
+    rules for blocks of ``[128, 8 x 128]``, the fp32 products at ``HIGHEST``
+    and the states held across the chunks as the output block."""
+    from theanompi_tpu.models.minicpm_sala import _turn, decay_rates
+    from theanompi_tpu.ops import pallas_lightning as plg
+
+    _mosaic(monkeypatch, plg)
+    T, H, D = 16384, 32, 128
+    qk = jax.ShapeDtypeStruct((T, H, D), jnp.float32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((T, H, D), jnp.bfloat16, sharding=one_chip)
+    n_real = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def scan(q, k, v, n_real):
+        return plg.lightning_chunk(q, k, v, decay_rates(H), n_real, 128, _turn(jnp.arange(T), D, 10000.0))
+
+    compiled = jax.jit(scan).lower(qk, qk, v, n_real).compile()
+    assert _kernels(compiled) == 1 and "lightning_chunk" in compiled.as_text()
+    o, state = jax.eval_shape(scan, qk, qk, v, n_real)
+    assert o.shape == (T, H, D) and state.shape == (H, D, D) and state.dtype == o.dtype == jnp.float32
 
 
 def _evabyte_decode(one_chip, monkeypatch):

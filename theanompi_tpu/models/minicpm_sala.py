@@ -17,7 +17,7 @@ A layer's ``mixer`` is one of two kinds (``recipe.mixer_types``):
   state ``S [D, D]`` a head in fp32, ``S_t = l_h S_(t-1) + k_t^T v_t``,
   ``o_t = D^-0.5 q_t S_t`` with ``l_h = exp(-2^(-8 (h + 1) / H))``; then an
   RMS norm over all ``H D`` with a gain, a sigmoid gate ``h W_gate``, ``W_o``.
-  A prompt runs the chunked form, a decode step the one-position kernel
+  A prompt runs the chunked kernel, a decode step the one-position kernel
   (``ops/pallas_lightning.py``).
 - ``minicpm4``: ``H`` query heads over ``G`` K/V heads, ``q, k`` RMS-normed,
   NO rotary. The query at position ``t`` sees BLOCKS of ``block`` keys: the
@@ -59,7 +59,7 @@ import numpy as np
 from theanompi_tpu.models.contract import Model, Recipe
 from theanompi_tpu.models.mistral4 import Arch
 from theanompi_tpu.models.transformer import _rms, next_token_loss, softmax_nll
-from theanompi_tpu.ops.pallas_lightning import lightning_chunked, lightning_step
+from theanompi_tpu.ops.pallas_lightning import lightning_chunk, lightning_step
 from theanompi_tpu.ops.pallas_sparse import (
     sparse_cache_write, sparse_decode, sparse_prefill, tile_lists)
 
@@ -104,13 +104,20 @@ def decay_rates(n_heads: int):
     return jnp.asarray(2.0 ** (-8.0 * (np.arange(n_heads) + 1.0) / n_heads), jnp.float32)
 
 
+def _turn(positions, head_dim: int, theta: float):
+    """``(cos, sin) [..., T, D / 2]`` of ``positions [..., T]``: frequencies
+    from float64, angles in fp32."""
+    half = head_dim // 2
+    freq = jnp.asarray(theta ** (-np.arange(half, dtype=np.float64) / half), jnp.float32)
+    ang = positions.astype(jnp.float32)[..., None] * freq
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def _rotate(x, positions, theta: float):
     """``[..., T, H, D]`` in fp32 rotated by ``positions [..., T]``, pairs
-    ``(j, j + D/2)``; frequencies from float64, angles in fp32."""
+    ``(j, j + D/2)``."""
     half = x.shape[-1] // 2
-    freq = jnp.asarray(theta ** (-np.arange(half, dtype=np.float64) / half), jnp.float32)
-    ang = positions.astype(jnp.float32)[..., None, None] * freq
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    cos, sin = (t[..., None, :] for t in _turn(positions, x.shape[-1], theta))
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
@@ -331,19 +338,17 @@ class MiniCPMSALA(Model):
             o = jnp.transpose(o, (2, 0, 1, 3)).reshape(T, -1)
         return self._out(p, o.astype(jnp.float32) * gate), k.reshape(T, G * D), v.reshape(T, G * D), ck
 
-    def _lightning_prompt(self, p, h, n_real):
+    def _lightning_prompt(self, p, h, n_real, scan=lightning_chunk):
         """One prompt's ``lightning-attn`` layer: ``h [T, d]`` -> (output
         ``[T, d]``, the state ``[H, D, D]`` after position ``n_real - 1``)."""
         r = self.recipe
-        dt = r.compute_dtype
         q, k, v, gate = self._qkv(p, h, r.n_heads)
-        pos = jnp.arange(h.shape[0])
-        q, k = _rotate(q, pos, r.rope_theta).astype(dt), _rotate(k, pos, r.rope_theta).astype(dt)
-        with jax.named_scope("lightning_chunk"):
-            o, state = lightning_chunked(q, k, v, decay_rates(r.n_heads), n_real, min(r.chunk, h.shape[0]))
+        turn = _turn(jnp.arange(h.shape[0]), r.head_dim, r.rope_theta)
+        with jax.named_scope("lightning_chunk"):  # q and k rotated there
+            o, state = scan(q, k, v, decay_rates(r.n_heads), n_real, min(r.chunk, h.shape[0]), turn)
         return self._lightning_out(p, o, gate), state
 
-    def _prompt(self, params, tokens, n_real, attend=sparse_prefill):
+    def _prompt(self, params, tokens, n_real, attend=sparse_prefill, scan=lightning_chunk):
         """``tokens [T]`` -> (``x [T, d]`` after the last layer, the sparse
         layers' ``(k, v, compressed keys)``, the lightning layers' states)."""
         r = self.recipe
@@ -355,7 +360,7 @@ class MiniCPMSALA(Model):
                 a, *cached = self._sparse_prompt(p["mixer"], h, attend)
                 paged.append(cached)
             else:
-                a, state = self._lightning_prompt(p["mixer"], h, n_real)
+                a, state = self._lightning_prompt(p["mixer"], h, n_real, scan)
                 states.append(state)
             x = x + self.residual_scale * a
             x = x + self.residual_scale * self._mlp(p["mlp"], _rms(x, p["norm_2"], r.rms_eps))
@@ -404,7 +409,7 @@ class MiniCPMSALA(Model):
         }
 
     def decode_prefill(self, params, tokens, pages, k_pool, pools, slot, n_real, *,
-                       page_size: int, attend=sparse_prefill):
+                       page_size: int, attend=sparse_prefill, scan=lightning_chunk):
         """Cache one padded prompt (``tokens [T_b]``, ``pages [T_b /
         page_size]``, the scratch index for the padding tail) for ``slot``,
         ``n_real`` of its positions real: the prompt's forms minus the head;
@@ -412,7 +417,7 @@ class MiniCPMSALA(Model):
         and rows (those past ``n_real`` are never read: a later step
         overwrites them before its position makes them visible), the
         lightning state at ``n_real`` to the slot. Every pool written once."""
-        _, paged, states = self._prompt(params, tokens, n_real, attend)
+        _, paged, states = self._prompt(params, tokens, n_real, attend, scan)
         n = tokens.shape[0] // page_size
         by_pages = lambda rows: jnp.stack(rows).reshape(len(rows), n, page_size, -1)  # noqa: E731
         k = by_pages([c[0] for c in paged])

@@ -21,19 +21,28 @@ with one decay ``l = exp(-rate)`` a head (the caller scales ``o``).
   sublanes), so that a head's key is a column to spread along the lanes
   beside its value row: the outer product and ``q S`` are then plain
   elementwise work and one sublane reduction, exact in fp32.
-- :func:`lightning_chunked` (plain ``jnp``, under the caller's scope): a
-  prompt in chunks of ``C``. Inside a chunk ``o_i = sum_(j<=i) l^(i-j)
-  (q_i . k_j) v_j + l^(i+1) q_i S_prev``; across chunks ``S_next = l^r
-  S_prev + sum_(j<r) l^(r-1-j) k_j^T v_j`` where ``r`` is the number of REAL
-  rows of the chunk (``C``, less in the chunk the prompt ends in, 0 past
-  it): padded rows neither add to the state nor decay it, and the state
-  handed back is the one at the real length. ``l^(i-j)`` is built as a
-  masked matrix of ``exp(-rate (i - j))`` in fp32 (``l`` is 0.43 for the
-  fastest head: a split into ``l^i / l^j`` overflows). The fp32 operands'
-  products run at ``HIGHEST``.
+- :func:`lightning_chunk`: one Pallas kernel a layer over a prompt in chunks
+  of ``C``. Inside a chunk ``o_i = sum_(j<=i) l^(i-j) (q_i . k_j) v_j +
+  l^(i+1) q_i S_prev``; across chunks ``S_next = l^r S_prev + sum_(j<r)
+  l^(r-1-j) k_j^T v_j`` where ``r`` is the number of REAL rows of the chunk
+  (``C``, less in the chunk the prompt ends in, 0 past it): padded rows
+  neither add to the state nor decay it, and the state handed back is the
+  one at the real length. The grid is (head groups, chunks), the chunks in
+  order; a group's states ``[hb, D, D]`` fp32 are the call's output block,
+  held in VMEM across the chunks and written to HBM once. q and k come
+  UNROTATED in fp32 and are rotated by a lane roll inside (``x cos +
+  roll(x, D/2) sin``, the arithmetic of the model's ``_rotate``), then
+  rounded to v's dtype: rotated outside, the halves of a 128-lane head and
+  the ``[T, H, D]`` -> ``[T, H D]`` relayout are passes of their own.
+  ``l^(i-j)`` is a masked matrix of ``exp(-rate (i - j))`` in fp32, built
+  once a head group (``l`` is 0.43 for the fastest head: a split into ``l^i
+  / l^j`` overflows). The scores take the rounded q and k (exact products,
+  fp32 sums); the three products with an fp32 operand run on the MXU at
+  ``HIGHEST``. :func:`lightning_chunk_reference` is its ``jnp`` twin, the
+  same sums over whole arrays.
 
 :func:`lightning_reference` is the recurrence itself, position by position:
-the oracle of both.
+the oracle of all three.
 """
 
 from __future__ import annotations
@@ -44,10 +53,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from theanompi_tpu.ops.pallas_util import interpret_mode as _interpret
 
-STEP_NAME = "lightning_step"  # the kernel's name in a device trace
+STEP_NAME = "lightning_step"  # the kernels' names in a device trace
+CHUNK_NAME = "lightning_chunk"
 _HEADS_A_STEP = 8
 
 
@@ -74,8 +85,6 @@ def lightning_step(
 ):
     """-> (``o [S, H, D]`` fp32 = ``q S_new``, the state with ``layer``'s part
     stepped; the state is aliased to the output)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     S, H, D = q.shape
     hb = min(_HEADS_A_STEP, H)
     if H % hb:
@@ -116,11 +125,83 @@ def lightning_step_reference(q, k, v, rates, state, lens, *, layer: int):
     return o, state.at[layer].set(s)
 
 
-def lightning_chunked(q, k, v, rates, n_real, chunk: int):
-    """``q, k, v [T, H, D]`` (``T`` whole chunks), ``rates [H]``, ``n_real``
-    the prompt's real length (a traced scalar). -> (``o [T, H, D]`` fp32,
+def _chunk_kernel(hb, C, D, n_ref, rates_ref, cos_ref, sin_ref, q_ref, k_ref, v_ref, o_ref, s_ref, dec_ref):
+    g, n = pl.program_id(0), pl.program_id(1)
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    row = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    # rows from row j to the chunk's last real row: negative past it, all of them past the prompt
+    left = (jnp.clip(n_ref[0] - n * C, 0, C) - 1 - row).astype(f32)
+
+    @pl.when(n == 0)
+    def _():
+        apart = (row - lax.broadcasted_iota(jnp.int32, (C, C), 1)).astype(f32)
+        for i in range(hb):
+            dec_ref[i] = jnp.where(apart >= 0, jnp.exp(-rates_ref[g * hb + i] * jnp.maximum(apart, 0.0)), 0.0)
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    def turn(x):  # pairs (j, j + D/2): x_j cos - x_(j+D/2) sin, x_(j+D/2) cos + x_j sin; then v's dtype
+        x = x.astype(f32)  # Mosaic rotates 32-bit lanes only
+        return (x * cos_ref[...] + pltpu.roll(x, D // 2, 1) * sin_ref[...]).astype(v_ref.dtype)
+
+    for i in range(hb):
+        rate, cols = rates_ref[g * hb + i], slice(i * D, (i + 1) * D)
+        q, k, v = turn(q_ref[:, cols]), turn(k_ref[:, cols]), v_ref[:, cols].astype(f32)
+        S = s_ref[i]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        reach = jnp.exp(-rate * (row + 1).astype(f32))  # [C, 1]: l^(i+1)
+        o_ref[:, cols] = (jnp.dot(s * dec_ref[i], v, precision=hi, preferred_element_type=f32)
+                          + jnp.dot(q.astype(f32) * reach, S, precision=hi, preferred_element_type=f32))
+        w = jnp.where(left >= 0, jnp.exp(-rate * jnp.maximum(left, 0.0)), 0.0)  # [C, 1]: l^(r-1-j)
+        through = jnp.exp(-rate * (left[:1] + 1.0))  # [1, 1]: l^r
+        s_ref[i] = through * S + lax.dot_general(k.astype(f32) * w, v, (((0,), (0,)), ((), ())), precision=hi,
+                                                 preferred_element_type=f32)
+
+
+def _turn_tables(turn):
+    """``(cos, sin) [T, D / 2]`` -> ``[T, D]`` tables for ``x cos + roll(x, D / 2) sin``."""
+    cos, sin = turn
+    return jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1)
+
+
+def lightning_chunk(q, k, v, rates, n_real, chunk: int, turn):
+    """``q, k [T, H, D]`` UNROTATED, ``v [T, H, D]`` (``T`` whole chunks),
+    ``rates [H]``, ``n_real`` the prompt's real length (a traced scalar),
+    ``turn = (cos, sin) [T, D / 2]`` fp32: q and k are rotated here (pairs
+    ``(j, j + D/2)``) and rounded to v's dtype. -> (``o [T, H, D]`` fp32,
     unscaled; the state ``[H, D, D]`` fp32 after position ``n_real - 1``)."""
     T, H, D = q.shape
+    C = int(chunk)
+    if T % C:
+        raise ValueError(f"{T} positions are no whole chunks of {C}")
+    hb = min(_HEADS_A_STEP, H)
+    if H % hb:
+        raise ValueError(f"{H} heads are no whole groups of {hb}")
+    f32 = jnp.float32
+    table = pl.BlockSpec((C, D), lambda g, n, n_real, rates: (n, 0))
+    rows = pl.BlockSpec((C, hb * D), lambda g, n, n_real, rates: (n, g))
+    o, state = pl.pallas_call(
+        functools.partial(_chunk_kernel, hb, C, D),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H // hb, T // C),
+            in_specs=[table, table, rows, rows, rows],
+            out_specs=(rows, pl.BlockSpec((hb, D, D), lambda g, n, n_real, rates: (g, 0, 0))),
+            scratch_shapes=[pltpu.VMEM((hb, C, C), f32)],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((T, H * D), f32), jax.ShapeDtypeStruct((H, D, D), f32)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        name=CHUNK_NAME,
+        interpret=_interpret(),
+    )(jnp.reshape(n_real, (1,)).astype(jnp.int32), rates.astype(f32), *_turn_tables(turn),
+      *(a.reshape(T, H * D) for a in (q, k, v)))
+    return o.reshape(T, H, D), state
+
+
+def lightning_chunk_reference(q, k, v, rates, n_real, chunk: int, turn):
+    """The chunk kernel's ``jnp`` twin: the same sums over whole arrays."""
+    T, H, D = q.shape
+    cos, sin = (t[:, None, :] for t in _turn_tables(turn))
+    q, k = ((x * cos + jnp.roll(x, D // 2, -1) * sin).astype(v.dtype) for x in (q, k))
     C = int(chunk)
     if T % C:
         raise ValueError(f"{T} positions are no whole chunks of {C}")
