@@ -331,10 +331,11 @@ def test_every_pallas_call_in_ops_passes_a_name():
                 # a module-level constant beside its kernel
                 assert isinstance(name[0], ast.Name) and name[0].id in constants, f"{path}:{node.lineno}"
                 names.add(constants[name[0].id])
-    assert calls == 23 and len(names) == 23
+    assert calls == 24 and len(names) == 24
     assert {"moe_gmm", "moe_tgmm", "mla_decode", "mla_cache_write"} <= names
     assert {"sparse_decode", "sparse_prefill", "sparse_cache_write", "lightning_step"} <= names  # PR 35
     assert {"eva_decode", "eva_prefill", "eva_cache_write"} <= names  # PR 39
     assert "lightning_chunk" in names  # PR 40
+    assert "mamba_scan" in names
     # the roofline readers find the flash kernels by these two stems
     assert {n for n in names if "flash" in n} == {"flash_fwd", "flash_bwd", "flash_bwd_2d"}

@@ -592,3 +592,83 @@ def test_evabyte_prefill_of_24576_positions_holds_no_square_of_the_prompt(
     assert mem.alias_size_in_bytes >= 6_048_186_368 and mem.temp_size_in_bytes < 3.5e9
     assert f"{T},{T}" not in text and f"{T},{T // 16}" not in text
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def _jamba2_decode(one_chip, monkeypatch):
+    """The cell ``jamba2-3b-decode-doc32k`` as shapes on the described chip:
+    the stage of 14 layers at the published widths and its engine's three
+    kinds of state as ``PagedKVCache`` shapes them (16 slots of 131 pages of
+    256 positions in the one attention layer; a conv window and a state a
+    slot in each of the 13 Mamba layers)."""
+    from theanompi_tpu.models.jamba import Jamba2_3B_Stage2
+    from theanompi_tpu.ops import pallas_attention as pa
+    from theanompi_tpu.ops import pallas_mamba as pm
+    from theanompi_tpu.ops import pallas_sparse as ps
+
+    for module in (pm, ps):
+        _mosaic(monkeypatch, module)
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    model, page, pages, S, M = Jamba2_3B_Stage2(), 256, 2096, 16, 131
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    spec = model.cache_spec(page)
+    k_pool = on_chip((spec["paged_layers"], pages + 1, *spec["k_page"]), spec["dtype"])
+    held = {"v": on_chip(k_pool.shape, spec["dtype"])}
+    for name, a in spec["slots"].items():
+        held[name] = on_chip((a["layers"], S, *a["row"]), a["dtype"])
+    assert k_pool.shape == (1, 2097, 256, 128) and held["conv"].shape == (13, 16, 3, 5120)
+    assert held["ssm"].shape == (13, 16, 16, 5120) and held["ssm"].dtype == jnp.float32
+    return model, page, params, k_pool, held, on_chip, (S, M)
+
+
+def test_jamba2_decode_step_reads_every_page_of_a_slot_and_updates_every_pool_in_place(
+        one_chip, no_persistent_cache, monkeypatch):
+    """16 slots over 0.27 GB of K and V pages (one attention layer, one K/V
+    head) and 75 MB of conv windows and float32 states (13 Mamba layers): one
+    ``sparse_decode`` over every page of the slot (20 query heads over the one
+    K/V head), one ``sparse_cache_write``, the Mamba steps as fusions;
+    everything donated aliases its output and no slot's whole context is
+    gathered (``[16, 33536, 128]`` would be 137 MB)."""
+    model, page, params, k_pool, held, on_chip, (S, M) = _jamba2_decode(one_chip, monkeypatch)
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_pool, held, on_chip((S, M), jnp.int32), on_chip((S,), jnp.int32), on_chip((S,), jnp.int32),
+        on_chip((S,), jnp.bool_), on_chip((S,), jnp.float32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 1 + 1
+    assert "sparse_decode" in text and "sparse_cache_write" in text
+    assert _held_bytes(k_pool, held) == 349_405_184 and mem.alias_size_in_bytes >= 349_405_184
+    assert mem.temp_size_in_bytes < 0.2e9
+    assert not re.search(rf"\[{S},{M * page},128\]", text) and not re.search(rf"\[{S},{M},{page},128\]", text)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_jamba2_prefill_of_32768_positions_fits_beside_its_weights(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One bucket of 32,768 rows for its slot at a traced real length: one
+    ``flash_fwd`` at 20 heads over one K/V head (its resident K and V pass the
+    default scoped VMEM: the forward states its limit), thirteen
+    ``mamba_scan`` kernels, whole pages and the slot's windows and states
+    written to the donated pools; nothing is shaped ``[.., T, T]`` or ``[T,
+    16, 5120]`` (a state a row: 10.7 GB)."""
+    model, page, params, k_pool, held, on_chip, _ = _jamba2_decode(one_chip, monkeypatch)
+
+    def prefill(p, t, pg, k, v, slot, n_real):
+        return model.decode_prefill(p, t, pg, k, v, slot, n_real, page_size=page)
+
+    T = 32768
+    compiled = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, on_chip((T,), jnp.int32), on_chip((T // page,), jnp.int32), k_pool, held,
+        on_chip((), jnp.int32), on_chip((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 1 + 13 and "mamba_scan" in text and "flash_fwd" in text
+    assert mem.alias_size_in_bytes >= 349_405_184 and mem.temp_size_in_bytes < 4e9
+    assert f"{T},{T}" not in text and f"{T},16,5120" not in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES

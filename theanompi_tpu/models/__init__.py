@@ -35,6 +35,8 @@ MODEL_REGISTRY = {
     "minicpm_sala_stage8": ("theanompi_tpu.models.minicpm_sala", "MiniCPM_SALA_Stage8"),
     "evabyte_lm": ("theanompi_tpu.models.evabyte", "EvaByte"),
     "evabyte_stage4": ("theanompi_tpu.models.evabyte", "EvaByte_Stage4"),
+    "jamba_lm": ("theanompi_tpu.models.jamba", "Jamba"),
+    "jamba2_3b_stage2": ("theanompi_tpu.models.jamba", "Jamba2_3B_Stage2"),
 }
 
 

@@ -37,6 +37,8 @@ numerics on the CPU test meshes. ``TMPI_PALLAS=0`` falls back to the
 unfused reference implementation.
 
 K/V (and in backward Q) blocks for one batch·head row must fit VMEM:
+past 8,192 bfloat16 keys the forward states its own limit
+(:func:`_fwd_vmem_bytes`: 48 MiB at 32,768 keys of 128); the backward is
 fine through T ~ 8-16k at D <= 128; beyond that use
 :func:`~theanompi_tpu.ops.ring_attention.ring_attention`, whose local
 block this kernel exactly is (each device's ring hop folds one K/V
@@ -356,10 +358,23 @@ def _full(shape):
                         memory_space=pltpu.VMEM)
 
 
+def _fwd_vmem_bytes(Tkp: int, D: int, itemsize: int) -> Optional[int]:
+    """What :func:`_fwd_kernel` may take of VMEM where its resident K and V
+    (two buffers each, lanes padded to 128) pass 8 MiB: those and 16 MiB for
+    the query and output blocks and the tile's intermediates. None (the
+    default scoped limit, 16 MiB on a v5e) up to 8 MiB: the programs of
+    contexts up to 8,192 bfloat16 keys are what they were."""
+    kv = 4 * Tkp * _ceil_to(D, 128) * itemsize
+    return None if kv <= (8 << 20) else kv + (16 << 20)
+
+
 def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
     """Padded [BH, T_pad, D] flash forward -> (o, lse[BH, T_pad, 1])."""
+    from jax.experimental.pallas import tpu as pltpu
+
     BH, Tqp, D = q3.shape
     Tkp = k3.shape[1]
+    limit = _fwd_vmem_bytes(Tkp, D, k3.dtype.itemsize)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg),
         grid=(BH, Tqp // cfg.BQ),
@@ -380,6 +395,7 @@ def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
             jax.ShapeDtypeStruct((BH, Tqp, D), q3.dtype),
             jax.ShapeDtypeStruct((BH, Tqp, 1), jnp.float32),
         ),
+        compiler_params=None if limit is None else pltpu.CompilerParams(vmem_limit_bytes=limit),
         name=FWD_NAME,
         interpret=cfg.interpret,
     )(q_off, k_off, q3, k3, v3)

@@ -535,7 +535,7 @@ SERVE_METRIC_PREFIX = "tmpi_serve_"
 #   tmpi_decode_kv_pool_bytes   gauge      by kind=kv|latent|compressed
 #                                          (the paged pools; EVA's pages
 #                                          hold compressed rows) |compressed|
-#                                          state|ring (held a slot)
+#                                          state|ring|conv|ssm (held a slot)
 #   tmpi_decode_kv_bytes_per_position gauge  by kind, one more position
 #                                          (a ring or a state: none)
 #   tmpi_decode_visible_context_share gauge  share of their context the
@@ -545,6 +545,11 @@ SERVE_METRIC_PREFIX = "tmpi_serve_"
 #                                          sum an iteration in the span
 #                                          store's counts, by that name)
 #   tmpi_decode_eva_compressed_rows gauge  EVA: compressed rows it read
+#   tmpi_decode_mqa_rows        gauge      Jamba: K/V rows a query read
+#                                          over the attention layers
+#   tmpi_decode_ssm_state_bytes gauge      Jamba: Mamba state a step read
+#                                          and wrote a slot (conv|ssm held a
+#                                          slot: ..._conv_bytes, ..._ssm_bytes)
 #   tmpi_decode_requests_total  counter    by status=served|expired|
 #                                          evicted|rejected|failed
 #   tmpi_decode_tokens_total    counter    tokens sampled and returned
