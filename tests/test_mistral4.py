@@ -159,12 +159,12 @@ _TINY, _CELL = (4, 32, 16, PAGE, 12), (32, 256, 64, 128, 11)  # H, R, Dr, page, 
 ], ids=["mixed", "zeros-between", "page-edges", "step-edges", "all-empty", "ragged-steps", "one-step",
         "cell-widths"])
 def test_mla_decode_in_interpret_mode_equals_its_jnp_twin(lens, dims, most, pps, monkeypatch):
-    from theanompi_tpu.ops import pallas_mla as pm
+    from theanompi_tpu.ops import pallas_paged
 
     H, R, Dr, page, M = dims
     if most:
-        monkeypatch.setattr(pm, "_STEP_BYTES", most * page * (R + Dr) * 2)
-    assert pm._pages_a_step(M, page * (R + Dr) * 2) == pps
+        monkeypatch.setattr(pallas_paged, "_STEP_BYTES", most * page * (R + Dr) * 2)
+    assert pallas_paged.pages_a_step(M, page * (R + Dr) * 2) == pps
     S, L = len(lens), 2
     P = sum(pages_needed(n + 1, page) for n in lens)
     k = jax.random.split(jax.random.PRNGKey(0), 6)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, SingleDeviceSharding
+from jax.sharding import Mesh, PartitionSpec as P, SingleDeviceSharding
 
 V5E_HBM_BYTES = 16e9
 
@@ -379,12 +379,16 @@ def _assert_no_relayout_of_a_pool(compiled, pools):
 
 
 def test_lm136m_decode_step_copies_each_undonated_pool_once_and_no_layer_of_it(
-        one_chip, no_persistent_cache):
-    """24 slots x 64 pages over two 0.906 GB pools: 24 gathers by page out
-    of the pools as they stood, both attention products over whole
-    768-lane rows (a reshape of the gathered rows to ``[12, 64]`` re-lays
-    them into padded tiles: 0.42 GB of temporaries where 0.01 GB stand), one
-    scatter a pool after the last layer."""
+        one_chip, no_persistent_cache, monkeypatch):
+    """24 slots x 64 pages over two 0.906 GB pools: one ``kv_decode`` call a
+    layer reads each slot's live pages straight out of the pools as they
+    stood (no gather by page, no slot's rows at the table's whole length),
+    whole 768-lane rows throughout (a reshape of the cached rows to
+    ``[12, 64]`` re-lays them into padded tiles: 0.42 GB of temporaries
+    where 0.01 GB stand), one scatter a pool after the last layer."""
+    from theanompi_tpu.ops import pallas_paged
+
+    _mosaic(monkeypatch, pallas_paged)
     model, page, params, pools, on_chip, (S, M) = _lm136m_decode(one_chip)
 
     def step(p, k, v, tb, sl, la, ac, te):
@@ -394,8 +398,48 @@ def test_lm136m_decode_step_copies_each_undonated_pool_once_and_no_layer_of_it(
         params, *pools, on_chip((S, M), jnp.int32), on_chip((S,), jnp.int32),
         on_chip((S,), jnp.int32), on_chip((S,), jnp.bool_), on_chip((S,), jnp.float32)).compile()
     _assert_no_relayout_of_a_pool(compiled, pools)
-    assert "f32[24,1024,12,64]" not in compiled.as_text()  # a slot's gathered rows, by heads
+    text = compiled.as_text()
+    assert "f32[24,1024,12,64]" not in text  # a slot's gathered rows, by heads
+    assert "kv_decode" in text and _kernels(compiled) == model.arch.n_layers
+    assert "[1536,16,768]" not in text  # the gather of every slot's whole table, of K or V
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_lm136m_decode_step_compiles_tensor_sharded_over_four_chips(
+        topo, no_persistent_cache, monkeypatch):
+    """``tmpi serve --decode --shard tensor`` on a 2x2 v5e host: the params
+    Megatron-sharded and the pools whole on every chip
+    (``ShardingRecipe.serve_tensor``). The compiler does not partition a
+    Mosaic kernel (lowered for four devices unmapped, ``kv_decode`` is
+    refused), so each chip runs it over all heads on its own copy of the
+    pools: the same 12 calls, copies and scatters as on one chip."""
+    from jax.sharding import NamedSharding
+
+    from theanompi_tpu.ops import pallas_paged
+    from theanompi_tpu.parallel.recipe import ShardingRecipe
+
+    _mosaic(monkeypatch, pallas_paged)
+    model, page, params, pools, _, (S, M) = _lm136m_decode(SingleDeviceSharding(topo.devices[0]))
+    recipe = ShardingRecipe.serve_tensor(model, mesh=Mesh(np.array(topo.devices), ("model",)))
+    specs = jax.tree_util.tree_leaves(recipe.roles["params"], is_leaf=lambda x: isinstance(x, P))
+
+    def placed(a, spec=P()):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(recipe.mesh, spec))
+
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    params = jax.tree_util.tree_unflatten(tree, [placed(a, s) for a, s in zip(leaves, specs)])
+    assert any(s != P() for s in specs)
+    pools = [placed(p) for p in pools]
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step).lower(params, *pools, *(placed(jax.ShapeDtypeStruct(shape, dtype)) for shape, dtype in (
+        ((S, M), jnp.int32), ((S,), jnp.int32), ((S,), jnp.int32), ((S,), jnp.bool_), ((S,), jnp.float32)))).compile()
+    _assert_no_relayout_of_a_pool(compiled, pools)
+    text = compiled.as_text()
+    assert "kv_decode" in text and _kernels(compiled) == model.arch.n_layers
+    assert "[1536,16,768]" not in text  # no gather of every slot's whole table
 
 
 def test_lm136m_prefill_writes_each_pool_once(one_chip, no_persistent_cache):

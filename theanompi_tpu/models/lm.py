@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax.numpy as jnp
+import numpy as np
 
 from theanompi_tpu.models.contract import Model, Recipe
 from theanompi_tpu.models.transformer import (
@@ -144,11 +145,21 @@ class TransformerLMModel(Model):
         its two minor dimensions: ``[n_heads, head_dim] = [12, 64]`` fills
         none and made the compiler re-lay whole pools
         (``transformer.paged_decode_step``). The programs do not take the
-        pools donated."""
+        pools donated. The page size is kept for :meth:`cache_rows`."""
         a = self.arch
+        self._page_size = page_size
         page = (page_size, a.d_model)  # n_heads * head_dim
         return {"kind": "kv", "k_page": page, "v_page": page, "dtype": jnp.float32,
                 "donate": False}
+
+    def cache_rows(self, seq_lens) -> dict:
+        """Host side: ``kv_positions``, the cached positions the decode step's
+        kernel copies, in every layer, for the query at each of ``seq_lens``
+        (numpy): its live pages whole (``ops/pallas_paged.py kv_decode``),
+        pages of the size :meth:`cache_spec` was given."""
+        page = self._page_size
+        t = np.asarray(seq_lens, np.int64)
+        return {"kv_positions": -(-t // page) * page}
 
     def decode_prefill(self, params, tokens, pages, k_pool, v_pool, *,
                        page_size: int):

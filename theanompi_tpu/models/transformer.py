@@ -36,9 +36,10 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from theanompi_tpu.ops.pallas_attention import flash_attention, ring_flash_attention
+from theanompi_tpu.ops.pallas_paged import kv_decode
 from theanompi_tpu.ops.ring_attention import (
     full_attention_reference,
     ring_attention,
@@ -473,8 +474,32 @@ def paged_prefill(arch, params, tokens, pages, k_pool, v_pool,
         att = full_attention_reference(q, k, v, causal=True)
         x = x + jnp.einsum("bthk,hkd->btd", att, blk["proj"])
         x = x + ffn(blk, _rms(x, blk["ln2"]))
-    return (k_pool.at[:, pages].set(jnp.stack(ks).astype(k_pool.dtype)),
-            v_pool.at[:, pages].set(jnp.stack(vs).astype(v_pool.dtype)))
+    mesh = jax.typeof(k_pool).sharding.mesh
+    return (k_pool.at[:, pages].set(_whole(mesh, jnp.stack(ks).astype(k_pool.dtype))),
+            v_pool.at[:, pages].set(_whole(mesh, jnp.stack(vs).astype(v_pool.dtype))))
+
+
+def _whole(mesh, rows):
+    """``rows`` whole on every device of ``mesh``, as the pools they are
+    written to: rows left split by heads would leave the pools split too,
+    and :func:`_attend_cached` reads them whole on every device."""
+    if mesh.size > 1:
+        rows = lax.with_sharding_constraint(rows, NamedSharding(mesh, P()))
+    return rows
+
+
+def _attend_cached(mesh, q, k, v, k_pool, v_pool, page_tables, seq_lens, **kw):
+    """:func:`kv_decode` on ``mesh``, the pools' mesh. The compiler does not
+    partition a Mosaic kernel, so on a mesh of several devices
+    (tensor-sharded serving, ``ShardingRecipe.serve_tensor``, which places
+    the pools whole on every device) each device runs it over all heads on
+    its own copy. Split by heads, a device's lanes would be 192 at the 136M
+    widths on four chips, and Mosaic copies rows in multiples of 128."""
+    call = functools.partial(kv_decode, **kw)
+    if mesh.size > 1:
+        call = jax.shard_map(call, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)
+    return call(q, k, v, k_pool, v_pool, page_tables, seq_lens)
 
 
 def paged_decode_step(arch, params, k_pool, v_pool, page_tables, seq_lens,
@@ -484,10 +509,10 @@ def paged_decode_step(arch, params, k_pool, v_pool, page_tables, seq_lens,
 
     Per slot ``s``: embed ``last_tokens[s]`` at position ``seq_lens[s]``
     and attend over positions ``0..seq_lens[s]`` inclusive: the cached
-    rows below ``seq_lens[s]``, gathered through the slot's page table
-    from the pool AS IT STOOD (viewed ``[L * (n_pages + 1), page_size,
-    H * hd]`` and indexed ``page_tables + li * (n_pages + 1)``: no layer
-    is cut out of it), and the step's own K/V row, an operand whose score
+    rows below ``seq_lens[s]``, read from the pools AS THEY STOOD by
+    ``ops/pallas_paged.py kv_decode`` (one call a layer: only the pages
+    those rows lie in; every device of a mesh its own call, see
+    :func:`_attend_cached`), and the step's own K/V row, an operand whose score
     joins the cached ones under one fp32 softmax (same ``1/sqrt(hd)``
     scale as :func:`full_attention_reference`). The new rows of ALL
     layers go to (page ``page_tables[s, pos//page_size]``, offset
@@ -498,60 +523,35 @@ def paged_decode_step(arch, params, k_pool, v_pool, page_tables, seq_lens,
     program serves every iteration.
 
     The pools are ``[L, n_pages + 1, page_size, H * hd]`` (lane-dense
-    rows: the ``(8, 128)`` tile, see above) and the gathered rows are
-    never reshaped to ``[H, hd]``, which would re-lay them into padded
-    tiles layer by layer (7.6 ms of a 22.7 ms step on a v5e, PERF.md
-    section 6, PR 34): both attention products run over whole rows, head
-    ``h``'s query zero outside its own ``hd`` lanes and its output taken
-    from them.
+    rows: the ``(8, 128)`` tile, see above), and the kernel takes the
+    query, its output and the new rows as whole rows too.
 
     Returns ``(next_tokens [S] int32, logits [S, V] fp32, k_pool,
     v_pool)``.
     """
     S, M = page_tables.shape
     L, n_phys = k_pool.shape[:2]  # n_pages + 1: the last is the scratch page
+    mesh = jax.typeof(k_pool).sharding.mesh
     H, D = arch.n_heads, k_pool.shape[-1]
     hd = D // H
-    f32, hi = jnp.float32, lax.Precision.HIGHEST
-    sc = 1.0 / math.sqrt(hd)
     pos = jnp.clip(seq_lens, 0, params["pos_emb"].shape[0] - 1)
     x = (params["tok_emb"][last_tokens] + params["pos_emb"][pos]).astype(
         arch.dtype
     )
-    k_pages = k_pool.reshape(L * n_phys, page_size, D)
-    v_pages = v_pool.reshape(L * n_phys, page_size, D)
-    cached = (jnp.arange(M * page_size)[None, :] < seq_lens[:, None])[:, None, :]
-    head_lanes = jnp.arange(D)[None, :] // hd == jnp.arange(H)[:, None]  # [H, D]
     ks, vs = [], []
     for li, blk in enumerate(params["blocks"]):
         blk = cast_block_params(blk, arch.dtype)
         hin = _rms(x, blk["ln1"])
         qkv = jnp.einsum("sd,dchk->schk", hin, blk["qkv"])
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [S, H, hd]
+        q, k, v = (qkv[:, i].reshape(S, D) for i in range(3))
         # the row as the pool will hold it, so that this step and the next
         # read the same K and V of this position
         k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
-        ks.append(k.reshape(S, D))
-        vs.append(v.reshape(S, D))
-        k_ctx = k_pages[page_tables + li * n_phys].reshape(S, M * page_size, D)
-        v_ctx = v_pages[page_tables + li * n_phys].reshape(S, M * page_size, D)
-        # every cached row was a compute-dtype value when it was written, so
-        # the cast back is exact: products of such values, summed in fp32
-        q_heads = jnp.where(head_lanes, q.reshape(S, 1, D), 0)  # [S, H, D]
-        s_ = jnp.einsum("shD,stD->sht", q_heads, k_ctx.astype(q.dtype),
-                        precision=hi, preferred_element_type=f32) * sc
-        s_own = jnp.einsum("shd,shd->sh", q.astype(f32), k.astype(f32)) * sc
-        p = jax.nn.softmax(
-            jnp.concatenate(
-                [jnp.where(cached, s_, -1e30), s_own[..., None]], axis=-1
-            ),
-            axis=-1,
-        )
-        o = jnp.einsum("sht,stD->shD", p[..., :-1], v_ctx.astype(f32),
-                       precision=hi)  # head h's output lies in head h's lanes
-        att = jnp.where(head_lanes, o, 0).sum(axis=1).reshape(S, H, hd)
-        att = (att + p[..., -1:] * v.astype(f32)).astype(x.dtype)
-        x = x + jnp.einsum("shk,hkd->sd", att, blk["proj"])
+        ks.append(k)
+        vs.append(v)
+        att = _attend_cached(mesh, q, k, v, k_pool, v_pool, page_tables, seq_lens,
+                             layer=li, n_heads=H, scale=1.0 / math.sqrt(hd))
+        x = x + jnp.einsum("shk,hkd->sd", att.reshape(S, H, hd), blk["proj"])
         x = x + ffn(blk, _rms(x, blk["ln2"]))
     write_page = jnp.where(
         active,
@@ -560,8 +560,10 @@ def paged_decode_step(arch, params, k_pool, v_pool, page_tables, seq_lens,
     )
     rows = write_page + jnp.arange(L)[:, None] * n_phys  # [L, S] pages of the view
     write_off = seq_lens % page_size
-    k_pool = k_pages.at[rows, write_off].set(jnp.stack(ks)).reshape(k_pool.shape)
-    v_pool = v_pages.at[rows, write_off].set(jnp.stack(vs)).reshape(v_pool.shape)
+    k_pool = (k_pool.reshape(L * n_phys, page_size, D).at[rows, write_off]
+              .set(_whole(mesh, jnp.stack(ks))).reshape(k_pool.shape))
+    v_pool = (v_pool.reshape(L * n_phys, page_size, D).at[rows, write_off]
+              .set(_whole(mesh, jnp.stack(vs))).reshape(v_pool.shape))
     logits = (x @ params["head"].astype(arch.dtype)).astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     safe_t = jnp.maximum(temperature, 1e-6)[:, None]

@@ -21,7 +21,8 @@ anyway and copies it whole to the padded one before every kernel call)
 
 reading ONLY the pages its real length covers. The grid is the slots. The
 pools stay in HBM (``memory_space=pl.ANY``: one operand each, no block, no
-copy of a pool) and the kernel brings a slot's pages itself: the slot's
+copy of a pool) and the kernel brings a slot's pages itself, by the walk
+``ops/pallas_paged.py page_walk`` that ``kv_decode`` shares: the slot's
 table is cut into the fewest equal steps of at most ``_STEP_BYTES`` of rows
 (the served cell's 69-page table: 3 steps of 23 pages), and a step's live
 pages are copied, a page each of ``c`` and ``r``, into one half of a
@@ -62,68 +63,29 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from theanompi_tpu.ops.pallas_paged import page_walk, pages_a_step
 from theanompi_tpu.ops.pallas_util import interpret_mode as _interpret
 
 DECODE_NAME = "mla_decode"  # the kernels' names in a device trace
 WRITE_NAME = "mla_cache_write"
-_STEP_BYTES = 2 << 20  # of cached rows a step brings at most (VMEM holds two such halves)
 _NEG = -1e30
 
 
 def _kernel(scale, page, pps, layer, tables_ref, lens_ref, qlat_ref, qrope_ref, cnew_ref, rnew_ref,
             cpool_ref, rpool_ref, o_ref, cbuf_ref, rbuf_ref, sem_ref, par_ref):
-    s, S = pl.program_id(0), pl.num_programs(0)
+    def copies(pg, buf, i):
+        return (pltpu.make_async_copy(cpool_ref.at[layer, pg], cbuf_ref.at[buf, pl.ds(i * page, page)],
+                                      sem_ref.at[buf, 0]),
+                pltpu.make_async_copy(rpool_ref.at[layer, pg], rbuf_ref.at[buf, :, pl.ds(i * page, page)],
+                                      sem_ref.at[buf, 1]))
+
+    n, run = page_walk(page, pps, tables_ref, lens_ref, (cbuf_ref, rbuf_ref), copies, par_ref)
     span = pps * page
-    n = lens_ref[s]
-    steps = (n + span - 1) // span
-
-    def pages(slot, step, buf, act):
-        """``act`` on the copies, ``c`` and ``r``, of every live page of
-        ``slot``'s step into half ``buf`` (a copy is waited for as it was started)."""
-        for i in range(pps):
-            k = step * pps + i
-            pg = tables_ref[slot, jnp.minimum(k, tables_ref.shape[1] - 1)]
-
-            @pl.when(k * page < lens_ref[slot])
-            def _(i=i, pg=pg):
-                act(pltpu.make_async_copy(cpool_ref.at[layer, pg],
-                                          cbuf_ref.at[buf, pl.ds(i * page, page)], sem_ref.at[buf, 0]))
-                act(pltpu.make_async_copy(rpool_ref.at[layer, pg],
-                                          rbuf_ref.at[buf, :, pl.ds(i * page, page)], sem_ref.at[buf, 1]))
-
-    def start(slot, step, buf):
-        pages(slot, step, buf, lambda copy: copy.start())
-
-    def wait(slot, step, buf):
-        pages(slot, step, buf, lambda copy: copy.wait())
-
-    @pl.when(s == 0)
-    def _first():
-        cbuf_ref[...] = jnp.zeros_like(cbuf_ref)  # a dead page's rows are masked, so must be finite:
-        rbuf_ref[...] = jnp.zeros_like(rbuf_ref)  # zeros now, an earlier step's rows later
-        par_ref[0] = 0  # the half that holds the slot's first step
-
-    par = par_ref[0]
-
-    @pl.when(jnp.logical_or(s == 0, lens_ref[jnp.maximum(s - 1, 0)] == 0))
-    def _own_first_step():  # else the slot before started it from its last step
-        start(s, 0, par)
-
-    nxt = jnp.minimum(s + 1, S - 1)
-    has_next = s + 1 < S
     qlat, qrope = qlat_ref[...], qrope_ref[...]  # [H, R], [H, Dr]
     H, R = qlat.shape
 
-    def step(t, carry):
+    def body(t, buf, carry):
         m, l, acc = carry
-        buf = (par + t) % 2
-        last = t + 1 == steps
-
-        @pl.when(jnp.logical_or(jnp.logical_not(last), has_next))
-        def _next_step():  # this slot's, or the first of the next slot
-            start(jnp.where(last, nxt, s), jnp.where(last, 0, t + 1), 1 - buf)
-
-        wait(s, t, buf)
         c, r = cbuf_ref[buf], rbuf_ref[buf]  # [span, R], [Dr, span]
         sc = (lax.dot_general(qlat, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
               + lax.dot_general(qrope, r, (((1,), (0,)), ((), ())),
@@ -137,11 +99,8 @@ def _kernel(scale, page, pps, layer, tables_ref, lens_ref, qlat_ref, qrope_ref, 
                                            preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    m, l, acc = lax.fori_loop(0, steps, step, (jnp.full((H, 1), _NEG, jnp.float32),
-                                               jnp.zeros((H, 1), jnp.float32),
-                                               jnp.zeros((H, R), jnp.float32)))
-
-    par_ref[0] = (par + steps) % 2
+    m, l, acc = run(body, (jnp.full((H, 1), _NEG, jnp.float32), jnp.zeros((H, 1), jnp.float32),
+                           jnp.zeros((H, R), jnp.float32)))
     cn = cnew_ref[...].astype(jnp.float32)  # [1, R]
     rn = rnew_ref[...].astype(jnp.float32)
     sc = (jnp.sum(qlat.astype(jnp.float32) * cn, axis=-1, keepdims=True)
@@ -149,12 +108,6 @@ def _kernel(scale, page, pps, layer, tables_ref, lens_ref, qlat_ref, qrope_ref, 
     m_new = jnp.maximum(m, sc)
     corr, p = jnp.exp(m - m_new), jnp.exp(sc - m_new)
     o_ref[...] = ((acc * corr + p * cn) / (l * corr + p)).astype(o_ref.dtype)
-
-
-def _pages_a_step(M: int, page_bytes: int) -> int:
-    """The table's ``M`` pages in the fewest equal steps of at most ``_STEP_BYTES``."""
-    steps = -(-M // max(1, min(M, _STEP_BYTES // page_bytes)))
-    return -(-M // steps)
 
 
 def mla_decode(
@@ -175,7 +128,7 @@ def mla_decode(
     S, H, R = q_lat.shape
     Dr = q_rope.shape[-1]
     M, page = tables.shape[1], c_pool.shape[2]
-    pps = _pages_a_step(M, page * (R + Dr) * c_pool.dtype.itemsize)
+    pps = pages_a_step(M, page * (R + Dr) * c_pool.dtype.itemsize)
     slot = lambda s, tables, lens: (s, 0, 0)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_kernel, float(scale), page, pps, int(layer)),

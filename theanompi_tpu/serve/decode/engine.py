@@ -53,7 +53,8 @@ Telemetry is ``tmpi_decode_*``-prefixed (schema: ``kind=decode`` in
 tools/check_obs_schema.py): TTFT/TPOT/queue-wait histograms, tokens/sec,
 kv page occupancy, batch occupancy, eviction/expiry counters, the rows a
 model's query reads by kind where the model counts them (``cache_rows``:
-EVA's window positions and compressed rows), plus periodic ``decode``
+EVA's window positions and compressed rows, the K/V positions of the LM's
+live pages), plus periodic ``decode``
 JSONL records in ``<obs_dir>/decode.jsonl``.
 
 **The loop measured from inside** (ISSUE 37). The engine owns one
