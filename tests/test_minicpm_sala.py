@@ -144,8 +144,8 @@ def test_the_programs_with_the_kernels_equal_the_programs_with_their_twins():
     rng = np.random.default_rng(3)
     tokens = jnp.asarray(rng.integers(0, 512, size=96).astype(np.int32))
 
-    def twin(q, k, v, mask, tiles, counts, *, scale, block, tq, tk):
-        return pl_sparse.sparse_prefill_reference(q, k, v, mask, scale=scale, block=block)
+    def twin(q, k, v, mask, n_real, steps, *, block, tq, tk):
+        return pl_sparse.sparse_prefill_reference(q, k, v, mask, n_real, block=block)
 
     with jax.default_matmul_precision("highest"):
         a, _, states = model._prompt(params, tokens, 77)
@@ -249,26 +249,48 @@ def test_sparse_cache_write_puts_each_row_into_its_page_and_touches_nothing_else
     assert np.array_equal(np.asarray(v2), np.asarray(v_pool.at[:, write_page, lens % 8].set(rows[1])))
 
 
+@pytest.mark.parametrize("n_real", [48, 37, 32])  # the bucket, inside a query tile, on a tile boundary
 @pytest.mark.parametrize("tq,tk", [(8, 8), (16, 8), (8, 16)])
-def test_sparse_prefill_equals_its_twin_and_visits_only_the_tiles_some_row_sees(tq, tk):
-    G, R, T, D, block = 2, 4, 32, 16, 4
+def test_sparse_prefill_equals_its_twin_and_visits_only_the_tiles_some_row_sees(tq, tk, n_real):
+    G, R, T, D, block = 2, 4, 48, 16, 4
     keys = jax.random.split(jax.random.PRNGKey(1), 3)
     rng = np.random.default_rng(0)
-    q = jax.random.normal(keys[0], (G, R, T, D))
+    q = 0.25 * jax.random.normal(keys[0], (G, R, T, D))
     k, v = (jax.random.normal(key, (G, T, D)) for key in keys[1:])
     own = np.arange(T) // block
     seen = (rng.random((G, T, T // block)) < 0.3) & (np.arange(T // block)[None, None, :] <= own[None, :, None])
+    seen[:, 17, 0] = True  # rows 17 and 18 share a query tile: 17 sees block 0, 18 its own block alone
+    seen[:, 18, :] = False
     seen[:, np.arange(T), own] = True  # every row sees its own block
     mask = np.zeros((G, T, 128), np.float32)
     mask[:, :, :T // block] = seen
-    tiles, counts = pl_sparse.tile_lists(jnp.asarray(mask), tq, tk, block)
-    a = pl_sparse.sparse_prefill(q, k, v, jnp.asarray(mask), tiles, counts, scale=0.25, block=block, tq=tq, tk=tk)
-    b = pl_sparse.sparse_prefill_reference(q, k, v, jnp.asarray(mask), scale=0.25, block=block)
-    assert np.abs(np.asarray(a - b)).max() < 1e-5
-    by_tile = seen.reshape(G, T // tq, tq, T // tk, tk // block).any(axis=(2, 4))
-    assert np.array_equal(np.asarray(counts), by_tile.sum(-1)) and counts.sum() < G * (T // tq) * (T // tk)
-    for g, i in ((0, 1), (1, T // tq - 1)):
-        assert list(np.asarray(tiles[g, i, :counts[g, i]])) == list(np.nonzero(by_tile[g, i])[0])
+    rows, cols, live, masked = pl_sparse.tile_lists(jnp.asarray(mask), n_real, tq, tk, block)
+    a = np.asarray(pl_sparse.sparse_prefill(q, k, v, jnp.asarray(mask), n_real, (rows, cols), block=block,
+                                            tq=tq, tk=tk))
+    b = np.asarray(pl_sparse.sparse_prefill_reference(q, k, v, jnp.asarray(mask), n_real, block=block))
+    assert np.abs(a - b)[:, :, :n_real].max() < 1e-5
+    assert not a[:, :, n_real:].any() and not b[:, :, n_real:].any()  # rows past the real length are zero
+    # the schedule: every live pair once, query tile by query tile, then nothing
+    nq, nk = T // tq, T // tk
+    real = (np.arange(T) < n_real)[None, :, None]
+    by_tile = (seen & real).reshape(G, nq, tq, nk, tk // block).any(axis=(2, 4))
+    whole = seen.reshape(G, nq, tq, nk, tk // block).all(axis=(2, 4))
+    diag = (np.arange(nk)[None, :] + 1) * tk - 1 > np.arange(nq)[:, None] * tq
+    assert int(live) == by_tile.sum() and int(masked) == (by_tile & (~whole | diag)).sum()
+    rows, cols = np.asarray(rows), np.asarray(cols)
+    flags, tiles = cols & 31, cols >> 5
+    padding = -(-n_real // tq)  # the first query tile past the real rows
+    working = by_tile.sum() + G * (nq - padding)
+    assert len(rows) == G * pl_sparse.causal_steps(T, tq, tk) and (flags[:working] != 0).all()
+    assert not flags[working:].any() and (rows[working:] == rows[working - 1]).all()
+    assert (flags & 1).sum() == by_tile.sum()
+    for g in range(G):
+        for i in range(nq):
+            at = rows[:working] == g << 16 | i
+            want = list(np.nonzero(by_tile[g, i])[0]) if i < padding else [0]
+            assert list(tiles[:working][at]) == want and (flags[:working][at] & 1 == (i < padding)).all()
+    # row 18's first listed tile holds none of its blocks: the fold's one select must wipe what it sums
+    assert by_tile[0, 18 // tq, 0] and not seen[0, 18, :tk // block].any()
 
 
 def test_the_visible_blocks_are_the_first_the_windows_and_the_two_best_of_the_rest():

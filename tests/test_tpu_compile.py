@@ -516,12 +516,14 @@ def test_minicpm_sala_decode_step_walks_chosen_pages_and_updates_every_pool_in_p
 def test_minicpm_sala_prefill_of_16384_positions_holds_no_square_of_the_prompt(
         one_chip, no_persistent_cache, monkeypatch):
     """One bucket of 16,384 rows for its slot at a traced real length: two
-    ``sparse_prefill`` flash passes under a per-row block mask, six
-    ``lightning_chunk`` scans, whole pages and the slot's rows written to the
-    donated pools; 1.58 GB of temporaries beside 5.64 GB of weights (1.72
-    with the ``jnp`` scans, whose ``[128, 32, 128, 128]`` fp32 arrays are
-    gone, as are the rotation's halves). Nothing is shaped ``[.., T, T]``: shown on a bucket of 8,192,
-    because at 16,384 the SwiGLU's ``[T, 16384]`` is a square by accident."""
+    ``sparse_prefill`` flash passes over their lists of live tile pairs (a
+    grid of 8,448 steps a layer at K/V tiles of 512, two int32 lists of
+    them prefetched), six ``lightning_chunk`` scans, whole pages and the
+    slot's rows written to the donated pools; 1.576 GB of temporaries
+    beside 5.64 GB of weights (1.72 with the ``jnp`` scans, whose ``[128,
+    32, 128, 128]`` fp32 arrays are gone, as are the rotation's halves).
+    Nothing is shaped ``[.., T, T]``: shown on a bucket of 8,192, because at
+    16,384 the SwiGLU's ``[T, 16384]`` is a square by accident."""
     model, page, params, k_pool, held, on_chip, _ = _minicpm_sala_decode(one_chip, monkeypatch)
 
     def prefill(p, t, pg, k, v, slot, n_real):

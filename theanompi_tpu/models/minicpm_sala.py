@@ -306,35 +306,44 @@ class MiniCPMSALA(Model):
         return float(np.mean((blocks * B - (B - 1 - t % B)) / (t + 1.0)))
 
     # -- the prompt's forms ------------------------------------------------
-    def _sparse_prompt(self, p, h, attend=sparse_prefill):
-        """One prompt's ``minicpm4`` layer: ``h [T, d]`` -> (output ``[T, d]``,
-        ``k``, ``v`` ``[T, G D]`` and the compressed keys ``[nC, G D]`` for the
-        cache)."""
+    def _prompt_blocks(self, q, k):
+        """A prompt's selection: ``q [T, H, D]`` and ``k [T, G, D]`` (normed,
+        the compute dtype) -> (the blocks each row sees, ``[T, G, T / block]``
+        bool, and the compressed keys ``[nC, G D]``)."""
         r = self.recipe
-        G, D, B, dt = r.n_kv_heads, r.head_dim, r.sparse_block, r.compute_dtype
-        R, T = r.n_heads // G, h.shape[0]
-        q, k, v, gate = self._qkv(p, h, G)
-        q, k = q.astype(dt), k.astype(dt)
+        G, D, dt, T = r.n_kv_heads, r.head_dim, r.compute_dtype, q.shape[0]
         st, over = r.sparse_stride, r.sparse_kernel // r.sparse_stride
         strides = jnp.mean(k.astype(jnp.float32).reshape(T // st, st, G, D), axis=1)
         nC = T // st - over + 1
         ck = (sum(strides[i:i + nC] for i in range(over)) / over).astype(dt).reshape(nC, G * D)
-        nB = T // B
-        rows = min(r.select_rows, T)
-        qg = q.reshape(T // rows, rows, G, R, D)
+        nB, rows = T // r.sparse_block, min(r.select_rows, T)
+        qg = q.reshape(T // rows, rows, G, r.n_heads // G, D)
 
         def some_rows(x):
             q_rows, first = x
             return self._visible(q_rows, ck, first + jnp.arange(rows), nB)
 
-        seen = jax.lax.map(some_rows, (qg, jnp.arange(0, T, rows))).reshape(T, G, nB)
+        return jax.lax.map(some_rows, (qg, jnp.arange(0, T, rows))).reshape(T, G, nB), ck
+
+    def _sparse_prompt(self, p, h, n_real, attend=sparse_prefill):
+        """One prompt's ``minicpm4`` layer: ``h [T, d]`` -> (output ``[T, d]``,
+        its rows at or past ``n_real`` zero, ``k``, ``v`` ``[T, G D]`` and the
+        compressed keys ``[nC, G D]`` for the cache)."""
+        r = self.recipe
+        G, D, B, dt = r.n_kv_heads, r.head_dim, r.sparse_block, r.compute_dtype
+        R, T = r.n_heads // G, h.shape[0]
+        q, k, v, gate = self._qkv(p, h, G)
+        scaled = (q * D ** -0.5).astype(dt)  # the softmax's scale on q in fp32, before its cast
+        q, k = q.astype(dt), k.astype(dt)
+        seen, ck = self._prompt_blocks(q, k)
+        nB = T // B
         with jax.named_scope("sparse_prefill"):
             mask = jnp.swapaxes(seen, 0, 1).astype(dt)  # [G, T, nB]
             mask = jnp.pad(mask, ((0, 0), (0, 0), (0, -nB % 128)))  # whole lanes
             tq, tk = min(r.prefill_tq, T), min(r.prefill_tk, T)
-            tiles, counts = tile_lists(mask, tq, tk, B)
-            o = attend(jnp.transpose(q.reshape(T, G, R, D), (1, 2, 0, 3)), jnp.swapaxes(k, 0, 1),
-                       jnp.swapaxes(v, 0, 1), mask, tiles, counts, scale=D ** -0.5, block=B, tq=tq, tk=tk)
+            steps = tile_lists(mask, n_real, tq, tk, B)
+            o = attend(jnp.transpose(scaled.reshape(T, G, R, D), (1, 2, 0, 3)), jnp.swapaxes(k, 0, 1),
+                       jnp.swapaxes(v, 0, 1), mask, n_real, steps, block=B, tq=tq, tk=tk)
             o = jnp.transpose(o, (2, 0, 1, 3)).reshape(T, -1)
         return self._out(p, o.astype(jnp.float32) * gate), k.reshape(T, G * D), v.reshape(T, G * D), ck
 
@@ -357,7 +366,7 @@ class MiniCPMSALA(Model):
         for kind, p in zip(r.mixer_types, params["layers"]):
             h = _rms(x, p["norm_1"], r.rms_eps)
             if kind == SPARSE:
-                a, *cached = self._sparse_prompt(p["mixer"], h, attend)
+                a, *cached = self._sparse_prompt(p["mixer"], h, n_real, attend)
                 paged.append(cached)
             else:
                 a, state = self._lightning_prompt(p["mixer"], h, n_real, scan)
@@ -546,6 +555,6 @@ class MiniCPM_SALA_Stage8(MiniCPMSALA):
             mixer_types=(SPARSE, LIGHTNING, LIGHTNING, LIGHTNING) * 2, depth_published=32,
             scale_emb=12.0, scale_depth=1.4, dim_model_base=256, rope_theta=10000.0, rms_eps=1e-6,
             sparse_kernel=32, sparse_stride=16, sparse_block=64, sparse_topk=64,
-            sparse_init_blocks=1, sparse_window=2048, chunk=128, prefill_tq=64, prefill_tk=256,
+            sparse_init_blocks=1, sparse_window=2048, chunk=128, prefill_tq=64, prefill_tk=512,
             select_rows=1024,
         )

@@ -30,17 +30,23 @@ takes head ``g`` of a page as the block ``(page, D)`` at lane block ``g``.
   scatter of single positions).
 - :func:`sparse_prefill`: one prompt, every row its own set of visible
   blocks (``blockmask [G, T, NB]``, 0 or 1, the block-level causal rule
-  already in it). A flash pass over ``(K/V head, query tile, K/V tile)``
-  whose K/V tiles are the LIST of tiles that some row of the query tile sees
-  (:func:`tile_lists`, prefetched scalars): the others are neither read nor
-  computed. Inside a tile the row's blocks are widened to positions by one
-  small product with a 0/1 matrix made from iotas, and the causal rule by
-  position is added. No ``[T, T]`` array exists.
+  already in it, every real row seeing its own block). A flash pass whose
+  grid is the LIST of live (K/V head, query tile, K/V tile) pairs,
+  :func:`tile_lists`' prefetched scalars: a pair is live where some row below
+  the prompt's real length ``n_real`` sees a block of the K/V tile, and no
+  other pair is read, computed or stepped over. Rows at or past ``n_real``
+  come out ZERO. A tile's mask is made only where it is needed: the row's
+  blocks widened to positions by one small product with a 0/1 matrix made
+  from iotas where some row misses a block of the tile, the causal rule by
+  position where the tile crosses the diagonal, one select over the scores
+  of the ``R`` heads; none on a tile every row sees whole. No ``[T, T]``
+  array exists.
 
-Numerics: products in the input dtype on the MXU with fp32 accumulation,
-softmax statistics in fp32, probabilities cast to the input dtype for the
-second product. Off-TPU the kernels run through the Pallas interpreter; each
-has a ``jnp`` twin below (``*_reference``), the CPU tests' oracle.
+Numerics: products in the input dtype on the MXU with fp32 accumulation
+(the prefill takes the softmax's scale in ``q``), softmax statistics in
+fp32, probabilities cast to the input dtype for the second product.
+Off-TPU the kernels run through the Pallas interpreter; each has a ``jnp``
+twin below (``*_reference``), the CPU tests' oracle.
 """
 
 from __future__ import annotations
@@ -234,113 +240,198 @@ def sparse_cache_write(
 
 
 # -- prefill ------------------------------------------------------------------
-def tile_lists(blockmask, tq: int, tk: int, block: int):
-    """``blockmask [G, T, NB]`` (0 or 1; columns past ``T / block`` are
-    padding) -> (``tiles [G, T / tq, T / tk]`` int32: the K/V tiles that some
-    row of the query tile sees, in order, first; ``counts [G, T / tq]``)."""
+# a grid step's flags: the low _FLAG_BITS of its ``cols`` entry, the K/V tile above them
+_LIVE = 1  # a tile to fold
+_FIRST, _LAST = 2, 4  # the query tile's first and last step
+_BLOCKS = 8  # some row misses a block of the tile: the blocks widened to positions
+_CAUSAL = 16  # a key of the tile lies past a row: the causal compare
+_FLAG_BITS = 5
+_TILE_BITS = 16  # ``rows``: the K/V head above them, the query tile below (shifts, no division)
+
+
+def causal_steps(T: int, tq: int, tk: int) -> int:
+    """The prefill grid's length a K/V head: the (query tile, K/V tile)
+    pairs on or below the diagonal, the most a block-causal mask can make
+    live."""
+    return sum(min(T // tk, (i * tq + tq - 1) // tk + 1) for i in range(T // tq))
+
+
+def tile_lists(blockmask, n_real, tq: int, tk: int, block: int):
+    """The prefill's schedule. ``blockmask [G, T, NB]`` (0 or 1, block-causal;
+    columns past ``T / block`` are padding), ``n_real`` the prompt's real
+    length -> ``(rows, cols, live, masked)``: ``rows, cols [G * causal_steps]``
+    int32, one grid step each: ``rows`` the K/V head and the query tile,
+    ``g << 16 | i``, ``cols`` the K/V tile and the step's flags, ``j << 5 |
+    flags``. The live pairs come query tile by query tile, K/V tiles in
+    order: a pair is live where some row below ``n_real`` sees a block of the
+    tile. A query tile whose
+    first row is at or past ``n_real`` takes one step that writes zeros; the
+    steps past the last repeat its tiles and do nothing. ``live``: the live
+    pairs; ``masked``: those that need a mask (a row misses a block of the
+    tile, or the tile crosses the diagonal)."""
     G, T, _ = blockmask.shape
     nq, nk, per = T // tq, T // tk, tk // block
-    seen = blockmask[:, :, :nk * per].reshape(G, nq, tq, nk, per) > 0
-    seen = jnp.any(seen, axis=(2, 4))  # [G, nq, nk]
-    order = jnp.argsort(~seen, axis=-1, stable=True)
-    return order.astype(jnp.int32), jnp.sum(seen, axis=-1).astype(jnp.int32)
+    first_row = jnp.arange(nq) * tq
+    bm = blockmask[:, :, :nk * per].reshape(G, nq, tq, nk, per) > 0
+    real = (jnp.arange(T) < n_real).reshape(1, nq, tq, 1, 1)
+    under = jnp.arange(nk)[None, :] * tk <= first_row[:, None] + tq - 1  # [nq, nk] on or below the diagonal
+    live = (jnp.any(bm & real, axis=(2, 4)) & under).reshape(G * nq, nk)
+    diag = (jnp.arange(nk)[None, :] + 1) * tk - 1 > first_row[:, None]
+    masked = (~jnp.all(bm, axis=(2, 4)) * _BLOCKS + diag * _CAUSAL).reshape(G * nq, nk)
+    padding = jnp.tile(first_row >= n_real, G)  # [G * nq]
+    count = jnp.where(padding, 1, jnp.sum(live, axis=-1))
+    end = jnp.cumsum(count)
+    step = jnp.arange(G * causal_steps(T, tq, tk))
+    tile = jnp.minimum(jnp.searchsorted(end, step, side="right"), G * nq - 1)
+    at = step - (end - count)[tile]
+    j = jnp.argsort(~live, axis=-1, stable=True)[tile, jnp.minimum(at, count[tile] - 1)]
+    flags = (_FIRST * (at == 0) + _LAST * (at == count[tile] - 1)
+             + jnp.where(padding[tile], 0, _LIVE + masked[tile, j]))
+    flags = jnp.where(step < end[-1], flags, 0)
+    rows = (tile // nq << _TILE_BITS | tile % nq).astype(jnp.int32)
+    return (rows, (j << _FLAG_BITS | flags).astype(jnp.int32), jnp.sum(live).astype(jnp.int32),
+            jnp.sum(live & (masked > 0)).astype(jnp.int32))
 
 
-def _prefill_kernel(scale, block, tq, tk, R, nq, tiles_ref, cnt_ref, q_ref, k_ref, v_ref, bm_ref,
-                    o_ref, m_ref, l_ref, acc_ref):
-    i, step = pl.program_id(1), pl.program_id(2)
-    row = pl.program_id(0) * nq + i
+_LANES = 128  # the prefill's running maximum and normaliser, the same in every lane
+
+
+def _lanes(x, width: int):
+    """``[M, 128]`` the same in every lane -> ``[M, width]``."""
+    return jnp.tile(x, (1, width // _LANES)) if width % _LANES == 0 else x[:, :width]
+
+
+def _prefill_kernel(block, tq, tk, R, rows_ref, cols_ref, n_ref, q_ref, k_ref, v_ref, bm_ref,
+                    o_ref, m_ref, l_ref, acc_ref, vis_ref):
+    step = pl.program_id(0)
+    i, col = rows_ref[step] & ((1 << _TILE_BITS) - 1), cols_ref[step]
+    j, flags = col >> _FLAG_BITS, col & ((1 << _FLAG_BITS) - 1)
     D = q_ref.shape[-1]
 
-    @pl.when(step == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(step < cnt_ref[row])
-    def _tile():
-        first = tiles_ref[row, step] * tk  # the tile's first key position
-        q = q_ref[...].reshape(R * tq, D)
-        k, v = k_ref[...], v_ref[...]  # [tk, D]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        # the row's visible blocks widened to this tile's positions: one
-        # product with the 0/1 matrix "position c lies in block b"
-        bm = bm_ref[...]  # [tq, NB]
-        nb = bm.shape[-1]
-        at = first + lax.broadcasted_iota(jnp.int32, (nb, tk), 1)
-        lo = lax.broadcasted_iota(jnp.int32, (nb, tk), 0) * block
-        widen = ((at >= lo) & (at < lo + block)).astype(bm.dtype)
-        by_block = lax.dot_general(bm, widen, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32) > 0.5
-        key_pos = first + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        row_pos = i * tq + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-        seen = by_block & (key_pos <= row_pos)  # [tq, tk], the same for the R heads
-        seen = jnp.broadcast_to(seen[None], (R, tq, tk)).reshape(R * tq, tk)
-        _online_softmax_step(s, seen, v, m_ref, l_ref, acc_ref)
+    def fold(s, v):
+        """One tile of scores into the running statistics, which are held
+        the same in every lane (no lane broadcast a tile). A masked score is
+        ``_NEG``: exp of it is 0 once the row has seen a key, and a row that
+        has seen none yet keeps ``m = _NEG``, so what it sums is wiped by
+        ``corr = 0`` at its first seen key."""
+        m = m_ref[...]  # [R tq, 128]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, s.shape[-1]))
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(corr, D) + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when((flags & _LIVE) != 0)
+    def _tile():
+        q = q_ref[...].reshape(R * tq, D)  # the softmax's scale is in q
+        v = v_ref[...]
+        s = lax.dot_general(q, k_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [R tq, tk]
+
+        @pl.when((flags & (_BLOCKS | _CAUSAL)) == 0)
+        def _whole():
+            fold(s, v)
+
+        @pl.when((flags & (_BLOCKS | _CAUSAL)) != 0)
+        def _masked():
+            vis_ref[...] = jnp.ones_like(vis_ref)
+
+            @pl.when((flags & _BLOCKS) != 0)
+            def _blocks():
+                # the row's blocks widened to the tile's positions: one product
+                # with the 0/1 matrix "position c lies in block b"
+                bm = bm_ref[...]  # [tq, NB]
+                in_block = j * (tk // block) + lax.broadcasted_iota(jnp.int32, (bm.shape[-1], tk), 1) // block
+                widen = (lax.broadcasted_iota(jnp.int32, in_block.shape, 0) == in_block).astype(bm.dtype)
+                vis_ref[...] = lax.dot_general(bm, widen, (((1,), (0,)), ((), ())),
+                                               preferred_element_type=jnp.float32)
+
+            @pl.when((flags & _CAUSAL) != 0)
+            def _causal():
+                key = j * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+                row = i * tq + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+                vis_ref[...] = jnp.where(key <= row, vis_ref[...], 0.0)
+
+            seen = vis_ref[...] > 0.5  # [tq, tk], the same for the R heads
+            fold(jnp.where(seen[None], s.reshape(R, tq, tk), _NEG).reshape(R * tq, tk), v)
+
+    @pl.when((flags & _LAST) != 0)
     def _out():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).reshape(R, tq, D).astype(o_ref.dtype)
+        o = (acc_ref[...] / _lanes(l_ref[...], D)).reshape(R, tq, D)
+        row = i * tq + lax.broadcasted_iota(jnp.int32, o.shape, 1)
+        o_ref[...] = jnp.where(row < n_ref[0], o, 0.0).astype(o_ref.dtype)
 
 
 def sparse_prefill(
-    q: jax.Array,  # [G, R, T, D]
+    q: jax.Array,  # [G, R, T, D] times the softmax's scale
     k: jax.Array,  # [G, T, D]
     v: jax.Array,
     blockmask: jax.Array,  # [G, T, NB] 0 or 1 in q's dtype: row t of head g sees block b
-    tiles: jax.Array,  # [G, T / tq, T / tk] int32 (tile_lists)
-    counts: jax.Array,  # [G, T / tq] int32
+    n_real,  # int32: rows at or past it come out zero
+    steps,  # (rows, cols, ...) of tile_lists
     *,
-    scale: float,
     block: int,
     tq: int,
     tk: int,
 ) -> jax.Array:
-    """``o [G, R, T, D]``: row ``t`` attends over the positions ``<= t`` of
-    its visible blocks. Every row must see its own block (the kernel divides
-    by the row's normaliser)."""
+    """``o [G, R, T, D]``: row ``t < n_real`` attends over the positions
+    ``<= t`` of its visible blocks; rows at or past ``n_real`` are zero.
+    Every real row must see its own block (the kernel divides by the row's
+    normaliser). One grid step a live (query tile, K/V tile) pair."""
     from jax.experimental.pallas import tpu as pltpu
 
     G, R, T, D = q.shape
     NB = blockmask.shape[-1]
     if T % tq or T % tk or tk % block:
         raise ValueError(f"{T} positions are no whole tiles of {tq} rows and {tk} keys of blocks of {block}")
-    nq, nk = T // tq, T // tk
+    rows, cols = steps[0], steps[1]
+    low = (1 << _TILE_BITS) - 1
 
-    def kv_at(g, i, j, tiles, cnt):
-        row = g * nq + i
-        return (g, tiles[row, jnp.minimum(j, jnp.maximum(cnt[row] - 1, 0))], 0)
+    def q_at(s, rows, cols, n):
+        return (rows[s] >> _TILE_BITS, 0, rows[s] & low, 0)
 
-    rows = lambda g, i, j, *_: (g, 0, i, 0)  # noqa: E731
+    def kv_at(s, rows, cols, n):
+        return (rows[s] >> _TILE_BITS, cols[s] >> _FLAG_BITS, 0)
+
+    def bm_at(s, rows, cols, n):
+        return (rows[s] >> _TILE_BITS, rows[s] & low, 0)
+
     return pl.pallas_call(
-        functools.partial(_prefill_kernel, float(scale), int(block), tq, tk, R, nq),
+        functools.partial(_prefill_kernel, int(block), tq, tk, R),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(G, nq, nk),
-            in_specs=[pl.BlockSpec((None, R, tq, D), rows),
+            num_scalar_prefetch=3,
+            grid=(rows.shape[0],),
+            in_specs=[pl.BlockSpec((None, R, tq, D), q_at),
                       pl.BlockSpec((None, tk, D), kv_at), pl.BlockSpec((None, tk, D), kv_at),
-                      pl.BlockSpec((None, tq, NB), lambda g, i, j, *_: (g, i, 0))],
-            out_specs=pl.BlockSpec((None, R, tq, D), rows),
-            scratch_shapes=[pltpu.VMEM((R * tq, 1), jnp.float32), pltpu.VMEM((R * tq, 1), jnp.float32),
-                            pltpu.VMEM((R * tq, D), jnp.float32)],
+                      pl.BlockSpec((None, tq, NB), bm_at)],
+            out_specs=pl.BlockSpec((None, R, tq, D), q_at),
+            scratch_shapes=[pltpu.VMEM((R * tq, _LANES), jnp.float32), pltpu.VMEM((R * tq, _LANES), jnp.float32),
+                            pltpu.VMEM((R * tq, D), jnp.float32), pltpu.VMEM((tq, tk), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         name=PREFILL_NAME,
         interpret=_interpret(),
-    )(tiles.reshape(G * nq, nk).astype(jnp.int32), counts.reshape(G * nq).astype(jnp.int32),
-      q, k, v, blockmask)
+    )(rows, cols, jnp.asarray(n_real, jnp.int32).reshape(1), q, k, v, blockmask)
 
 
-def sparse_prefill_reference(q, k, v, blockmask, *, scale: float, block: int):
+def sparse_prefill_reference(q, k, v, blockmask, n_real, *, block: int):
     """The prefill kernel's ``jnp`` twin: the whole ``[T, T]`` mask (small
-    sizes only); fp32 throughout."""
+    sizes only); fp32 throughout; ``q`` holds the scale, rows at or past
+    ``n_real`` are zero."""
     G, R, T, D = q.shape
     f32, hi = jnp.float32, lax.Precision.HIGHEST
     pos = jnp.arange(T)
     by_block = jnp.take(blockmask > 0, pos // block, axis=-1)  # [G, T, T]
     seen = by_block & (pos[None, :] <= pos[:, None])[None]
-    s = jnp.einsum("grtd,gsd->grts", q.astype(f32), k.astype(f32), precision=hi) * scale
+    s = jnp.einsum("grtd,gsd->grts", q.astype(f32), k.astype(f32), precision=hi)
     p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), axis=-1)
-    return jnp.einsum("grts,gsd->grtd", p, v.astype(f32), precision=hi).astype(q.dtype)
+    o = jnp.einsum("grts,gsd->grtd", p, v.astype(f32), precision=hi)
+    return jnp.where((pos < n_real)[:, None], o, 0.0).astype(q.dtype)
