@@ -215,14 +215,14 @@ def _train_rows(run_dir: str, run_name: str) -> dict[int, dict]:
 
 def exp_wrn_tpu() -> list[dict]:
     """The WRN recipe ON THE REAL TPU with the production hot path active
-    (round-4 verdict item 1): bf16 compute, fused 4-step dispatch,
-    augmentation, 10-crop val, and a checkpointed mid-run resume — the
-    production code path carried to an accuracy number instead of a
-    perf sample. A same-seed
-    single-device CPU run in f32 per-step dispatch is the trusted-math
-    reference curve; results/wrn_tpu_vs_cpu.json quantifies divergence
-    (bf16 + platform + fusion, jointly — each alone is below the run-to-
-    run noise of the task). Converged = final 10-crop val error <= 8%
+    (round-4 verdict item 1): bf16 compute, augmentation, 10-crop val,
+    and a checkpointed mid-run resume — the production code path carried
+    to an accuracy number instead of a perf sample. A same-seed
+    single-device CPU run in f32 is the trusted-math reference curve;
+    results/wrn_tpu_vs_cpu.json quantifies divergence (bf16 + platform,
+    jointly — each alone is below the run-to-run noise of the task; the
+    committed result's run also fused 4 steps a dispatch, an option
+    since removed). Converged = final 10-crop val error <= 8%
     on BOTH paths (the SURVEY §4 convergence-curve validation applied to
     the TPU hot path)."""
     os.makedirs(RESULTS, exist_ok=True)
@@ -248,7 +248,6 @@ def exp_wrn_tpu() -> list[dict]:
         common,
         recipe_overrides={**common["recipe_overrides"],
                           "compute_dtype": "bfloat16"},
-        steps_per_dispatch=4,
         ckpt_dir=ck,
         ckpt_every_epochs=2,
         async_checkpoint=False,
@@ -283,7 +282,7 @@ def exp_wrn_tpu() -> list[dict]:
         for d, s in zip(dloss, steps)
     ]
     cmp_out = {
-        "tpu": {"path": "bf16 compute + fused 4-step dispatch, 1x v5e",
+        "tpu": {"path": "bf16 compute + per-step dispatch, 1x v5e",
                 "val": out["val"], "resumed_from_step": 44},
         "cpu": {"path": "f32 per-step dispatch, 1-device CPU mesh",
                 "val": ref["val"]},

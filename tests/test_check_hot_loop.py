@@ -25,7 +25,7 @@ def run_training():
         state, metrics = step(state, xg)
         loss = float(metrics["loss"])  # the per-step sync, reborn
         v = metrics["lr"].item()
-    for xs in loader:  # fused path
+    for xs in loader:  # a second loop is linted too
         import numpy as np
         mh = {k: np.asarray(v) for k, v in metrics.items()}
         block_until_ready(metrics)  # bare from-import form
@@ -45,8 +45,8 @@ def test_live_worker_source_is_clean():
     with open(WORKER_PATH) as f:
         src = f.read()
     assert check_source(src) == []
-    # the lint actually found both train loops (anchor guard)
-    assert len(train_loop_segments(src)) >= 2
+    # the lint actually found the train loop (anchor guard)
+    assert len(train_loop_segments(src)) == 1
 
 
 def test_violations_detected_per_line():

@@ -155,24 +155,18 @@ def test_wait_time_subtracted_from_attribution():
     assert dt < 0.04  # wait excluded: attributed step time ~ 0
 
 
-def test_fused_group_rows_expand_with_final_row_attribution():
-    rec = FakeRecorder()
-    disp = MetricsDispatcher(rec, depth=1)
-    stacked = {"loss": np.array([1.0, 2.0, 3.0]), "lr": np.array([4.0, 5.0, 6.0])}
-    disp.push(6, stacked, n_images=96, substeps=3)
-    assert [r[0] for r in rec.rows] == [4, 5, 6]
-    assert [r[1]["loss"] for r in rec.rows] == [1.0, 2.0, 3.0]
-    # group throughput attributed to the final substep row only
-    assert [r[2] for r in rec.rows] == [0, 0, 96]
-    assert len(rec.times) == 1  # one timing per dispatch entry
-
-
 def test_on_step_seconds_callback_fires_at_sync():
     seen = []
-    disp = MetricsDispatcher(FakeRecorder(), depth=1,
-                             on_step_seconds=seen.append)
-    disp.push(2, {"loss": np.array([1.0, 2.0])}, substeps=2)
+    rec = FakeRecorder()
+    disp = MetricsDispatcher(rec, depth=2, on_step_seconds=seen.append)
+    disp.push(1, {"loss": np.float32(1.0)})
+    assert seen == []  # in flight: no sync yet
+    disp.push(2, {"loss": np.float32(2.0)})  # drains step 1
     assert len(seen) == 1 and seen[0] >= 0.0
+    # the step's own seconds: what its timing entry holds
+    assert seen[0] == rec.times[0][1] == disp.last_step_seconds
+    disp.flush()
+    assert len(seen) == 2 and seen[1] == rec.times[1][1]
 
 
 # -- drain equivalence: async JSONL bit-identical to sync -------------------
@@ -259,9 +253,8 @@ def test_the_default_is_two_steps_in_flight():
     assert args.dispatch_depth == 1  # the classic sync stays a legal value
 
 
-@pytest.mark.parametrize("fuse", [1, 2], ids=["per_step", "fused2"])
 def test_default_run_keeps_one_entry_in_flight_and_emits_depth1s_rows(
-        tmp_path, monkeypatch, fuse):
+        tmp_path, monkeypatch):
     in_flight = []
     push = MetricsDispatcher.push
 
@@ -270,40 +263,37 @@ def test_default_run_keeps_one_entry_in_flight_and_emits_depth1s_rows(
         in_flight.append(disp.in_flight)
 
     monkeypatch.setattr(MetricsDispatcher, "push", spying_push)
-    kw = dict(rule="bsp", n_epochs=2, steps_per_dispatch=fuse,
+    kw = dict(rule="bsp", n_epochs=2,
               dataset_kwargs={**_TINY["dataset_kwargs"], "n_train": 128})
     s2, r2 = _run(tmp_path, "default", None, **kw)
     assert s2["dispatch_depth"] == 2 and s2["steps"] == 8
-    assert in_flight == [1] * (8 // fuse)
+    assert in_flight == [1] * 8
     in_flight.clear()
     s1, r1 = _run(tmp_path, "sync", 1, **kw)
-    assert s1["dispatch_depth"] == 1 and in_flight == [0] * (8 // fuse)
+    assert s1["dispatch_depth"] == 1 and in_flight == [0] * 8
     # the same rows (steps, values, n_images), one step later
     assert r1 == r2
     assert s1["dispatch_ahead_share"] == 0.0
 
 
-@pytest.mark.parametrize("fuse,ready,want", [
-    (1, True, 0.0), (1, False, 6 / 8), (2, False, 2 / 4)],
-    ids=["ready_at_once", "pending_until_drained", "fused2_pending"])
-def test_a_runs_dispatch_ahead_share(monkeypatch, fuse, ready, want):
+@pytest.mark.parametrize("ready,want", [(True, 0.0), (False, 6 / 8)],
+                         ids=["ready_at_once", "pending_until_drained"])
+def test_a_runs_dispatch_ahead_share(monkeypatch, ready, want):
     """2 epochs of 4 steps at the default depth: every dispatch but the
     first after each boundary's flush finds the step before still running,
     when its results are not ready until drained; none does when they are
     ready at once."""
     from theanompi_tpu.parallel.bsp import BSPEngine
 
-    name = "train_step" if fuse == 1 else "fused_train_step"
-    orig = getattr(BSPEngine, name)
+    orig = BSPEngine.train_step
 
     def step(engine, state, images, labels, rng, numerics=False):
         state, metrics = orig(engine, state, images, labels, rng, numerics=numerics)
         return state, {k: _Pending(v, ready) for k, v in metrics.items()}
 
-    monkeypatch.setattr(BSPEngine, name, step)
+    monkeypatch.setattr(BSPEngine, "train_step", step)
     summary = run_training(
         model_cls=TinyCNN, devices=8, rule="bsp", n_epochs=2,
-        steps_per_dispatch=fuse,
         **{**_TINY, "dataset_kwargs": {**_TINY["dataset_kwargs"], "n_train": 128}})
     assert summary["steps"] == 8
     assert summary["dispatch_ahead_share"] == pytest.approx(want)

@@ -4,8 +4,8 @@ name on every Pallas kernel.
 
 ``run_training`` on a tiny model: every step has exactly one ``wait``,
 ``dispatch``, ``key_split`` (ISSUE 27: the next step's keys, split under the
-step just dispatched), ``drain`` and ``emit`` span under its own number (a
-fused group: under its last), on one clock, none overlapping.
+step just dispatched), ``drain`` and ``emit`` span under its own number, on one clock, none
+overlapping.
 """
 
 import ast
@@ -46,13 +46,6 @@ _TINY = dict(
 def per_step(request):
     summary = run_training(dispatch_depth=request.param, **_TINY)
     return request.param, summary, summary.pop("recorder")
-
-
-@pytest.fixture(scope="module", params=[1, None], ids=["depth1", "default_depth"])
-def fused(request):
-    depth = {} if request.param is None else {"dispatch_depth": request.param}
-    summary = run_training(steps_per_dispatch=2, **depth, **_TINY)
-    return summary, summary.pop("recorder")
 
 
 def _spans(rec, names=FIVE):
@@ -131,28 +124,6 @@ def test_host_blocked_is_the_sum_of_the_drain_spans(per_step):
     _, _, dur = rec.span_rings["drain"].held()
     assert summary["host_blocked_s"] == pytest.approx(1e-9 * float(dur.sum()), abs=2e-6)
     assert sum(rec.timings["drain"]) == pytest.approx(1e-9 * float(dur.sum()), rel=1e-9)
-
-
-def test_a_fused_group_has_one_span_of_each_name_under_its_last_step(fused):
-    summary, rec = fused
-    assert summary["steps"] == STEPS
-    for name in FIVE:
-        steps, _, _ = rec.span_rings[name].held()
-        want = list(range(2, STEPS + 1, 2)) + ([STEPS + 1] if name == "wait" else [])
-        assert list(steps) == want, name
-    spans = _spans(rec)
-    for (_, end, _, _), (start, _, _, _) in zip(spans, spans[1:]):
-        assert end <= start
-    for s in range(2, STEPS + 1, 2):
-        t0 = [rec.span(name, s)[0] for name in FIVE]
-        assert t0 == sorted(t0)
-        if s < STEPS:
-            # the default keeps two groups in flight: a group is drained
-            # after the next one's dispatch (ISSUE 31); depth 1: before
-            ahead = rec.span("dispatch", s + 2)[0] < rec.span("drain", s)[0]
-            assert ahead == (summary["dispatch_depth"] == 2)
-    # the rows stay one a step
-    assert [r["step"] for r in rec.history["train"]] == list(range(1, STEPS + 1))
 
 
 def test_the_ring_keeps_constant_length_and_answers_by_step_number():

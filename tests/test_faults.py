@@ -45,13 +45,6 @@ def test_crash_fires_exactly_once():
     inj.check_step(4)
 
 
-def test_crash_fires_inside_fused_group_range():
-    inj = FaultInjector(["crash@6"])
-    inj.check_step(1, 4)  # group [1,4]: not due
-    with pytest.raises(InjectedCrash):
-        inj.check_step(5, 8)  # group [5,8] contains step 6
-
-
 def test_nan_batch_poisons_float_once():
     inj = FaultInjector(["nan_batch@2"])
     x = jnp.ones((4, 3))
@@ -165,15 +158,6 @@ def test_world_override_follows_firing_order_not_spec_order():
     with pytest.raises(TopologyChanged):
         inj.check_step(5)          # grow fires last -> its world wins
     assert inj.world_override() == 4
-
-
-def test_topology_fault_fires_inside_fused_group_range():
-    from theanompi_tpu.utils.faults import TopologyChanged
-
-    inj = FaultInjector(["shrink@6:2"])
-    inj.check_step(1, 4)
-    with pytest.raises(TopologyChanged):
-        inj.check_step(5, 8)
 
 
 # -- storage-level kinds + the fired-fault ledger (chaos PR) ---------------

@@ -1,6 +1,6 @@
 """The driver's key stream (ISSUE 27, ``utils/dispatch.py KeyStream``): the
 chain ``rng, sub = jax.random.split(rng)`` of one split a step, made one
-dispatch unit ahead by ONE jitted program. Whatever is made ahead, the keys
+step ahead by ONE jitted program. Whatever is made ahead, the keys
 handed out and the committed carry are the eager chain's, bit for bit.
 """
 
@@ -57,7 +57,7 @@ def test_take_one_gives_the_eager_chain_whatever_was_made_ahead(refills):
     subs, carries = _eager_chain(jax.random.PRNGKey(7), 20)
     keys = KeyStream(jax.random.PRNGKey(7))
     for sub, carry in zip(subs, carries):
-        np.testing.assert_array_equal(_bits(keys.take(1)), sub)
+        np.testing.assert_array_equal(_bits(keys.take()), sub)
         np.testing.assert_array_equal(_bits(keys.carry), carry)  # a checkpoint's rng=
         for _ in range(refills):
             keys.refill()
@@ -67,46 +67,39 @@ def test_take_one_gives_the_eager_chain_whatever_was_made_ahead(refills):
     assert KeyStream(jax.random.PRNGKey(7)).ready_share is None  # nothing taken
 
 
-def test_ragged_groups_stack_the_keys_of_single_takes():
-    subs, carries = _eager_chain(jax.random.PRNGKey(7), 12)
-    keys = KeyStream(jax.random.PRNGKey(7), ahead=4)
-    at = 0
-    for g in (4, 4, 3, 1):
-        stacked = keys.take(g, stacked=True)
-        assert stacked.shape[0] == g
-        np.testing.assert_array_equal(_bits(stacked), np.stack(subs[at:at + g]))
-        at += g
-        np.testing.assert_array_equal(_bits(keys.carry), carries[at - 1])
-        keys.refill()
-        assert len(keys._ready) == 4
-    # the first group was made on the spot; a group larger than what is ready
-    # (none here) would be too: late, never wrong
-    assert (keys.taken, keys.taken_ready) == (12, 8)
-    keys = KeyStream(jax.random.PRNGKey(7))  # ahead 1, groups of 3: one key ready a group
+def test_a_refill_makes_one_key_and_leaves_the_carry_where_it_was():
+    subs, carries = _eager_chain(jax.random.PRNGKey(7), 3)
+    keys = KeyStream(jax.random.PRNGKey(7))
     keys.refill()
-    for at in (0, 3):
-        np.testing.assert_array_equal(_bits(keys.take(3, stacked=True)), np.stack(subs[at:at + 3]))
-        keys.refill()
-    assert (keys.taken, keys.taken_ready) == (6, 2)
+    ready = keys._ready
+    keys.refill()  # already ready: nothing is made
+    assert keys._ready is ready
+    # made ahead, not taken: the committed carry is still the start
+    np.testing.assert_array_equal(_bits(keys.carry), _bits(jax.random.PRNGKey(7)))
+    for i in range(3):
+        np.testing.assert_array_equal(_bits(keys.take()), subs[i])
+        np.testing.assert_array_equal(_bits(keys.carry), carries[i])
+    # the first key was ready, the next two were made on the spot
+    assert (keys.taken, keys.taken_ready) == (3, 1)
 
 
 @pytest.mark.parametrize("impl", ["default", "threefry2x32"])
 def test_a_stream_rebuilt_from_a_carry_continues_and_drops_what_was_ready(impl):
     subs, carries = _eager_chain(_start(impl), 10)
-    keys = KeyStream(_start(impl), ahead=2)
+    keys = KeyStream(_start(impl))
     for i in range(6):
-        np.testing.assert_array_equal(_bits(keys.take(1)), subs[i])
+        np.testing.assert_array_equal(_bits(keys.take()), subs[i])
         keys.refill()
-    assert len(keys._ready) == 2  # keys 7 and 8, made ahead
+    assert keys._ready is not None  # key 7, made ahead
     saved = keys.carry  # the checkpoint after step 6 ...
     for i in range(6, 9):
-        keys.take(1)
+        keys.take()
         keys.refill()
     keys.reset(saved)  # ... restored after step 9: the rollback
-    assert not keys._ready
+    assert keys._ready is None
     for stream in (keys, KeyStream(saved)):  # and the resume in a new process
         for i in range(6, 10):
-            sub = stream.take(1)
+            sub = stream.take()
             np.testing.assert_array_equal(_bits(sub), subs[i])
             np.testing.assert_array_equal(_bits(stream.carry), carries[i])
             stream.refill()
@@ -118,7 +111,7 @@ def test_the_split_is_one_compiled_program():
     dispatch._split.clear_cache()
     keys = KeyStream(jax.random.PRNGKey(3))
     for _ in range(20):
-        keys.take(1)
+        keys.take()
         keys.refill()
     # made on the spot from PRNGKey's carry or ahead from a split's own output:
     # one signature, one program
@@ -129,20 +122,18 @@ def test_no_eager_split_is_left_in_the_driver():
     with open(WORKER_PATH) as f:
         src = f.read()
     assert "KeyStream" in src and check_source(src) == []
-    reborn = src.replace("keys.take(1)\n", "rng, _ = jax.random.split(rng)\n", 1)
+    reborn = src.replace("keys.take()\n", "rng, _ = jax.random.split(rng)\n", 1)
     assert reborn != src
     errs = check_source(reborn)
     assert len(errs) == 1 and "eager key split" in errs[0]
 
 
-@pytest.mark.parametrize("fuse,depth", [(1, 1), (1, 2), (2, 1), (2, 2)],
-                         ids=["per_step", "per_step_depth2", "fused2", "fused2_depth2"])
-def test_a_run_takes_its_keys_ready_and_saves_the_chains_carry(fuse, depth, tmp_path):
-    summary = run_training(ckpt_dir=str(tmp_path), seed=5, steps_per_dispatch=fuse,
-                           dispatch_depth=depth, **_TINY)
+@pytest.mark.parametrize("depth", [1, 2], ids=["per_step", "per_step_depth2"])
+def test_a_run_takes_its_keys_ready_and_saves_the_chains_carry(depth, tmp_path):
+    summary = run_training(ckpt_dir=str(tmp_path), seed=5, dispatch_depth=depth, **_TINY)
     assert summary["steps"] == STEPS and summary["dispatch_depth"] == depth
-    # all but the first dispatch unit's keys were waiting
-    assert summary["keys_ready_share"] == (STEPS - fuse) / STEPS
+    # all but the first step's keys were waiting
+    assert summary["keys_ready_share"] == (STEPS - 1) / STEPS
     path = latest_checkpoint(str(tmp_path), verify=True)
     _, saved = load_checkpoint(path, None)
     _, carries = _eager_chain(jax.random.PRNGKey(5), STEPS)

@@ -340,35 +340,3 @@ def test_zero_numerics_sentinels(tmp_path):
         assert r["metrics"]["nm_nonfinite"] == 0.0
         assert r["metrics"]["nm_grad_norm"] > 0.0
     assert check_file(str(obs / "numerics_rank0.jsonl")) == []
-
-
-def test_fused_dispatch_numerics_rows(tmp_path):
-    """steps_per_dispatch > 1: sentinels ride every substep of the
-    fused group and expand to per-substep numerics rows at drain."""
-    obs = tmp_path / "obs"
-    summary = run_training(
-        rule="bsp", model_cls=TinyCNN, devices=8, n_epochs=1,
-        steps_per_dispatch=2, obs_dir=str(obs), numerics_freq=1, **_TINY,
-    )
-    assert summary["steps"] == 2
-    rows = [json.loads(l)
-            for l in (obs / "numerics_rank0.jsonl").read_text().splitlines()]
-    assert [r["step"] for r in rows if r["kind"] == "numerics"] == [1, 2]
-    assert check_file(str(obs / "numerics_rank0.jsonl")) == []
-
-
-def test_fused_dispatch_honors_numerics_freq(tmp_path):
-    """The cadence gates at GROUP granularity under fusion: groups with
-    no step on the nfreq grid run the plain program (on GoSGD that is
-    the difference between paying the divergence pmean every group and
-    amortizing it as documented)."""
-    obs = tmp_path / "obs"
-    run_training(
-        rule="bsp", model_cls=TinyCNN, devices=8, n_epochs=2,
-        steps_per_dispatch=2, obs_dir=str(obs), numerics_freq=4, **_TINY,
-    )
-    rows = [json.loads(l)
-            for l in (obs / "numerics_rank0.jsonl").read_text().splitlines()]
-    # 4 steps in groups [1,2] and [3,4]; only the group containing
-    # step 4 (the nfreq multiple) runs the numerics variant
-    assert [r["step"] for r in rows if r["kind"] == "numerics"] == [3, 4]

@@ -129,13 +129,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "(':ef' syncs post-backward, bucketed). 0 = "
                         "off; 4-32 MB is the useful range — biggest "
                         "win multi-chip/DCN, a no-op on one chip")
-    p.add_argument("--steps-per-dispatch", type=int, default=1,
-                   help="fuse this many steps into one compiled dispatch "
-                        "(one H2D transfer + one host dispatch per group) — "
-                        "works for every rule: EASGD embeds its avg_freq "
-                        "exchange in the scan, GoSGD keeps its gossip "
-                        "cadence per substep; amortizes per-step host "
-                        "dispatch latency")
     p.add_argument("--dispatch-depth", type=int, default=2,
                    help="async dispatch pipeline: keep up to K steps in "
                         "flight before the host blocks on a metrics "
@@ -614,7 +607,6 @@ def main(argv=None) -> int:
             fused_update=args.fused_update,
             allreduce_buckets=args.allreduce_buckets,
             n_slices=args.slices,
-            steps_per_dispatch=args.steps_per_dispatch,
             dispatch_depth=args.dispatch_depth,
             accum_steps=args.accum_steps,
             tp=args.tp,

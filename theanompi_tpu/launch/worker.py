@@ -155,7 +155,6 @@ def run_training(
     fused_update: bool = False,
     allreduce_buckets: float = 0.0,
     n_slices: Optional[int] = None,
-    steps_per_dispatch: int = 1,
     # async dispatch pipeline (utils/dispatch.py): keep up to this many
     # steps in flight before the host blocks on a metrics D2H. 2 (the
     # default): step N is queued before step N-1's metrics are drained,
@@ -369,7 +368,6 @@ def run_training(
     from theanompi_tpu.parallel.codec import get_codec
 
     codec = get_codec(wire_codec)  # validate the spec before any build
-    fuse = max(1, int(steps_per_dispatch))
     tp, sp, pp, expert = int(tp), int(sp), int(pp), int(expert)
     zero = int(zero or 0)
     nd_active = max(tp, sp, pp, expert) > 1
@@ -526,9 +524,6 @@ def run_training(
             "it needs a multislice mesh (--slices N with N > 1); on a "
             "single slice the flat 'psum' is already optimal"
         )
-    # fuse>1 works for every rule: BSP scans allreduce-inside steps;
-    # EASGD embeds its elastic exchange at the avg_freq boundaries
-    # inside the scan; GoSGD ships per-substep gossip-cadence flags
     # Async-rule worker groups: each worker = group_size chips, so the
     # worker count (and the global batch multiplier) is n_dev / group_size
     # (bsp with group_size already raised above)
@@ -725,9 +720,9 @@ def run_training(
 
     from theanompi_tpu.utils.dispatch import KeyStream, MetricsDispatcher
 
-    # every step's random key, split off the carry one dispatch unit
-    # ahead (utils/dispatch.py); keys.carry is what a checkpoint saves
-    keys = KeyStream(jax.random.PRNGKey(seed), ahead=fuse)
+    # every step's random key, split off the carry one step ahead
+    # (utils/dispatch.py); keys.carry is what a checkpoint saves
+    keys = KeyStream(jax.random.PRNGKey(seed))
     state = _commit(engine.init_state(keys.carry))
     start_epoch = 0
     summary_resumed_from = None
@@ -869,34 +864,9 @@ def run_training(
             x, y = b
             return (put_global_batch(mesh, x), put_global_batch(mesh, y))
 
-    def place_group(group):
-        # fused dispatch: stack g host batches -> ONE [g, batch, ...]
-        # transfer (dim 0 replicated, dim 1 sharded); ND engines own the
-        # stacked layout (token specs / microbatch-major)
-        if hasattr(engine, "place_group"):
-            return engine.place_group(group)
-        from theanompi_tpu.parallel.mesh import put_stacked_batches
-
-        xs = np.stack([b[0] for b in group])
-        ys = np.stack([b[1] for b in group])
-        return put_stacked_batches(mesh, xs), put_stacked_batches(mesh, ys)
-
-    def grouper(it, k):
-        buf = []
-        for b in it:
-            buf.append(b)
-            if len(buf) == k:
-                yield buf
-                buf = []
-        if buf:  # epoch remainder: a smaller fused program (cached)
-            yield buf
-
     summary: dict = {"epochs": [], "rule": rule, "model": model.name,
                      "resumed_from_step": summary_resumed_from}
-    # images shipped per dispatch ('step' timing bracket) — fused
-    # dispatches carry g x batch, so throughput must be computed from
-    # this ledger, not batch / mean_time (which undercounts g-fold)
-    dispatch_images: list[int] = []
+    n_dispatched = 0  # steps dispatched in this call (throughput window)
     # sharded_ckpt: per-host shard files, no cross-host gather / rank-0
     # memory spike; restorable under any process count (SURVEY.md §5.4)
     ckpt_writer = (
@@ -1026,7 +996,7 @@ def run_training(
 
     obs.set_flight_state_saver(_flight_state_saver)
     # Async dispatch pipeline (utils/dispatch.py): the ONLY
-    # host<->device sync in the train loops below lives in the
+    # host<->device sync in the train loop below lives in the
     # dispatcher's drain (lint: tools/check_hot_loop.py). depth=2, the
     # default, drains step N-1 with step N already queued; depth=1
     # reproduces the classic per-step sync exactly. on_row feeds each
@@ -1049,20 +1019,20 @@ def run_training(
             flush=True,
         )
 
-    def _dispatch_and_split(step_fn, state, xs, ys, n, stacked, numerics):
-        """The ``dispatch`` and ``key_split`` spans of one dispatch, for
-        both train loops: the call of the step program on the next ``n``
-        keys of the stream (stacked for a fused group), then the splits
-        that make the NEXT unit's keys, queued behind the program just
-        dispatched; each a recorder bracket under the dispatched group's
-        last step number. -> (state, metrics)."""
+    def _dispatch_and_split(state, xs, ys, numerics):
+        """The ``dispatch`` and ``key_split`` spans of one step: the call
+        of the step program on the next key of the stream, then the
+        split that makes the NEXT step's key, queued behind the program
+        just dispatched; each a recorder bracket under the dispatched
+        step's number. -> (state, metrics)."""
         disp.note_dispatch()  # before the call: is the device still busy?
-        last = step_count + n
+        last = step_count + 1
         carry = keys.carry
-        subs = keys.take(n, stacked)
+        sub = keys.take()
         rec.start("dispatch")
         try:
-            state, metrics = step_fn(state, xs, ys, subs, numerics=numerics)
+            state, metrics = engine.train_step(state, xs, ys, sub,
+                                               numerics=numerics)
         except Exception:
             # no step happened: the crash save pairs this state with the
             # carry BEFORE the step's keys, which is where a resume starts
@@ -1074,11 +1044,11 @@ def run_training(
         rec.end("key_split", step=last)
         return state, metrics
 
-    train_loop_s = 0.0  # wall time inside the train loops (the
+    train_loop_s = 0.0  # wall time inside the train loop (the
     # denominator of summary['host_blocked_frac'])
     # -- fault-tolerance state (fault-tolerant run supervisor PR) -------
     # injected faults fire at deterministic steps (utils/faults.py);
-    # SIGTERM flips a flag the train loops poll, so preemption
+    # SIGTERM flips a flag the train loop polls, so preemption
     # checkpoints and exits cleanly inside the grace window; the
     # rollback policy restores the last VERIFIED checkpoint on a
     # confirmed anomaly and keeps training within its budget.
@@ -1123,7 +1093,7 @@ def run_training(
         max(0, int(rollback_budget)) if on_anomaly == "rollback" else 0
     )
     skip_from_step: Optional[int] = None  # anomalous step whose batch
-    # window the post-rollback replay skips (per-step path)
+    # window the post-rollback replay skips
     skip_data_batches = 0
     skipped_steps_total = skipped_prior  # timeline total, persisted in
     # every checkpoint's meta so replay positioning survives resume
@@ -1177,176 +1147,92 @@ def run_training(
             rec.start_epoch()
             epoch_steps = 0
             t_loop0 = time.perf_counter()
-            if fuse > 1:
-                # fused dispatch: groups of <= fuse batches, stacked and
-                # shipped in one transfer, run by one compiled program
-                import itertools
-
-                with PrefetchLoader(
-                    grouper(
-                        itertools.islice(
-                            data.train_epoch(epoch, batch, seed=seed, part=part),
-                            skip_batches,
-                            None,
-                        ),
-                        fuse,
-                    ),
-                    place_group,
-                    # depth counts GROUPS here: keep device-resident input
-                    # comparable to the per-step path (depth x fuse steps
-                    # prefetched would scale input HBM by fuse)
-                    depth=max(1, prefetch_depth // fuse),
-                ) as loader:
-                    skip_batches = 0
-                    rec.start("wait")
-                    for xs, ys in loader:
-                        if _preempt["flag"]:
-                            raise Preempted(step_count)
-                        # the group's last step, after the trim below
-                        last = step_count + int(xs.shape[0])
-                        disp.note_wait(rec.end(
-                            "wait",
-                            step=min(last, max_steps) if max_steps else last))
-                        if max_steps and step_count + xs.shape[0] > max_steps:
-                            # trim the final group to land exactly on max_steps
-                            keep = max_steps - step_count
-                            xs, ys = xs[:keep], ys[:keep]
-                        rec.profile_tick(step_count)
-                        g = int(xs.shape[0])
-                        if faults is not None:
-                            # fused injection at GROUP granularity: a
-                            # fault due anywhere in the group fires
-                            # before its dispatch; nan_batch poisons
-                            # the whole stacked transfer (the sentinel
-                            # machinery reads it identically)
-                            faults.check_step(step_count + 1, step_count + g)
-                            xs = faults.poison_batch(
-                                xs, step_count + 1, step_count + g
-                            )
-                        # numerics under fusion: the dispatch unit is
-                        # the GROUP, so the cadence gates at group
-                        # granularity — the numerics variant runs only
-                        # for groups that contain a step on the nfreq
-                        # grid (then sentinels ride every substep of
-                        # that group; per-substep gating would split
-                        # the compiled program). GoSGD's param-sized
-                        # divergence pmean is therefore still amortized
-                        # by raising --numerics-freq.
-                        nm_group = bool(nfreq) and (
-                            (step_count + g) // nfreq > step_count // nfreq
-                        )
-                        # the SAME sequential splits the per-step path draws,
-                        # shipped stacked — fused training is bit-identical
-                        state, metrics = _dispatch_and_split(
-                            engine.fused_train_step, state, xs, ys,
-                            g, True, nm_group,
-                        )
-                        step_count += g
-                        epoch_steps += g
-                        dispatch_images.append(batch * g)
-                        # liveness first (watchdog/heartbeat learn of the
-                        # dispatch immediately — a hung collective stops
-                        # the drain, and with it further dispatches,
-                        # within `depth` groups), then rows + step timing
-                        # via the dispatcher's drain — the only host sync
-                        # in this loop
-                        obs.on_step(step_count, substeps=g)
-                        disp.push(step_count, metrics,
-                                  n_images=batch * g, substeps=g)
-                        rec.start("wait")
-                        if max_steps and step_count >= max_steps:
-                            break
-                    # the epoch-tail wait (the loader's StopIteration
-                    # fetch) must be credited too, or the flush below
-                    # would attribute it to the in-flight steps AND the
-                    # wait bracket — double counting that breaks the
-                    # span-fraction invariant
+            with PrefetchLoader(
+                data.train_epoch(epoch, batch, seed=seed, part=part),
+                place,
+                depth=prefetch_depth,
+            ) as loader:
+                rec.start("wait")
+                for xg, yg in loader:
+                    if skip_batches:
+                        skip_batches -= 1
+                        continue
+                    if skip_from_step is not None and (
+                        step_count + 1 == skip_from_step
+                    ):
+                        # post-rollback replay reached the anomalous
+                        # step again: skip its batch window (consume
+                        # the data and its rng splits, train
+                        # nothing) so a persistent bad batch cannot
+                        # re-poison every rollback attempt
+                        skip_from_step = None
+                        skip_data_batches = max(0, int(rollback_skip))
+                    if skip_data_batches:
+                        skip_data_batches -= 1
+                        skipped_steps_total += 1
+                        keys.take()
+                        continue
+                    if _preempt["flag"]:
+                        raise Preempted(step_count)
                     disp.note_wait(rec.end("wait", step=step_count + 1))
-                disp.flush()
-                rec.end_epoch(epoch, n_images=epoch_steps * batch)
-            else:
-                with PrefetchLoader(
-                    data.train_epoch(epoch, batch, seed=seed, part=part),
-                    place,
-                    depth=prefetch_depth,
-                ) as loader:
-                    rec.start("wait")
-                    for xg, yg in loader:
-                        if skip_batches:
-                            skip_batches -= 1
-                            continue
-                        if skip_from_step is not None and (
-                            step_count + 1 == skip_from_step
-                        ):
-                            # post-rollback replay reached the anomalous
-                            # step again: skip its batch window (consume
-                            # the data and its rng splits, train
-                            # nothing) so a persistent bad batch cannot
-                            # re-poison every rollback attempt
-                            skip_from_step = None
-                            skip_data_batches = max(0, int(rollback_skip))
-                        if skip_data_batches:
-                            skip_data_batches -= 1
-                            skipped_steps_total += 1
-                            keys.take(1)
-                            continue
-                        if _preempt["flag"]:
-                            raise Preempted(step_count)
-                        disp.note_wait(rec.end("wait", step=step_count + 1))
-                        if faults is not None:
-                            faults.check_step(step_count + 1)
-                            xg = faults.poison_batch(xg, step_count + 1)
-                        rec.profile_tick(step_count)
-                        # sentinel cadence: every nfreq-th step runs the
-                        # numerics variant of the SAME compiled step
-                        # (extra scalar outputs; obs/numerics.py) — the
-                        # scalars drain with the loss, no host sync here
-                        state, metrics = _dispatch_and_split(
-                            engine.train_step, state, xg, yg, 1, False,
-                            bool(nfreq) and (step_count + 1) % nfreq == 0,
+                    if faults is not None:
+                        faults.check_step(step_count + 1)
+                        xg = faults.poison_batch(xg, step_count + 1)
+                    rec.profile_tick(step_count)
+                    # sentinel cadence: every nfreq-th step runs the
+                    # numerics variant of the SAME compiled step
+                    # (extra scalar outputs; obs/numerics.py) — the
+                    # scalars drain with the loss, no host sync here
+                    state, metrics = _dispatch_and_split(
+                        state, xg, yg,
+                        bool(nfreq) and (step_count + 1) % nfreq == 0,
+                    )
+                    step_count += 1
+                    epoch_steps += 1
+                    n_dispatched += 1
+                    # liveness first (watchdog/heartbeat track
+                    # dispatched progress; a hang stops dispatches
+                    # within `depth` steps), then the row + step
+                    # timing via the dispatcher's drain (step
+                    # N-depth+1 while this step runs) — the per-step
+                    # host round trip lives ONLY there
+                    obs.on_step(step_count)
+                    disp.push(step_count, metrics, n_images=batch)
+                    # periodic exchange (EASGD avg_freq; reference: worker
+                    # loop calling exchanger.exchange() — recorded as 'comm')
+                    if engine.exchange_every and step_count % engine.exchange_every == 0:
+                        # exchange boundary: drain in-flight metrics
+                        # first so the comm bracket below times the
+                        # collective, not K backlogged steps
+                        disp.flush()
+                        rec.start("comm")
+                        state = engine.exchange(state)
+                        # sync on a leaf of the exchanged state: without it
+                        # the bracket measures only async dispatch and the
+                        # collective's real cost bleeds into the next
+                        # wait/step brackets
+                        cdt = rec.end(
+                            "comm", sync=jax.tree_util.tree_leaves(state)[0],
+                            step=step_count,
                         )
-                        step_count += 1
-                        epoch_steps += 1
-                        dispatch_images.append(batch)
-                        # liveness first (watchdog/heartbeat track
-                        # dispatched progress; a hang stops dispatches
-                        # within `depth` steps), then the row + step
-                        # timing via the dispatcher's drain (step
-                        # N-depth+1 while this step runs) — the per-step
-                        # host round trip lives ONLY there
-                        obs.on_step(step_count)
-                        disp.push(step_count, metrics, n_images=batch)
-                        # periodic exchange (EASGD avg_freq; reference: worker
-                        # loop calling exchanger.exchange() — recorded as 'comm')
-                        if engine.exchange_every and step_count % engine.exchange_every == 0:
-                            # exchange boundary: drain in-flight metrics
-                            # first so the comm bracket below times the
-                            # collective, not K backlogged steps
-                            disp.flush()
-                            rec.start("comm")
-                            state = engine.exchange(state)
-                            # sync on a leaf of the exchanged state: without it
-                            # the bracket measures only async dispatch and the
-                            # collective's real cost bleeds into the next
-                            # wait/step brackets
-                            cdt = rec.end(
-                                "comm", sync=jax.tree_util.tree_leaves(state)[0],
-                                step=step_count,
-                            )
-                            # the comm gauge's denominator includes the
-                            # exchange's wall time on the steps that pay
-                            # it (amortized bytes / local-only time would
-                            # report gbps above the physical link)
-                            obs.note_step_seconds(
-                                (disp.last_step_seconds or 0.0) + cdt
-                            )
-                        rec.start("wait")
-                        if max_steps and step_count >= max_steps:
-                            break
-                    # credit the epoch-tail wait (see the fused path)
-                    disp.note_wait(rec.end("wait", step=step_count + 1))
-                disp.flush()
-                rec.end_epoch(epoch, n_images=epoch_steps * batch)
+                        # the comm gauge's denominator includes the
+                        # exchange's wall time on the steps that pay
+                        # it (amortized bytes / local-only time would
+                        # report gbps above the physical link)
+                        obs.note_step_seconds(
+                            (disp.last_step_seconds or 0.0) + cdt
+                        )
+                    rec.start("wait")
+                    if max_steps and step_count >= max_steps:
+                        break
+                # the epoch-tail wait (the loader's StopIteration
+                # fetch) must be credited too, or the flush below
+                # would attribute it to the in-flight steps AND the
+                # wait bracket — double counting that breaks the
+                # span-fraction invariant
+                disp.note_wait(rec.end("wait", step=step_count + 1))
+            disp.flush()
+            rec.end_epoch(epoch, n_images=epoch_steps * batch)
 
             train_loop_s += time.perf_counter() - t_loop0
 
@@ -1469,14 +1355,9 @@ def run_training(
                 keys.reset(saved_rng)
             step_count = engine.get_step(state)
             last_ckpt_step = step_count
-            # replay from the restored boundary; the per-step path
-            # skips the anomalous step's batch window when it gets
-            # there (fused dispatch replays without skipping: transient
-            # faults clear on replay, persistent ones exhaust the
-            # budget)
-            skip_from_step = (
-                rb.step if (rollback_skip and fuse == 1) else None
-            )
+            # replay from the restored boundary; the loop skips the
+            # anomalous step's batch window when it gets there
+            skip_from_step = rb.step if rollback_skip else None
             skip_data_batches = 0
             # position by BATCHES CONSUMED in the restored timeline:
             # the checkpoint's meta records the batches earlier
@@ -1491,7 +1372,7 @@ def run_training(
             epoch = consumed // steps_per_epoch
             skip_batches = consumed % steps_per_epoch
             obs.note_rollback(rb.step, step_count, rollback_budget_left,
-                              skipped=int(rollback_skip) if fuse == 1 else 0)
+                              skipped=int(rollback_skip))
             print(
                 f"[rank {jax.process_index()}] anomaly rollback: restored "
                 f"{path} at step {step_count} (anomaly at step {rb.step}; "
@@ -1730,16 +1611,15 @@ def run_training(
         round(min(1.0, disp.host_blocked_s / train_loop_s), 6)
         if train_loop_s > 0 else None
     )
-    k_recent = min(50, len(dispatch_images))
+    k_recent = min(50, n_dispatched)
     t_recent = rec.mean_time("step", k_recent)
     summary["images_per_sec"] = (
-        (sum(dispatch_images[-k_recent:]) / k_recent) / t_recent
-        if (k_recent and t_recent) else 0.0
+        batch / t_recent if (k_recent and t_recent) else 0.0
     )
     if obs.cost is not None and summary["images_per_sec"]:
         # achieved utilization from the SHARED cost model (the same
         # numbers the live gauges carry): per-step seconds recovered from the
-        # throughput ledger so fused dispatches amortize correctly
+        # throughput
         _sps = batch / summary["images_per_sec"]
         _mfu = obs.cost.mfu(_sps)
         summary["mfu"] = round(_mfu, 4) if _mfu is not None else None

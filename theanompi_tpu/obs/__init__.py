@@ -706,10 +706,9 @@ class Observability:
             print(f"[rank {self.rank}] anomaly rollback: {line}",
                   file=sys.stderr, flush=True)
 
-    def on_step(self, step: int, substeps: int = 1,
+    def on_step(self, step: int,
                 step_seconds: Optional[float] = None) -> None:
-        """Per completed dispatch: advance health + comm accounting.
-        ``substeps`` > 1 for fused dispatches (one call per group)."""
+        """Per completed dispatch: advance health + comm accounting."""
         self._last_step = int(step)
         if self.heartbeat is not None:
             self.heartbeat.set_step(step)
@@ -719,17 +718,17 @@ class Observability:
             return
         self.registry.counter(
             "tmpi_steps_total", help="completed training steps"
-        ).inc(substeps)
+        ).inc()
         if self.traffic is not None:
             per_step = self.traffic.bytes_per_step_amortized
             self.registry.counter(
                 "tmpi_comm_bytes_total",
                 help="cumulative analytic per-device wire bytes",
-            ).inc(per_step * substeps)
+            ).inc(per_step)
             if step_seconds:
-                gbps = self.traffic.achieved_gbps(step_seconds / substeps)
+                gbps = self.traffic.achieved_gbps(step_seconds)
                 if gbps is not None:
-                    self._set_gbps_gauges(gbps, step_seconds / substeps)
+                    self._set_gbps_gauges(gbps, step_seconds)
         if (
             self.snapshot_freq
             and step - self._last_snapshot_step >= self.snapshot_freq

@@ -204,107 +204,6 @@ def make_bsp_train_step(
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 
 
-def make_bsp_fused_step(
-    model: Model,
-    mesh: Mesh,
-    steps_per_epoch: int = 1,
-    strategy: str = "psum",
-    axis_name=DATA_AXIS,
-    input_transform=None,
-    accum_steps: int = 1,
-    numerics: bool = False,
-    wire_codec=None,
-    fused_update: bool = False,
-    allreduce_buckets: float = 0.0,
-):
-    """``k`` BSP steps fused into ONE compiled program via ``lax.scan``
-    over stacked batches ``[k, batch, ...]`` — one host dispatch (and one
-    H2D transfer) per k steps instead of per step, amortizing the
-    per-step host dispatch; the reference had no analogue (Python drove
-    every iteration).
-
-    Takes ``rngs`` STACKED ``[k]`` per-step keys (the driver derives them
-    with the same sequential splits the per-step path uses), so each
-    fused sub-step computes exactly the per-step math — a single step
-    agrees to float epsilon; over a long run the two XLA programs'
-    fusion choices accumulate ULP-level drift
-    (tests/test_fused_dispatch.py). Returns ``(state, stacked_metrics)``.
-    """
-    from theanompi_tpu.parallel.codec import get_codec
-
-    codec = get_codec(wire_codec)
-    allreduce_buckets = float(allreduce_buckets or 0.0)
-    axes = _axes_tuple(axis_name)
-    n = 1
-    for a in axes:
-        n *= mesh.shape[a]
-    axis_sizes = tuple(int(mesh.shape[a]) for a in axes)
-    checked = _checked_vma()
-
-    if n == 1:
-        # same validation contract as make_bsp_train_step's n==1 path:
-        # names/codec pairs are checked, but the checked-mode bucket
-        # refusal does not apply — one device has no collective either
-        # way, so the knob is the documented no-op
-        if allreduce_buckets:
-            bucketed(strategy, axis_name, n, allreduce_buckets, codec=codec,
-                     axis_sizes=axis_sizes)
-        else:
-            get_strategy(strategy, axis_name, n, codec=codec,
-                         axis_sizes=axis_sizes)
-        base = make_train_step(
-            model, steps_per_epoch, input_transform=input_transform,
-            accum_steps=accum_steps, numerics=numerics,
-            fused_update=fused_update,
-        )
-
-        def single(state, images, labels, rngs):
-            def body(st, inp):
-                x, y, r = inp
-                return base(st, x, y, jax.random.fold_in(r, 0))
-
-            return lax.scan(body, state, (images, labels, rngs))
-
-        return jax.jit(single, donate_argnums=(0,))
-    grad_sync = _bsp_grad_sync(  # also validates the name
-        strategy, axis_name, n, codec, checked, allreduce_buckets,
-        axis_sizes=axis_sizes,
-    )
-    base_step = make_train_step(
-        model, steps_per_epoch, grad_sync=grad_sync,
-        input_transform=input_transform, accum_steps=accum_steps,
-        numerics=numerics, fused_update=fused_update,
-    )
-
-    def sharded_step(state: TrainState, images, labels, rngs):
-        def body(st, inp):
-            x, y, r = inp
-            new_state, metrics = base_step(
-                st, x, y, _fold_linear_index(r, axes, mesh)
-            )
-            new_state = new_state._replace(
-                model_state=lax.pmean(new_state.model_state, axis_name)
-            )
-            return new_state, lax.pmean(metrics, axis_name)
-
-        return lax.scan(body, state, (images, labels, rngs))
-
-    # dim 0 = step index (replicated), dim 1 = batch (sharded).
-    # donate like the unfused step: without it every dispatch holds a
-    # second full params+opt copy
-    recipe = _bsp_recipe(mesh, axis_name, codec)
-    spec = recipe.stacked_batch_spec
-    sspec = recipe.state_spec(TrainState)
-    mapped = jax.shard_map(
-        sharded_step,
-        mesh=mesh,
-        in_specs=(sspec, spec, spec, recipe.scalar),
-        out_specs=(sspec, recipe.scalar),
-        check_vma=checked,
-    )
-    return jax.jit(mapped, donate_argnums=(0,))
-
-
 class BSPEngine:
     """Rule-engine wrapper over the BSP step (uniform driver protocol
     shared with EASGDEngine/GOSGDEngine).
@@ -353,15 +252,14 @@ class BSPEngine:
             fused_update=bool(fused_update),
             allreduce_buckets=float(allreduce_buckets or 0.0),
         )
-        # per-flag variants, built lazily: {numerics_flag: jitted step}.
-        # The numerics step is a SECOND compiled program (sentinels are
-        # extra outputs) — only runs where --numerics-freq selects it.
-        self._fused_steps: dict = {}
         # THE spec source for this engine (parallel/recipe.py): the
         # analyzer (SHARD001-004) verifies these declared specs against
         # the compiled executable, memory_model divides by their
         # extents, and the checkpoint topology stamp carries them
         self.sharding = _bsp_recipe(mesh, axis_name, self.codec)
+        # per-flag variants, built lazily: {numerics_flag: jitted step}.
+        # The numerics step is a SECOND compiled program (sentinels are
+        # extra outputs) — only runs where --numerics-freq selects it.
         self._steps = {False: make_bsp_train_step(model, mesh, **self._build)}
         self._eval = make_bsp_eval_step(
             model, mesh, axis_name=axis_name, input_transform=input_transform,
@@ -407,20 +305,6 @@ class BSPEngine:
                 self.model, self.mesh, numerics=numerics, **self._build
             )
         return self._steps[numerics](state, images, labels, rng)
-
-    def fused_train_step(self, state, images, labels, rngs,
-                         numerics: bool = False):
-        """Run ``images.shape[0]`` fused steps on stacked batches
-        ``[g, batch, ...]`` with stacked per-step keys (one dispatch).
-        One jitted function per numerics flag; jit recompiles per
-        distinct group size (the driver produces at most the configured
-        k plus an epoch-remainder size)."""
-        numerics = bool(numerics)
-        if numerics not in self._fused_steps:
-            self._fused_steps[numerics] = make_bsp_fused_step(
-                self.model, self.mesh, numerics=numerics, **self._build
-            )
-        return self._fused_steps[numerics](state, images, labels, rngs)
 
     def exchange(self, state):
         return state

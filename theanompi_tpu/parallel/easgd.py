@@ -182,7 +182,6 @@ class EASGDEngine:
 
             return sharded_step
 
-        self._make_sharded_step = make_sharded_step
         # THE spec source (parallel/recipe.py): worker stack + ef
         # residuals sharded over the worker axis, center replicated —
         # the worker-axis prefix broadcasts over an empty () ef subtree
@@ -191,12 +190,9 @@ class EASGDEngine:
 
         self.sharding = ShardingRecipe.easgd(
             mesh, ax, group_batch_spec=gspec if g > 1 else None)
-        self._state_spec = self.sharding.state_spec(EASGDState)
-        sspec = self._state_spec
+        sspec = self.sharding.state_spec(EASGDState)
         scalar = self.sharding.scalar
-        self._bspec = self.sharding.batch_spec
-        bspec = self._bspec
-        self._fused: dict = {}
+        bspec = self.sharding.batch_spec
 
         def jit_step(numerics: bool):
             return jax.jit(
@@ -237,7 +233,6 @@ class EASGDEngine:
             )
             return EASGDState(workers, center, center_ms, new_ef)
 
-        self._sharded_exchange_fn = sharded_exchange
         self._exchange = jax.jit(
             jax.shard_map(
                 sharded_exchange,
@@ -286,40 +281,6 @@ class EASGDEngine:
         if numerics not in self._steps:
             self._steps[numerics] = self._jit_step(numerics)
         return self._steps[numerics](state, images, labels, rng)
-
-    def fused_train_step(self, state, images, labels, rngs,
-                         numerics: bool = False):
-        """``g`` local steps in ONE program, with the elastic exchange
-        embedded at the exact ``avg_freq`` boundaries the per-step
-        driver would hit (``lax.cond`` on the in-program step counter) —
-        identical trajectory, one dispatch. The driver must NOT call
-        ``exchange()`` around fused groups; the recorder's comm bracket
-        is subsumed into the step (documented tradeoff of fusion)."""
-        numerics = bool(numerics)
-        if numerics not in self._fused:
-            from theanompi_tpu.parallel.fused import fuse_sharded_step
-
-            freq = self.avg_freq
-            step_fn = self._make_sharded_step(numerics)
-            exchange_fn = self._sharded_exchange_fn
-
-            def step_and_maybe_exchange(st, x, y, r):
-                st, metrics = step_fn(st, x, y, r)
-                # workers.step is the stacked [1] per-worker counter;
-                # it matches the driver's step_count after each step
-                st = lax.cond(
-                    st.workers.step[0] % freq == 0,
-                    exchange_fn, lambda s: s, st,
-                )
-                return st, metrics
-
-            self._fused[numerics] = fuse_sharded_step(
-                step_and_maybe_exchange, self.mesh, self._state_spec,
-                (self.sharding.stacked_batch_spec,
-                 self.sharding.stacked_batch_spec,
-                 self.sharding.scalar), True,
-            )
-        return self._fused[numerics](state, images, labels, rngs)
 
     def exchange(self, state):
         return self._exchange(state)

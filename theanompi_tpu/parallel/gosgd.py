@@ -231,9 +231,8 @@ class GOSGDEngine:
 
             def sharded_step_flag(state: GOSGDState, images, labels, rng,
                                   with_gossip):
-                """``with_gossip`` may be a static Python bool (the cond
-                folds at trace time — the per-step jit variants) or a
-                traced bool (the fused scan decides per substep)."""
+                """``with_gossip``: a static Python bool, one jit variant
+                each."""
                 local = jax.tree_util.tree_map(lambda v: v[0], state.workers)
                 a_local = state.alpha[0]
                 step_rng, gossip_rng = jax.random.split(rng)
@@ -247,25 +246,16 @@ class GOSGDEngine:
                     new_local = new_local._replace(
                         model_state=lax.pmean(new_local.model_state, DATA_AXIS)
                     )
-                if isinstance(with_gossip, bool):
-                    # static flag (the per-step jit variants): keep the
-                    # no-gossip program genuinely collective-free — lax.cond
-                    # stages BOTH branches even for a concrete predicate
-                    # (verified), which would put a dead ppermute switch in
-                    # the local step and lean on XLA to simplify it out
-                    merged, a_new, ef_new = (
-                        gossip(new_local.params, a_local, gossip_rng,
-                               state.ef)
-                        if with_gossip
-                        else (new_local.params, a_local, state.ef)
-                    )
-                else:
-                    merged, a_new, ef_new = lax.cond(
-                        with_gossip,
-                        lambda: gossip(new_local.params, a_local,
-                                       gossip_rng, state.ef),
-                        lambda: (new_local.params, a_local, state.ef),
-                    )
+                # a Python branch, not lax.cond: the no-gossip program
+                # stays genuinely collective-free — lax.cond stages BOTH
+                # branches even for a concrete predicate (verified),
+                # which would put a dead ppermute switch in the local
+                # step and lean on XLA to simplify it out
+                merged, a_new, ef_new = (
+                    gossip(new_local.params, a_local, gossip_rng, state.ef)
+                    if with_gossip
+                    else (new_local.params, a_local, state.ef)
+                )
                 new_local = new_local._replace(params=merged)
                 if numerics:
                     wbar = jax.tree_util.tree_map(
@@ -294,11 +284,8 @@ class GOSGDEngine:
 
             return sharded_step_flag
 
-        self._make_flag_fn = make_flag_fn
         self._sharded_step_flag = make_flag_fn(False)
         self._state_spec = self.sharding.state_spec(GOSGDState)
-        self._bspec = bspec
-        self._fused: dict = {}
 
         def make_sharded_step(with_gossip: bool, numerics: bool = False):
             flag_fn = (
@@ -391,43 +378,6 @@ class GOSGDEngine:
         # advance only after the dispatch succeeds: a raise (OOM on a new
         # shape) must not shift the gossip cadence off the applied steps
         self._count = nxt
-        return out
-
-    def fused_train_step(self, state, images, labels, rngs,
-                         numerics: bool = False):
-        """``g`` local-SGD-plus-gossip steps in ONE program; each
-        substep's gossip decision follows the same ``gossip_every``
-        cadence the per-step path applies (substep counters shipped as
-        a stacked operand, uniform across devices so the in-cond
-        collective cannot diverge)."""
-        numerics = bool(numerics)
-        if self._count is None:
-            self._count = self.get_step(state)
-        g_steps = int(images.shape[0])
-        counts = jnp.arange(1, g_steps + 1, dtype=jnp.int32) + self._count
-        if numerics not in self._fused:
-            from theanompi_tpu.parallel.fused import fuse_sharded_step
-
-            every = self.gossip_every
-            flag_fn = self._make_flag_fn(numerics) if numerics else (
-                self._sharded_step_flag
-            )
-
-            def substep(st, x, y, r, count):
-                return flag_fn(st, x, y, r, count % every == 0)
-
-            self._fused[numerics] = fuse_sharded_step(
-                substep, self.mesh, self._state_spec,
-                (self.sharding.stacked_batch_spec,
-                 self.sharding.stacked_batch_spec,
-                 self.sharding.scalar, self.sharding.scalar),
-                True,
-            )
-        out = self._fused[numerics](state, images, labels, rngs, counts)
-        # advance only after the fused dispatch returns: a raise (OOM on
-        # a new trimmed-group shape) must not permanently shift the
-        # gossip cadence off the actually-applied steps
-        self._count += g_steps
         return out
 
     def exchange(self, state):

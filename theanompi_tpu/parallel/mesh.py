@@ -341,41 +341,24 @@ def host_local_batch_slice(mesh: Mesh, global_batch: int) -> slice:
     return slice(idx * per_host, (idx + 1) * per_host)
 
 
-def _place_batch(mesh: Mesh, x, sharding: NamedSharding, batch_dim: int,
-                 global_rows: Optional[int]):
-    """Shared placement core. Multi-controller: assemble the global array
-    from per-process rows of ``batch_dim`` (no cross-host copy)."""
-    n_proc = jax.process_count()
-    if n_proc > 1:
-        x = np.asarray(x)
-        rows = global_rows if global_rows is not None else x.shape[batch_dim] * n_proc
-        shape = list(x.shape)
-        shape[batch_dim] = rows
-        return jax.make_array_from_process_local_data(sharding, x, tuple(shape))
-    return jax.device_put(x, sharding)
-
-
 def put_global_batch(mesh: Mesh, x, axis=None, global_rows: Optional[int] = None):
     """Place a host batch onto the mesh sharded along the data axis.
 
     ``x`` holds THIS PROCESS's rows: in single-controller runs that is
     the whole global batch; in multi-controller runs each host passes
     only its ``host_local_batch_slice`` rows (the analogue of the
-    reference's per-rank batch-file partition). ``global_rows`` overrides
+    reference's per-rank batch-file partition) and the global array is
+    assembled from them (no cross-host copy). ``global_rows`` overrides
     the inferred global batch (defaults to ``rows_here * process_count``,
     the equal-split case)."""
-    return _place_batch(mesh, x, batch_sharding(mesh, axis), 0, global_rows)
-
-
-def put_stacked_batches(mesh: Mesh, x, axis=None, global_rows: Optional[int] = None):
-    """Place a STACKED group of batches ``[k, batch, ...]`` — the fused
-    multi-step dispatch ships k steps of data in one transfer; dim 0 (the
-    step index) is replicated, dim 1 (the batch) shards across the mesh.
-    Multi-controller hosts pass their local rows of dim 1 as usual."""
-    if axis is None:
-        axis = batch_axes(mesh)
-    sharding = NamedSharding(mesh, PartitionSpec(None, axis))
-    return _place_batch(mesh, x, sharding, 1, global_rows)
+    sharding = batch_sharding(mesh, axis)
+    n_proc = jax.process_count()
+    if n_proc > 1:
+        x = np.asarray(x)
+        rows = global_rows if global_rows is not None else x.shape[0] * n_proc
+        return jax.make_array_from_process_local_data(
+            sharding, x, (rows, *x.shape[1:]))
+    return jax.device_put(x, sharding)
 
 
 def first_local_value(x):

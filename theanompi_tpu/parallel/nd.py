@@ -266,12 +266,7 @@ class NDEngine:
         self._opt = opt
         self._tok_spec = tok_spec
         self._tok_sharding = NamedSharding(mesh, tok_spec)
-        # fused dispatch: group dim replicated ahead of the token spec
-        self._stacked_sharding = NamedSharding(
-            mesh, self.sharding.stacked_batch_spec)
-        self._donate = donate
         self.donates_state = bool(donate)
-        self._fused: dict = {}
         # multi-controller feed fraction (lo, hi, B): set by
         # host_batch_part when hosts load only their slice of the global
         # batch; None = every host feeds the full batch (replicated
@@ -359,8 +354,6 @@ class NDEngine:
                 )
 
             return sharded_step
-
-        self._make_sharded_step = make_sharded_step
 
         def jit_step(numerics: bool):
             return jax.jit(
@@ -502,22 +495,19 @@ class NDEngine:
 
         return jax.make_array_from_callback(tuple(gshape), sharding, cb)
 
-    def _split_microbatches(self, x, axis: int):
-        """Reshape the batch dim at ``axis`` to microbatch-major
-        ``[M, B/M]`` (no-op for non-pipeline engines) — the ONE place
-        the pipeline host layout is defined, shared by the per-step and
-        fused placement paths."""
+    def _split_microbatches(self, x):
+        """Reshape the batch dim to microbatch-major ``[M, B/M]`` (no-op
+        for non-pipeline engines) — the ONE place the pipeline host
+        layout is defined."""
         if self.microbatches is None:
             return x
         M = self.microbatches
-        if x.shape[axis] % M:
+        if x.shape[0] % M:
             raise ValueError(
-                f"global batch {x.shape[axis]} must be divisible by "
+                f"global batch {x.shape[0]} must be divisible by "
                 f"microbatches={M}"
             )
-        return x.reshape(
-            *x.shape[:axis], M, x.shape[axis] // M, *x.shape[axis + 1:]
-        )
+        return x.reshape(M, x.shape[0] // M, *x.shape[1:])
 
     def place_batch(self, x, y):
         """Host tokens ``[B, T]`` -> device, sharded per the engine's
@@ -525,7 +515,7 @@ class NDEngine:
         device array for x and y (labels are the tokens; zero extra
         transfer)."""
         del y  # labels ARE the tokens
-        x = self._split_microbatches(np.asarray(x), axis=0)
+        x = self._split_microbatches(np.asarray(x))
         t = self._put_global(
             x, self._tok_sharding,
             batch_dim=1 if self.microbatches is not None else 0,
@@ -538,38 +528,6 @@ class NDEngine:
         if numerics not in self._steps:
             self._steps[numerics] = self._jit_step(numerics)
         return self._steps[numerics](state, tokens, rng)
-
-    def place_group(self, group):
-        """Fused dispatch: stack ``g`` host token batches into ONE
-        ``[g, ...]`` transfer sharded per the engine's token spec (group
-        dim replicated; microbatch-major per batch for pipelines)."""
-        xs = np.stack([np.asarray(b[0]) for b in group])
-        t = self._put_global(
-            self._split_microbatches(xs, axis=1), self._stacked_sharding,
-            batch_dim=2 if self.microbatches is not None else 1,
-        )
-        return t, t
-
-    def fused_train_step(self, state, tokens_g, labels_g, rngs,
-                         numerics: bool = False):
-        """``g`` steps in ONE compiled program (``lax.scan`` over the
-        stacked group — same dispatch-amortization as
-        ``parallel/bsp.py::make_bsp_fused_step``); per-step keys stacked
-        ``[g]``, metrics returned stacked. Jit recompiles per distinct
-        group size (the driver produces at most the configured k plus an
-        epoch remainder)."""
-        del labels_g
-        numerics = bool(numerics)
-        if numerics not in self._fused:
-            from theanompi_tpu.parallel.fused import fuse_sharded_step
-
-            self._fused[numerics] = fuse_sharded_step(
-                self._make_sharded_step(numerics), self.mesh,
-                self._state_specs,
-                (self.sharding.stacked_batch_spec, self.sharding.scalar),
-                self._donate,
-            )
-        return self._fused[numerics](state, tokens_g, rngs)
 
     def exchange(self, state):
         return state

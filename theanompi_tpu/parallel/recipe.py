@@ -10,7 +10,7 @@ per-leaf-role spec rules for a rule engine's state; everything that
 needs a spec asks the recipe:
 
 - the engines' ``shard_map`` in/out specs (``state_spec``,
-  ``batch_spec``, ``stacked_batch_spec``, ``scalar``);
+  ``batch_spec``, ``scalar``);
 - the per-leaf declared spec table (``leaf_specs``) the sharding
   analyzer (tools/analyze/sharding.py, rules SHARD001-004) checks
   against the COMPILED truth read off the lowered executable;
@@ -94,12 +94,6 @@ class ShardingRecipe:
         """Replicated spec — rng keys, scalar metrics, whole-state
         prefixes for replicated rules."""
         return PartitionSpec()
-
-    @property
-    def stacked_batch_spec(self) -> PartitionSpec:
-        """Fused-dispatch batch spec: leading group/step dim replicated,
-        the batch dims per ``batch_spec``."""
-        return PartitionSpec(None, *self.batch_spec)
 
     @property
     def leading_batch_spec(self) -> PartitionSpec:

@@ -174,7 +174,6 @@ def make_zero1_train_step(
     steps_per_epoch: int = 1,
     input_transform: Optional[Callable] = None,
     donate: bool = True,
-    fused: bool = False,
     numerics: bool = False,
     wire_codec=None,
     fused_update: bool = False,
@@ -191,9 +190,6 @@ def make_zero1_train_step(
     sharded). ``train_step(state, x, y, rng) -> (state, metrics)`` with
     ``x``/``y`` sharded over the axis (the global batch, exactly like
     parallel/bsp.py). ``optimizer`` defaults to the model recipe's.
-    With ``fused=True`` the returned step instead takes stacked
-    ``[g, batch, ...]`` groups + ``[g]`` keys and scans ``g`` sub-steps
-    in one program (``steps_per_dispatch``; metrics stacked).
     """
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     if axis_name not in sizes:
@@ -350,17 +346,6 @@ def make_zero1_train_step(
             metrics,
         )
 
-    if fused:
-        # fused dispatch: lax.scan over stacked [g, batch, ...] groups,
-        # same amortization as make_bsp_fused_step (stacked metrics out)
-        from theanompi_tpu.parallel.fused import fuse_sharded_step
-
-        return init_state, fuse_sharded_step(
-            sharded_step, mesh, state_specs,
-            (recipe.stacked_batch_spec, recipe.stacked_batch_spec,
-             recipe.scalar), donate,
-        )
-
     train_step = jax.jit(
         jax.shard_map(
             sharded_step,
@@ -415,7 +400,7 @@ class ZeroEngine:
         self.mesh = mesh
         self.codec = get_codec(wire_codec)
         # ONE _zero1_setup derivation, handed to every step variant the
-        # engine builds (per-numerics + fused dispatch) via _build — the
+        # engine builds (one per numerics flag) via _build — the
         # declared spec table (sharding analyzer, memory_model, topology
         # stamp) and the compiled programs share it by construction
         setup = _zero1_setup(model, mesh, DATA_AXIS, None,
@@ -428,7 +413,6 @@ class ZeroEngine:
                            _setup=setup)
         self._init, step = make_zero1_train_step(model, mesh, **self._build)
         self._steps = {False: step}
-        self._fused: dict = {}
         self._eval = make_bsp_eval_step(
             model, mesh, input_transform=input_transform, eval_views=eval_views,
         )
@@ -443,18 +427,6 @@ class ZeroEngine:
                 self.model, self.mesh, numerics=numerics, **self._build
             )
         return self._steps[numerics](state, images, labels, rng)
-
-    def fused_train_step(self, state, images, labels, rngs,
-                         numerics: bool = False):
-        """``g`` ZeRO steps in one program (stacked batches + keys, like
-        make_bsp_fused_step); jit recompiles per distinct group size."""
-        numerics = bool(numerics)
-        if numerics not in self._fused:
-            _, self._fused[numerics] = make_zero1_train_step(
-                self.model, self.mesh, fused=True, numerics=numerics,
-                **self._build
-            )
-        return self._fused[numerics](state, images, labels, rngs)
 
     def exchange(self, state):
         return state

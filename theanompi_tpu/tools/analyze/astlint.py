@@ -79,7 +79,7 @@ _LISTING_FUNCS = {"listdir", "scandir", "glob", "iglob"}
 # writes (every rank must agree to save/restore the same step)
 _SINK_NAMES = {
     "process_allgather", "broadcast_one_to_all", "sync_global_devices",
-    "train_step", "fused_train_step", "exchange", "eval_step",
+    "train_step", "exchange", "eval_step",
     "save_checkpoint", "save_checkpoint_sharded", "load_checkpoint",
     "psum", "pmean", "all_gather",
     # elastic PR: the reshard transfer plan is cross-rank gang work —
@@ -96,7 +96,7 @@ _DEVICE_FUNCS = {"devices", "local_devices"}
 # random reads diverge by VALUE and no sort fixes that
 _ORDERING_FUNCS = _LISTING_FUNCS | _DEVICE_FUNCS
 # engine-protocol calls whose FIRST positional argument is donated
-_DONATING_CALLS = {"train_step", "fused_train_step", "exchange"}
+_DONATING_CALLS = {"train_step", "exchange"}
 
 
 @dataclass

@@ -43,8 +43,7 @@ Rules (IDs in tools/lint.py RULES):
 
 Caveat: HLO collective pricing counts each op once — a collective
 inside an HLO ``while`` body is priced per appearance, not per trip.
-The preflight steps carry no loops (fused dispatch's ``lax.scan``
-configs are pinned by the SPMD schedule goldens instead).
+The preflight steps carry no loops.
 """
 
 from __future__ import annotations

@@ -19,7 +19,7 @@ an eager ``jax.random.split`` anywhere in ``run_training`` (ISSUE 27):
 the per-step keys come off ``utils/dispatch.py``'s ``KeyStream``.
 
 Scope: every ``for ... in loader`` loop inside ``run_training`` (the
-per-step and fused dispatch loops). The epoch-level code around them —
+train loop). The epoch-level code around them —
 eval's single end-of-epoch ``float(v)`` drain, checkpoint enqueue,
 ``Recorder.end(..., sync=...)`` comm brackets after a pipeline flush —
 is deliberately out of scope: those are per-epoch / per-exchange syncs,

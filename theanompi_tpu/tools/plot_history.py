@@ -68,9 +68,9 @@ def load_jsonl(path: str) -> dict:
                 for k in train:
                     if k in row:
                         train[k].append(row[k])
-                # throughput is SPARSE under fused dispatch (one reading
-                # per dispatch, on the group's final substep row): pair
-                # it with its own step axis, never the full step list
+                # throughput can be SPARSE (a row carries it only when
+                # its step reported images): pair it with its own step
+                # axis, never the full step list
                 if "images_per_sec" in row and "step" in row:
                     train["ips_step"].append(row["step"])
             elif row.get("kind") == "val":

@@ -61,7 +61,7 @@ drains — at depth 2 the step before the one just dispatched. The
 amortized windows close on the drain bracket's own end stamp.
 
 :class:`KeyStream` is the pipeline's other half on the way IN: each
-step's random key is split off the carry one dispatch unit ahead, by
+step's random key is split off the carry one step ahead, by
 one jitted program queued behind the step that is running, so the host
 has the next key in hand when the drain returns.
 """
@@ -72,7 +72,6 @@ from collections import deque
 from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -104,7 +103,7 @@ class MetricsDispatcher:
     ``recorder.train_metrics(...)``, so rows carry the amortized
     per-step throughput exactly like sync-mode rows carry the bracketed
     one. ``on_step_seconds``: optional callback receiving the amortized
-    per-substep seconds at each sync point (the driver wires
+    per-step seconds at each sync point (the driver wires
     ``Observability.note_step_seconds`` so the comm-GB/s gauge stays
     live under deferred timing).
     """
@@ -135,7 +134,7 @@ class MetricsDispatcher:
         # in_flight + this distinguish a wedged device program from a
         # stalled host driver)
         self.last_drained_step = -1
-        # amortized per-substep seconds of the most recent sync; None
+        # amortized per-step seconds of the most recent sync; None
         # while steps are in flight without a completed sync
         self.last_step_seconds: Optional[float] = None
         # step dispatches seen by note_dispatch, and those of them made
@@ -170,18 +169,17 @@ class MetricsDispatcher:
         split's meaning identical to sync mode."""
         self._wait_s += float(dt)
 
-    def push(self, step: int, metrics: dict, n_images: int = 0,
-             substeps: int = 1) -> None:
-        """Enqueue one dispatched step (or fused group of ``substeps``)
-        whose ``metrics`` are still device-resident futures. Drains the
-        oldest entry once ``depth`` entries are in flight."""
+    def push(self, step: int, metrics: dict, n_images: int = 0) -> None:
+        """Enqueue one dispatched step whose ``metrics`` are still
+        device-resident futures. Drains the oldest entry once ``depth``
+        entries are in flight."""
         if self._t_mark is None:
             # window opens at the first in-flight push; waits before it
             # (epoch-boundary eval/checkpoint, first batch load) are not
             # part of any step's attribution
             self._t_mark = self.rec.clock_ns()
             self._wait_s = 0.0
-        self._buf.append((int(step), metrics, int(n_images), max(1, int(substeps))))
+        self._buf.append((int(step), metrics, int(n_images)))
         while len(self._buf) >= self.depth:
             self._drain_one()
 
@@ -220,7 +218,7 @@ class MetricsDispatcher:
         per_entry = total / len(entries)
         self._t_mark = None
         self._wait_s = 0.0
-        for step, metrics, n_images, substeps in entries:
+        for step, metrics, n_images in entries:
             if err is not None:
                 # oldest-first salvage: materializing the first poisoned
                 # entry re-raises; everything older is already emitted
@@ -228,9 +226,9 @@ class MetricsDispatcher:
                     metrics = {k: np.asarray(v) for k, v in metrics.items()}
                 except Exception:  # noqa: BLE001
                     raise err
-            self.last_step_seconds = per_entry / substeps
+            self.last_step_seconds = per_entry
             self.rec.note_time("step", per_entry, step=step)
-            self._emit_rows(step, metrics, n_images, substeps)
+            self._emit_rows(step, metrics, n_images)
         if err is not None:
             raise err
         if self._on_step_seconds is not None and entries:
@@ -248,7 +246,7 @@ class MetricsDispatcher:
 
     # -- internals -----------------------------------------------------------
     def _drain_one(self) -> None:
-        step, metrics, n_images, substeps = self._buf.popleft()
+        step, metrics, n_images = self._buf.popleft()
         self.rec.start("drain")
         host = {k: np.asarray(v) for k, v in metrics.items()}  # D2H sync
         self.host_blocked_s += self.rec.end("drain", step=step)
@@ -257,27 +255,19 @@ class MetricsDispatcher:
         dt = max(0.0, (now - self._t_mark) * 1e-9 - self._wait_s)
         self._t_mark = now
         self._wait_s = 0.0
-        self.last_step_seconds = dt / substeps
+        self.last_step_seconds = dt
         self.rec.note_time("step", dt, step=step)
-        self._emit_rows(step, host, n_images, substeps)
+        self._emit_rows(step, host, n_images)
         if self._on_step_seconds is not None:
             self._on_step_seconds(self.last_step_seconds)
 
-    def _emit_rows(self, step: int, metrics: dict, n_images: int,
-                   substeps: int) -> None:
-        """The drained entry's rows, under one ``emit`` bracket (closed
+    def _emit_rows(self, step: int, metrics: dict, n_images: int) -> None:
+        """The drained entry's row, under one ``emit`` bracket (closed
         also when ``on_row`` raises an anomaly halt)."""
-        self.rec.start("emit")
-        try:
-            self._rows(step, metrics, n_images, substeps)
-        finally:
-            self.rec.end("emit", step=step)
-
-    def _rows(self, step: int, metrics: dict, n_images: int,
-              substeps: int) -> None:
         from theanompi_tpu.obs.numerics import split_numerics
 
-        if substeps == 1:
+        self.rec.start("emit")
+        try:
             plain, nm = split_numerics(metrics)
             self.rec.train_metrics(step, plain, n_images=n_images)
             self.last_drained_step = step
@@ -289,26 +279,8 @@ class MetricsDispatcher:
                     {k: float(v) for k, v in plain.items()},
                     {k: float(v) for k, v in nm.items()},
                 )
-            return
-        # fused group: one JSONL row PER SUBSTEP from the stacked
-        # metrics (same-resolution loss/LR curves as per-step runs);
-        # the group's throughput is attributed to its final row
-        host = {k: np.asarray(v) for k, v in metrics.items()}
-        for i in range(substeps):
-            sub = {k: a[i] for k, a in host.items()}
-            plain, nm = split_numerics(sub)
-            sub_step = step - substeps + i + 1
-            self.rec.train_metrics(
-                sub_step, plain,
-                n_images=n_images if i == substeps - 1 else 0,
-            )
-            self.last_drained_step = sub_step
-            if self._on_row is not None:
-                self._on_row(
-                    sub_step,
-                    {k: float(v) for k, v in plain.items()},
-                    {k: float(v) for k, v in nm.items()},
-                )
+        finally:
+            self.rec.end("emit", step=step)
 
 
 @jax.jit
@@ -328,55 +300,44 @@ class KeyStream:
 
     ``carry`` is the COMMITTED carry: the key after the splits of every
     step taken so far, what a checkpoint saves as ``rng=``. Ahead of it
-    sits a queue of ready ``(carry_after, sub)`` pairs that
-    :meth:`refill` tops up to ``ahead`` (the dispatch unit: 1, or
-    ``steps_per_dispatch``). The driver refills right after a step
+    sits at most one ready ``(carry_after, sub)`` pair, which
+    :meth:`refill` makes. The driver refills right after a step
     program's dispatch returns: the split's host work and its tiny
     device program then run under that step, not in the bare gap
     between two steps. A key that is not ready when :meth:`take` wants
-    it (first step, after :meth:`reset`, a group larger than the queue)
-    is made on the spot — late, never wrong.
+    it (first step, after :meth:`reset`) is made on the spot — late,
+    never wrong.
     """
 
-    def __init__(self, carry, ahead: int = 1):
+    def __init__(self, carry):
         self.carry = carry
-        self.ahead = ahead
-        self._ready: deque = deque()
+        self._ready: Optional[tuple] = None  # (carry_after, sub)
         self.taken = 0
         self.taken_ready = 0  # of those, the keys that were waiting
 
-    def take(self, n: int = 1, stacked: bool = False):
-        """The next ``n`` keys (``stacked``: one ``[n, ...]`` array for a
-        fused group; else ``n`` must be 1: the key itself), committing
-        the carry past them."""
-        subs = []
-        for _ in range(n):
-            if self._ready:
-                self.taken_ready += 1
-            else:
-                self._make()
-            self.carry, sub = self._ready.popleft()
-            subs.append(sub)
-        self.taken += n
-        return jnp.stack(subs) if stacked else subs[0]
+    def take(self):
+        """The next key, committing the carry past it."""
+        if self._ready is None:
+            self.refill()
+        else:
+            self.taken_ready += 1
+        (self.carry, sub), self._ready = self._ready, None
+        self.taken += 1
+        return sub
 
     def refill(self) -> None:
-        """Top the ready queue up to ``ahead`` keys."""
-        while len(self._ready) < self.ahead:
-            self._make()
+        """Make the next key ready, unless it already is."""
+        if self._ready is None:
+            self._ready = _split(self.carry)
 
     def reset(self, carry) -> None:
         """Continue from a restored carry; what was ready belonged to
         the timeline the restore erased."""
         self.carry = carry
-        self._ready.clear()
+        self._ready = None
 
     @property
     def ready_share(self) -> Optional[float]:
-        """Keys taken from the ready queue over keys taken (steady
-        state: 1 less the first unit; falling = refill not ahead)."""
+        """Keys taken ready over keys taken (steady state: 1 less the
+        first; falling = refill not ahead)."""
         return self.taken_ready / self.taken if self.taken else None
-
-    def _make(self) -> None:
-        tip = self._ready[-1][0] if self._ready else self.carry
-        self._ready.append(_split(tip))

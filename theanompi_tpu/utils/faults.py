@@ -215,8 +215,7 @@ class FaultInjector:
     """Fires each armed :class:`FaultSpec` exactly once at its step.
 
     The driver calls :meth:`check_step` with the 1-based step it is
-    ABOUT to dispatch (fused dispatch passes the group's step range),
-    :meth:`poison_batch` on the batch feeding that step,
+    ABOUT to dispatch, :meth:`poison_batch` on the batch feeding it,
     :meth:`storage_mutations_due`/:meth:`apply_storage_mutation` around
     checkpoint saves, and installs :meth:`write_fault` as the
     checkpoint writer shim for the during-write kinds. Deterministic by
@@ -268,13 +267,11 @@ class FaultInjector:
             f.flush()
             os.fsync(f.fileno())
 
-    def _take(self, kind: str, first: int, last: Optional[int] = None
-              ) -> Optional[FaultSpec]:
-        """The unfired spec of ``kind`` whose step falls in
-        ``[first, last]`` (marked fired), or None."""
-        last = first if last is None else last
+    def _take(self, kind: str, step: int) -> Optional[FaultSpec]:
+        """The unfired spec of ``kind`` due at ``step`` (marked fired),
+        or None."""
         for s in self.specs:
-            if s.kind == kind and not s.fired and first <= s.step <= last:
+            if s.kind == kind and not s.fired and s.step == step:
                 s.fired = True
                 s.fired_seq = self._fire_seq
                 self._fire_seq += 1
@@ -282,21 +279,20 @@ class FaultInjector:
                 return s
         return None
 
-    def check_step(self, first: int, last: Optional[int] = None) -> None:
+    def check_step(self, step: int) -> None:
         """Fire crash/sigterm/sigkill/loader_stall faults due before
-        dispatching steps ``[first, last]`` (a fused group passes its
-        whole substep range)."""
-        s = self._take("loader_stall", first, last)
+        dispatching ``step``."""
+        s = self._take("loader_stall", step)
         if s is not None:
             time.sleep(2.0 if s.arg is None else float(s.arg))
-        s = self._take("crash", first, last)
+        s = self._take("crash", step)
         if s is not None:
             raise InjectedCrash(f"injected crash before step {s.step}")
         for kind in ("shrink", "grow"):
-            s = self._take(kind, first, last)
+            s = self._take(kind, step)
             if s is not None:
                 raise TopologyChanged(kind, s.step, int(s.arg))
-        s = self._take("slice_down", first, last)
+        s = self._take("slice_down", step)
         if s is not None:
             lost = 1 if s.arg is None else int(s.arg)
             if self._topology is None or self._topology[0] <= 1:
@@ -316,22 +312,22 @@ class FaultInjector:
                 )
             s.resolved_world = survivors
             raise TopologyChanged("slice_down", s.step, survivors)
-        s = self._take("sigterm", first, last)
+        s = self._take("sigterm", step)
         if s is not None:
             os.kill(os.getpid(), signal.SIGTERM)
-        s = self._take("sigkill", first, last)
+        s = self._take("sigkill", step)
         if s is not None:
             os.kill(os.getpid(), signal.SIGKILL)
 
-    def poison_batch(self, x, first: int, last: Optional[int] = None):
+    def poison_batch(self, x, step: int):
         """``nan_batch``: return ``x`` poisoned with NaN when a spec is
-        due in ``[first, last]``, else ``x`` unchanged. Device-side op
+        due at ``step``, else ``x`` unchanged. Device-side op
         (adds NaN to the already-placed batch) — no host sync, and the
         result keeps ``x``'s sharding. Float batches only (token
         batches raise: an int stream cannot carry NaN)."""
         import jax.numpy as jnp
 
-        s = self._take("nan_batch", first, last)
+        s = self._take("nan_batch", step)
         if s is None:
             return x
         if not jnp.issubdtype(x.dtype, jnp.floating):
