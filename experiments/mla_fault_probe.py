@@ -19,6 +19,10 @@ compressed keys and recurrent state are all "the cache" here.
   before it: the rows a sequence decodes are never cached, in any layer.
 - ``prefill_unchanged``: every prefill hands back the pools as they were
   before it: a prompt's rows are never cached.
+- ``state_unwritten`` (``--workload gigachat35-decode-reason2k``):
+  every decode step hands back the recurrent state held a slot (the second
+  pool's ``"state"``) as it was before the step, and everything else as the
+  step wrote it: the delta rule never steps past the prompt.
 
 Every reading goes through the run's own ``measure``, ``checks_of`` and
 ``is_correct`` at the cell's own size, load and limits (``min_counted`` scaled
@@ -41,7 +45,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
 
-FAULTS = ("none", "state_unchanged", "prefill_unchanged")
+FAULTS = ("none", "state_unchanged", "prefill_unchanged", "state_unwritten")
 
 
 def plant(probe, fault):
@@ -66,10 +70,17 @@ def plant(probe, fault):
         prefill(params, tokens, pages, k_pool, v_pool, *where)
         return kept
 
+    def state_unwritten(params, k_pool, v_pool, *rest):
+        kept = copies(k_pool, v_pool)[1]["state"]
+        nxt, logits, k_new, v_new = decode(params, k_pool, v_pool, *rest)
+        return nxt, logits, k_new, {**v_new, "state": kept}
+
     if fault == "state_unchanged":
         probe._orig["_decode"] = state_unchanged
     elif fault == "prefill_unchanged":
         probe._orig["_prefill"] = prefill_unchanged
+    elif fault == "state_unwritten":
+        probe._orig["_decode"] = state_unwritten
     else:
         raise ValueError(fault)
 

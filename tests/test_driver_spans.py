@@ -302,12 +302,13 @@ def test_every_pallas_call_in_ops_passes_a_name():
                 # a module-level constant beside its kernel
                 assert isinstance(name[0], ast.Name) and name[0].id in constants, f"{path}:{node.lineno}"
                 names.add(constants[name[0].id])
-    assert calls == 25 and len(names) == 25
+    assert calls == 27 and len(names) == 27
     assert {"moe_gmm", "moe_tgmm", "mla_decode", "mla_cache_write"} <= names
     assert {"sparse_decode", "sparse_prefill", "sparse_cache_write", "lightning_step"} <= names  # PR 35
     assert {"eva_decode", "eva_prefill", "eva_cache_write"} <= names  # PR 39
     assert "lightning_chunk" in names  # PR 40
     assert "mamba_scan" in names
     assert "kv_decode" in names
+    assert {"gdn_chunk", "gdn_step"} <= names
     # the roofline readers find the flash kernels by these two stems
     assert {n for n in names if "flash" in n} == {"flash_fwd", "flash_bwd", "flash_bwd_2d"}

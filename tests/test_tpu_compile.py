@@ -718,3 +718,106 @@ def test_jamba2_prefill_of_32768_positions_fits_beside_its_weights(
     assert mem.alias_size_in_bytes >= 349_405_184 and mem.temp_size_in_bytes < 4e9
     assert f"{T},{T}" not in text and f"{T},16,5120" not in text
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_the_delta_rule_kernels_at_the_gigachat35_width(one_chip, no_persistent_cache, monkeypatch):
+    """``gdn_chunk`` over a 1,024-row prompt (32 key and 64 value heads of 128,
+    chunks of 64; its state in VMEM across the chunks) and ``gdn_step`` over
+    128 slots of a 2.1 GB float32 state, donated: the state aliases the
+    output and is not copied."""
+    from theanompi_tpu.ops import pallas_gdn as pg
+
+    _mosaic(monkeypatch, pg)
+    T, S, Hk, H, D = 1024, 128, 32, 64, 128
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, v, b, g, n: pg.gdn_chunk(q, k, v, b, g, n, chunk=64)).lower(
+        on_chip((T, Hk, D), jnp.bfloat16), on_chip((T, Hk, D), jnp.bfloat16), on_chip((T, H, D), jnp.bfloat16),
+        on_chip((T, H), jnp.float32), on_chip((T, H), jnp.float32), on_chip((), jnp.int32)).compile()
+    assert _kernels(compiled) == 1 and "gdn_chunk" in compiled.as_text()
+    state = on_chip((4, S, H, D, D), jnp.float32)
+    compiled = jax.jit(lambda q, k, v, b, g, s, n: pg.gdn_step(q, k, v, b, g, s, n, layer=2),
+                       donate_argnums=(5,)).lower(
+        on_chip((S, Hk, D), jnp.bfloat16), on_chip((S, Hk, D), jnp.bfloat16), on_chip((S, H, D), jnp.bfloat16),
+        on_chip((S, H), jnp.float32), on_chip((S, H), jnp.float32), state, on_chip((S,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert _kernels(compiled) == 1 and "gdn_step" in compiled.as_text()
+    assert mem.alias_size_in_bytes == 4 * S * H * D * D * 4 and mem.temp_size_in_bytes < 0.1e9
+
+
+def _gigachat35_decode(one_chip, monkeypatch):
+    """The cell ``gigachat35-decode-reason2k`` as shapes on the described chip:
+    the chip's share of 5 layers at the published widths and its engine's
+    three kinds of state as ``PagedKVCache`` shapes them (128 slots of 25
+    pages of 128 positions in the one full-attention layer; a conv window and
+    a state a slot in each of the 4 delta-rule layers)."""
+    from theanompi_tpu.models.gigachat35 import GigaChat35_EP32
+    from theanompi_tpu.ops import pallas_attention as pa
+    from theanompi_tpu.ops import pallas_gdn as pg
+    from theanompi_tpu.ops import pallas_mla as pm
+    from theanompi_tpu.ops import pallas_moe as po
+
+    for module in (pa, pg, pm, po):
+        _mosaic(monkeypatch, module)
+    model, page, pages, S, M = GigaChat35_EP32(), 128, 3200, 128, 25
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    spec = model.cache_spec(page)
+    k_pool = on_chip((spec["paged_layers"], pages + 1, *spec["k_page"]), spec["dtype"])
+    held = {"v": on_chip((spec["paged_layers"], pages + 1, *spec["v_page"]), spec["dtype"])}
+    for name, a in spec["slots"].items():
+        held[name] = on_chip((a["layers"], S, *a["row"]), a["dtype"])
+    assert k_pool.shape == (1, 3201, 128, 512) and held["v"].shape == (1, 3201, 64, 128)
+    assert held["conv"].shape == (4, 128, 3, 16384) and held["state"].shape == (4, 128, 64, 128, 128)
+    return model, page, params, k_pool, held, on_chip, (S, M)
+
+
+def test_gigachat35_decode_step_updates_every_pool_in_place(one_chip, no_persistent_cache, monkeypatch):
+    """128 slots over 0.47 GB of latent pages and 2.2 GB of conv windows and
+    float32 delta-rule state: four ``gdn_step`` calls, one ``mla_decode`` at 64
+    heads over a 576-wide latent row, one ``mla_cache_write``, three grouped
+    products in each of the four routed layers; everything donated aliases
+    its output, and the 6.64 GB of weights, the pools and the temporaries fit
+    the chip."""
+    model, page, params, k_pool, held, on_chip, (S, M) = _gigachat35_decode(one_chip, monkeypatch)
+
+    def step(p, k, v, tb, sl, la, ac, te):
+        return model.decode_step(p, k, v, tb, sl, la, ac, te, jax.random.PRNGKey(0), page_size=page)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_pool, held, on_chip((S, M), jnp.int32), on_chip((S,), jnp.int32), on_chip((S,), jnp.int32),
+        on_chip((S,), jnp.bool_), on_chip((S,), jnp.float32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 4 + 2 + 4 * 3
+    assert "gdn_step" in text and "mla_decode" in text and "mla_cache_write" in text
+    assert _held_bytes(k_pool, held) == 2_669_821_952 and mem.alias_size_in_bytes >= 2_669_821_952
+    assert mem.temp_size_in_bytes < 0.3e9  # a copy of the state would be 2.1 GB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_gigachat35_prefill_of_1024_positions_fits_beside_its_weights(one_chip, no_persistent_cache, monkeypatch):
+    """One bucket of 1,024 rows for its slot at a traced real length: four
+    ``gdn_chunk`` kernels, one ``flash_fwd`` with V of its own width (192 for
+    scores, 128 for values), the grouped products of the three routed layers
+    before the last (the last layer's MLP feeds nothing a prefill keeps);
+    whole pages and the slot's windows and states written to the donated
+    pools."""
+    model, page, params, k_pool, held, on_chip, _ = _gigachat35_decode(one_chip, monkeypatch)
+
+    def prefill(p, t, pg, k, v, slot, n_real):
+        return model.decode_prefill(p, t, pg, k, v, slot, n_real, page_size=page)
+
+    T = 1024
+    compiled = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, on_chip((T,), jnp.int32), on_chip((T // page,), jnp.int32), k_pool, held,
+        on_chip((), jnp.int32), on_chip((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(compiled) == 4 + 1 + 3 * 3 and "gdn_chunk" in text and "flash_fwd" in text
+    assert mem.alias_size_in_bytes >= 2_669_821_952 and mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES

@@ -37,6 +37,8 @@ MODEL_REGISTRY = {
     "evabyte_stage4": ("theanompi_tpu.models.evabyte", "EvaByte_Stage4"),
     "jamba_lm": ("theanompi_tpu.models.jamba", "Jamba"),
     "jamba2_3b_stage2": ("theanompi_tpu.models.jamba", "Jamba2_3B_Stage2"),
+    "gigachat35_lm": ("theanompi_tpu.models.gigachat35", "GigaChat35"),
+    "gigachat35_ep32": ("theanompi_tpu.models.gigachat35", "GigaChat35_EP32"),
 }
 
 

@@ -202,6 +202,7 @@ def routed_experts(
     total: int,
     *,
     tm: int = 256,
+    limit: Optional[float] = None,
 ) -> tuple[jax.Array, RoutedStats]:
     """``sum_j w[t, j] * swiglu_{idx[t, j]}(x[t])`` over the chosen experts
     that are held here. No capacity and no drop: the pairs whose expert
@@ -209,7 +210,9 @@ def routed_experts(
     case that ALL ``T * k`` pairs land here, each expert's rows starting
     at a tile; gate, up and down projection are one grouped product each
     (tiles past the real rows skip their work); the weighted rows are
-    gathered back by token."""
+    gathered back by token. ``limit`` (a SwiGLU limit): the gate's
+    pre-activation clamped at ``limit`` from above, the up projection's to
+    ``[-limit, limit]``."""
     T, k = idx.shape
     count, P = w_gate.shape[0], T * k
     M = padded_rows(P, count, tm)
@@ -230,7 +233,10 @@ def routed_experts(
     xs = _to_rows(x, row_pair, pos)
     gate = gmm(xs, w_gate, sizes, tm=tm)
     up = gmm(xs, w_up, sizes, tm=tm)
-    mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(x.dtype)
+    gate, up = gate.astype(jnp.float32), up.astype(jnp.float32)
+    if limit is not None:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    mid = (jax.nn.silu(gate) * up).astype(x.dtype)
     ys = _from_rows(gmm(mid, w_down, sizes, tm=tm), row_pair, pos)
     y = jnp.einsum("tk,tkd->td", w, ys.astype(jnp.float32)).astype(x.dtype)
 

@@ -175,10 +175,9 @@ def _fwd_kernel(cfg: _Cfg, qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
     i = pl.program_id(1)
     q_off, k_off = qo_ref[0, 0], ko_ref[0, 0]
     q = q_ref[0]  # [BQ, D], input dtype
-    D = q.shape[-1]
     nk = k_ref.shape[1] // cfg.BK
 
-    acc0 = jnp.zeros((cfg.BQ, D), jnp.float32)
+    acc0 = jnp.zeros((cfg.BQ, v_ref.shape[-1]), jnp.float32)  # V's own width
     m0 = jnp.full((cfg.BQ, 1), _NEG, jnp.float32)
     l0 = jnp.zeros((cfg.BQ, 1), jnp.float32)
 
@@ -358,13 +357,14 @@ def _full(shape):
                         memory_space=pltpu.VMEM)
 
 
-def _fwd_vmem_bytes(Tkp: int, D: int, itemsize: int) -> Optional[int]:
+def _fwd_vmem_bytes(Tkp: int, D: int, Dv: int, itemsize: int) -> Optional[int]:
     """What :func:`_fwd_kernel` may take of VMEM where its resident K and V
-    (two buffers each, lanes padded to 128) pass 8 MiB: those and 16 MiB for
-    the query and output blocks and the tile's intermediates. None (the
-    default scoped limit, 16 MiB on a v5e) up to 8 MiB: the programs of
-    contexts up to 8,192 bfloat16 keys are what they were."""
-    kv = 4 * Tkp * _ceil_to(D, 128) * itemsize
+    (two buffers each, lanes padded to 128; K ``D`` wide, V ``Dv``) pass 8
+    MiB: those and 16 MiB for the query and output blocks and the tile's
+    intermediates. None (the default scoped limit, 16 MiB on a v5e) up to 8
+    MiB: the programs of contexts up to 8,192 bfloat16 keys are what they
+    were."""
+    kv = 2 * Tkp * (_ceil_to(D, 128) + _ceil_to(Dv, 128)) * itemsize
     return None if kv <= (8 << 20) else kv + (16 << 20)
 
 
@@ -373,8 +373,8 @@ def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
     from jax.experimental.pallas import tpu as pltpu
 
     BH, Tqp, D = q3.shape
-    Tkp = k3.shape[1]
-    limit = _fwd_vmem_bytes(Tkp, D, k3.dtype.itemsize)
+    Tkp, Dv = k3.shape[1], v3.shape[-1]
+    limit = _fwd_vmem_bytes(Tkp, D, Dv, k3.dtype.itemsize)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg),
         grid=(BH, Tqp // cfg.BQ),
@@ -383,16 +383,16 @@ def _fwd(cfg: _Cfg, q3, k3, v3, q_off, k_off):
             _smem_spec(),                     # k_off
             _q_major((1, cfg.BQ, D)),         # q
             _full((1, Tkp, D)),               # k
-            _full((1, Tkp, D)),               # v
+            _full((1, Tkp, Dv)),              # v
         ],
         out_specs=(
-            _q_major((1, cfg.BQ, D)),
+            _q_major((1, cfg.BQ, Dv)),
             # [BH, Tqp, 1]: a trailing singleton lane keeps the block's
             # last-two dims Mosaic-legal ((BQ, 1) == (div 8, full dim))
             _q_major((1, cfg.BQ, 1)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((BH, Tqp, D), q3.dtype),
+            jax.ShapeDtypeStruct((BH, Tqp, Dv), q3.dtype),
             jax.ShapeDtypeStruct((BH, Tqp, 1), jnp.float32),
         ),
         compiler_params=None if limit is None else pltpu.CompilerParams(vmem_limit_bytes=limit),
@@ -534,6 +534,8 @@ def _flash_vjp_fwd(cfg, q3, k3, v3):
 
 
 def _flash_vjp_bwd(cfg, res, g):
+    if res[2].shape[-1] != res[0].shape[-1]:
+        raise NotImplementedError("flash_attention: no backward for V of a width other than Q's")
     return _bwd(cfg, *res, g)
 
 
@@ -548,7 +550,7 @@ def _prepare(q, k, v, causal, scale, precision, block_q, block_k, window=None):
     """Shared prologue of the public entry points: precision upcast,
     block sizing, heads-major reshape, padding. Returns
     ``(cfg, q3, k3, v3, shape_meta)`` where shape_meta =
-    ``(B, Tq, H, D, out_dtype)`` for :func:`_finish`."""
+    ``(B, Tq, H, Dv, out_dtype)`` for :func:`_finish` (``Dv``: V's width)."""
     out_dtype = q.dtype
     if precision in (lax.Precision.HIGHEST, "highest", "float32"):
         q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
@@ -563,13 +565,13 @@ def _prepare(q, k, v, causal, scale, precision, block_q, block_k, window=None):
 
     q3 = _to_heads_major(q, B, Tq, H, D)
     k3 = _to_heads_major(k, B, Tk, H, D)
-    v3 = _to_heads_major(v, B, Tk, H, D)
+    v3 = _to_heads_major(v, B, Tk, H, v.shape[-1])
     if Tqp != Tq:
         q3 = jnp.pad(q3, ((0, 0), (0, Tqp - Tq), (0, 0)))
     if Tkp != Tk:
         k3 = jnp.pad(k3, ((0, 0), (0, Tkp - Tk), (0, 0)))
         v3 = jnp.pad(v3, ((0, 0), (0, Tkp - Tk), (0, 0)))
-    return cfg, q3, k3, v3, (B, Tq, H, D, out_dtype)
+    return cfg, q3, k3, v3, (B, Tq, H, v.shape[-1], out_dtype)
 
 
 def _finish(o_padded, shape_meta):
@@ -582,7 +584,7 @@ def _finish(o_padded, shape_meta):
 def flash_attention(
     q: jax.Array,  # [B, Tq, H, D]
     k: jax.Array,  # [B, Tk, H, D]
-    v: jax.Array,  # [B, Tk, H, D]
+    v: jax.Array,  # [B, Tk, H, Dv]
     causal: bool = False,
     scale: Optional[float] = None,
     precision=None,
@@ -603,7 +605,9 @@ def flash_attention(
 
     Sequence lengths are padded up to the block sizes internally
     (padded keys masked, padded query rows discarded); head dim is used
-    as-is (Mosaic pads lanes — D a multiple of 128 is fastest).
+    as-is (Mosaic pads lanes — D a multiple of 128 is fastest). V may be
+    of a width ``Dv`` of its own (the output's; forward only: the backward
+    needs ``Dv == D``).
 
     ``precision``: matmuls run in the INPUT dtype with fp32 accumulation
     (softmax statistics are always fp32); ``Precision.HIGHEST`` upcasts

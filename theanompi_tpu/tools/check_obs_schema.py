@@ -550,6 +550,12 @@ SERVE_METRIC_PREFIX = "tmpi_serve_"
 #   tmpi_decode_ssm_state_bytes gauge      Jamba: Mamba state a step read
 #                                          and wrote a slot (conv|ssm held a
 #                                          slot: ..._conv_bytes, ..._ssm_bytes)
+#   tmpi_decode_latent_rows     gauge      GigaChat: latent rows a query
+#                                          read over the full-attention layers
+#   tmpi_decode_gdn_state_bytes gauge      GigaChat: delta-rule state a step
+#                                          read and wrote a slot (conv|state
+#                                          held a slot: ..._conv_bytes,
+#                                          ..._state_bytes)
 #   tmpi_decode_requests_total  counter    by status=served|expired|
 #                                          evicted|rejected|failed
 #   tmpi_decode_tokens_total    counter    tokens sampled and returned
